@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import csv_row
-from repro.kernels import on_tpu
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
 from repro.kernels.decode_attention.ref import decode_attention_ref
 
@@ -26,16 +25,15 @@ def run():
     B, H, Kv, D, C = 4, 8, 2, 64, 2048
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, C, Kv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, C, Kv, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, Kv, C, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, Kv, C, D), jnp.float32)
 
     proportional = True
     for frac in (0.125, 0.5, 1.0):
         lengths = jnp.full((B,), int(C * frac), jnp.int32)
         for bc in (256, 512):
             fn = jax.jit(lambda q, k, v, ln, bc=bc: decode_attention_pallas(
-                q, k, v, ln, bc=bc, interpret=not on_tpu(),
-                return_block_counts=True))
+                q, k, v, ln, bc=bc, return_block_counts=True))
             out, counts = fn(q, k, v, lengths)       # compile + warm
             jax.block_until_ready(out)
             t0 = time.perf_counter()

@@ -31,6 +31,9 @@ MODULES = [
 
 def main() -> None:
     import importlib
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = sys.argv[1:] or MODULES
     print("name,us_per_call,derived")
     summary = {}

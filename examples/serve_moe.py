@@ -49,13 +49,16 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.configs.base import DepClusterConfig
 from repro.core import FinDEPPlanner, PAPER_A6000
+from repro.core.perf_model import profile_for_device
 from repro.core.planner import PlannerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.profiling import ProfileStore
 from repro.runtime import ADMISSIONS, Request, ServingEngine
 from repro.sched import POLICIES, make_policy
@@ -121,11 +124,18 @@ def main():
                          "while serving (default: one final snapshot)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     store = ProfileStore(args.profile_store)
     policy = None
     if cfg.is_moe:
-        planner = FinDEPPlanner(cfg, DepClusterConfig(8, 3, 5), PAPER_A6000,
+        # a TPU plans from its kind's registry profile (an unknown kind
+        # raises); on the CPU the paper's A6000 fit stands in, as a
+        # planning tool only
+        dev = jax.devices()[0]
+        hw = profile_for_device(dev) if dev.platform == "tpu" \
+            else PAPER_A6000
+        planner = FinDEPPlanner(cfg, DepClusterConfig(8, 3, 5), hw,
                                 PlannerConfig(mem_cap_samples=8))
         policy = make_policy(args.policy, planner, static_seq_len=256)
     # the engine owns the measured-cost-model flow: calibrate= measures +

@@ -42,7 +42,7 @@ def _ints(tup) -> Tuple[int, ...]:
 
 
 # ---------------------------------------------------------------------------
-# dense decode: grid (B, Kv, C // bc), k/v [B, C, Kv, D] block (1, bc, 1, D)
+# dense decode: grid (B, Kv, C // bc), k/v [B, Kv, C, D] block (1, 1, bc, D)
 # ---------------------------------------------------------------------------
 
 
@@ -62,7 +62,7 @@ def check_dense_index_map(C: int, bc: int, lengths,
     for b in range(B):
         for kv in range(Kv):
             for c in range(n_c):
-                bi, ci, kvi, di = _ints(kv_map(b, kv, c, lens))
+                bi, kvi, ci, di = _ints(kv_map(b, kv, c, lens))
                 if not (bi == b and kvi == kv and di == 0):
                     out.append(Violation(
                         PASS, "dense-block-identity", where,
@@ -90,7 +90,7 @@ def _boundary_lengths(C: int, bc: int) -> List[int]:
 
 
 # ---------------------------------------------------------------------------
-# paged decode: grid (B, Kv, n_blocks), k/v pools [P, bs, Kv, D]
+# paged decode: grid (B, Kv, n_blocks), k/v pools [P, Kv, bs, D]
 # ---------------------------------------------------------------------------
 
 
@@ -113,12 +113,12 @@ def check_paged_index_map(tables, lengths, num_pages: int, bs: int,
     for b in range(B):
         for kv in range(Kv):
             for c in range(n_blocks):
-                pi, off, kvi, di = _ints(kv_map(b, kv, c, lens, tbl))
+                pi, kvi, off, di = _ints(kv_map(b, kv, c, lens, tbl))
                 if not (off == 0 and kvi == kv and di == 0):
                     out.append(Violation(
                         PASS, "paged-block-identity", where,
                         f"grid ({b},{kv},{c}) mapped offsets "
-                        f"({off},{kvi},{di}), expected (0,{kv},0)"))
+                        f"({kvi},{off},{di}), expected ({kv},0,0)"))
                 if not 0 <= pi < num_pages:
                     out.append(Violation(
                         PASS, "paged-page-range", where,
@@ -176,11 +176,11 @@ def run(fast: bool = False, log=None) -> Tuple[List[Violation], Dict]:
     out: List[Violation] = []
     cases = 0
 
-    # dense: dividing, non-dividing (largest_block_size fallback),
-    # single-block, and prime-C shapes
-    dense_shapes = [(64, 16), (60, 16), (16, 512), (13, 8)]
+    # dense: dividing, non-dividing (largest_block_size falls to a
+    # smaller tile-aligned divisor), single-block, and prime-C single-block
+    dense_shapes = [(64, 16), (40, 16), (16, 512), (13, 512)]
     if fast:
-        dense_shapes = [(64, 16), (60, 16)]
+        dense_shapes = [(64, 16), (40, 16)]
     for C, bc in dense_shapes:
         lens = _boundary_lengths(C, bc)
         out += check_dense_index_map(C, bc, lens)

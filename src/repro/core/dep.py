@@ -50,7 +50,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.core import taskgraph as tg
@@ -445,9 +444,9 @@ def moe_apply_dep(params, x, mcfg: MoEConfig, ctx, num_experts_padded: int,
     out_specs = (in_spec, P())
     if return_stats:
         out_specs += (moe_lib.MoEStats(load=P(), dropped=P()),)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(specs),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(*args)

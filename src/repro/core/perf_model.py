@@ -193,6 +193,22 @@ def get_profile(name: str) -> HardwareProfile:
                        f"{sorted(PROFILES)}") from None
 
 
+#: registry profile per device kind, as ``jax.Device.device_kind`` names it
+DEVICE_KIND_PROFILES = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def profile_for_device(device) -> HardwareProfile:
+    """The registry profile of ``device``'s kind. A kind with no profile
+    is an error: planning a chip from another chip's numbers would pass
+    silently for a wrong schedule."""
+    try:
+        return DEVICE_KIND_PROFILES[device.device_kind]
+    except KeyError:
+        raise KeyError(f"no hardware profile for device kind "
+                       f"{device.device_kind!r}; known kinds: "
+                       f"{sorted(DEVICE_KIND_PROFILES)}") from None
+
+
 # ---------------------------------------------------------------------------
 # DEP stage models (Eqs. 1-4 composed with Eqs. 7-9)
 # ---------------------------------------------------------------------------

@@ -15,18 +15,23 @@ per-row ``lengths [B]`` (the KV ledger) and
     HBM stream, and ``pl.when`` skips the flash update. Streamed bytes
     scale with ceil(length/bc), not C/bc.
 
-Grid = (B, Kv, C // bc); the flash state for the g query heads of one KV
-group lives in VMEM scratch across the contraction steps. Rows with
-length 0 (freshly-freed slots) execute no blocks and flush zeros.
-``return_block_counts=True`` also returns the executed-block count per
-(row, KV head) — the structural quantity CI verifies, since interpret
-mode has no meaningful wall clock.
+The cache is HEAD-MAJOR, ``[B, Kv, C, D]``: one grid step streams a
+``(bc, D)`` block of one KV head, so the block's last two dims meet the
+TPU tiling (``bc`` a multiple of the dtype's sublane tile, or all of C;
+D whole). Grid = (B, Kv, C // bc); the flash state for the g query heads
+of one KV group lives in VMEM scratch across the contraction steps.
+Rows with length 0 (freshly-freed slots) execute no blocks and flush
+zeros. ``return_block_counts=True`` also returns the executed-block
+count per (row, KV head) — the structural quantity CI verifies, since
+interpret mode has no meaningful wall clock; the serving path compiles
+the kernel without that output.
 
 ``paged_decode_attention_pallas`` is the block-table mode
 (``repro.runtime.paging``): K/V live in physical page pools
-``[P, bs, Kv, D]`` and a second scalar-prefetched operand — the per-row
+``[P, Kv, bs, D]`` and a second scalar-prefetched operand — the per-row
 block table — is dereferenced by the K/V ``index_map`` to turn a logical
-block step into a physical page fetch. Same flash body, same skip
+block step into a physical page fetch. A page block spans its whole
+``(bs, D)`` tail, so any page size tiles. Same flash body, same skip
 semantics; at ``bc == bs`` and identical logical contents the two modes
 stream identical blocks in identical order, so outputs are bit-equal
 (parity-locked in tests/test_paging.py).
@@ -35,53 +40,74 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def largest_block_size(C: int, bc: int) -> int:
-    """Largest block size <= ``bc`` that divides ``C`` (the shape-crash
-    fallback: C=600 with bc=512 used to assert; now it runs at bc=300)."""
-    bc = max(min(bc, C), 1)
-    while C % bc:
-        bc -= 1
-    return bc
+def sublane_tile(dtype) -> int:
+    """Rows of one TPU (sublane, lane) tile for ``dtype``: 8 for 32-bit,
+    16 for 16-bit (two values packed per sublane), 32 for 8-bit."""
+    return 8 * max(4 // jnp.dtype(dtype).itemsize, 1)
+
+
+def largest_block_size(C: int, bc: int, multiple: int = 8) -> int:
+    """The KV block size for a cache of ``C`` positions: all of C when it
+    fits in ``bc``, else the largest divisor of C that is <= ``bc`` and a
+    multiple of ``multiple`` (the sublane tile — a block the TPU can
+    tile). Raises ``ValueError`` when C has no such divisor (a prime C
+    larger than ``bc``): the caller picks another ``max_context``."""
+    if C <= bc:
+        return C
+    for cand in range(bc - bc % multiple, 0, -multiple):
+        if C % cand == 0:
+            return cand
+    raise ValueError(
+        f"a cache of {C} positions has no block size <= {bc} that is a "
+        f"multiple of {multiple}; the decode kernel cannot tile it")
 
 
 def dense_kv_index_map(bc: int):
-    """The dense-mode K/V ``index_map`` for block size ``bc``: clamp
-    past-length steps to the row's last in-range block, so the pipeline
-    sees an unchanged block index and skips the HBM fetch. Module-level
-    (not a closure inside the pallas_call wrapper) so
-    ``repro.analysis.kernelcheck`` can evaluate the exact production
-    index math over the full grid with concrete integers."""
+    """The dense-mode K/V ``index_map`` for block size ``bc`` over the
+    head-major ``[B, Kv, C, D]`` cache: clamp past-length steps to the
+    row's last in-range block, so the pipeline sees an unchanged block
+    index and skips the HBM fetch. Module-level (not a closure inside the
+    pallas_call wrapper) so ``repro.analysis.kernelcheck`` can evaluate
+    the exact production index math over the full grid with concrete
+    integers."""
     def kv_map(b, kv, c, lens):
         last = jnp.maximum((lens[b] + bc - 1) // bc, 1) - 1
-        return (b, jnp.minimum(c, last), kv, 0)
+        return (b, kv, jnp.minimum(c, last), 0)
     return kv_map
 
 
 def paged_kv_index_map(bs: int):
-    """The block-table K/V ``index_map`` for page size ``bs``: clamp the
-    logical block to the row's last in-range block, dereference the
-    scalar-prefetched table, and clamp unallocated (-1) entries to the
-    reserved scratch page 0. Module-level for
-    ``repro.analysis.kernelcheck`` (see ``dense_kv_index_map``)."""
+    """The block-table K/V ``index_map`` for page size ``bs`` over the
+    ``[P, Kv, bs, D]`` pools: clamp the logical block to the row's last
+    in-range block, dereference the scalar-prefetched table, and clamp
+    unallocated (-1) entries to the reserved scratch page 0. Module-level
+    for ``repro.analysis.kernelcheck`` (see ``dense_kv_index_map``)."""
     def kv_map(b, kv, c, lens, tbl):
         last = jnp.maximum((lens[b] + bs - 1) // bs, 1) - 1
         page = tbl[b, jnp.minimum(c, last)]
-        return (jnp.maximum(page, 0), 0, kv, 0)
+        return (jnp.maximum(page, 0), kv, 0, 0)
     return kv_map
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
-                   m_ref, l_ref, acc_ref, *, bc: int, n_c_steps: int,
-                   scale: float):
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, bc: int,
+                   n_c_steps: int, scale: float, with_counts: bool):
+    if with_counts:
+        cnt_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        cnt_ref = None
+        m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     c_step = pl.program_id(2)
     length = len_ref[b]
@@ -92,13 +118,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        cnt_ref[0, 0] = 0
+        if cnt_ref is not None:
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     @pl.when(start < length)
     def _compute():
         q = q_ref[0, 0]                              # [g, D]
-        k = k_ref[0, :, 0]                           # [bc, D]
-        v = v_ref[0, :, 0]
+        k = k_ref[0, 0]                              # [bc, D]
+        v = v_ref[0, 0]
         pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, bc), 1)
         valid = pos < length                         # [1, bc]
         logits = jax.lax.dot_general(
@@ -106,40 +133,75 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
             preferred_element_type=jnp.float32) * scale  # [g, bc]
         logits = jnp.where(valid, logits, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, logits.max(-1))
-        p = jnp.exp(logits - m_new[:, None])
+        m_prev = m_ref[...]                          # [g, 1]
+        m_new = jnp.maximum(m_prev, logits.max(-1, keepdims=True))
+        p = jnp.exp(logits - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(-1)
+        l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
         m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
                             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
-        cnt_ref[0, 0] += 1
+        if cnt_ref is not None:
+            cnt_ref[...] += 1
 
     @pl.when(c_step == n_c_steps - 1)
     def _flush():
         # length-0 rows executed no block: acc == 0 flushes to exact zeros
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                         cnt_ref, m_ref, l_ref, acc_ref, *, bc: int,
-                         n_c_steps: int, scale: float):
+                         *rest, **kw):
     # the block table is consumed by the K/V index_maps (it decides WHICH
     # physical page each grid step streams); the flash body is untouched
     del tbl_ref
-    _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, cnt_ref,
-                   m_ref, l_ref, acc_ref, bc=bc, n_c_steps=n_c_steps,
-                   scale=scale)
+    _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, **kw)
+
+
+def _decode_call(kernel, *, n_prefetch: int, B: int, Kv: int, g: int,
+                 D: int, n_steps: int, blk: int, kv_map, out_dtype,
+                 interpret: Optional[bool], with_counts: bool):
+    """The pallas_call both modes share: only the number of prefetched
+    scalars, the K/V index_map and the block length differ."""
+    def head(b, kv, c, *_):
+        return (b, kv, 0, 0)
+
+    out_specs = [pl.BlockSpec((1, 1, g, D), head)]
+    out_shape = [jax.ShapeDtypeStruct((B, Kv, g, D), out_dtype)]
+    if with_counts:
+        out_specs.append(pl.BlockSpec((1, 1, 1, 1), head))
+        out_shape.append(jax.ShapeDtypeStruct((B, Kv, 1, 1), jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(B, Kv, n_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, g, D), head),
+            pl.BlockSpec((1, 1, blk, D), kv_map),
+            pl.BlockSpec((1, 1, blk, D), kv_map),
+        ],
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, D), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(kernel, bc=blk, n_c_steps=n_steps,
+                               scale=1.0 / math.sqrt(D),
+                               with_counts=with_counts)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret_mode(interpret),
+        name="decode_attention")
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, lengths, block_table,
-                                  *, interpret: bool = True,
+                                  *, interpret: Optional[bool] = None,
                                   return_block_counts: bool = False):
-    """Block-table mode: q: [B,H,D]; k/v_pages: [P,bs,Kv,D] physical page
+    """Block-table mode: q: [B,H,D]; k/v_pages: [P,Kv,bs,D] physical page
     pools; ``block_table`` int [B,max_blocks] maps row b's logical block
     l to a physical page (< 0 = unallocated); lengths int [B] -> [B,H,D].
 
@@ -151,103 +213,48 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, lengths, block_table,
     resident page, and ``pl.when`` skips the update; unallocated entries
     clamp to page 0 (never dereferenced by an in-length step of a
     correctly-tabled row, and row 0 of the pool is a reserved scratch
-    page on the serving path)."""
+    page on the serving path). ``interpret`` defaults to "not on a TPU"."""
     B, H, D = q.shape
-    bs, Kv = k_pages.shape[1], k_pages.shape[2]
+    Kv, bs = k_pages.shape[1], k_pages.shape[2]
     g = H // Kv
     n_blocks = block_table.shape[1]
     C = n_blocks * bs
-
-    qg = q.reshape(B, Kv, g, D)
     lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, C)
     tbl = jnp.asarray(block_table, jnp.int32)
-
-    kv_map = paged_kv_index_map(bs)
-
-    kernel = functools.partial(_paged_decode_kernel, bc=bs,
-                               n_c_steps=n_blocks,
-                               scale=1.0 / math.sqrt(D))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Kv, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, D),
-                         lambda b, kv, c, lens, tbl: (b, kv, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, D),
-                         lambda b, kv, c, lens, tbl: (b, kv, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, kv, c, lens, tbl: (b, kv)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
-        ],
-    )
-    out, counts = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, Kv, g, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, Kv), jnp.int32)],
-        interpret=interpret,
-    )(lens, tbl, qg, k_pages, v_pages)
-    out = out.reshape(B, H, D)
+    outs = _decode_call(
+        _paged_decode_kernel, n_prefetch=2, B=B, Kv=Kv, g=g, D=D,
+        n_steps=n_blocks, blk=bs, kv_map=paged_kv_index_map(bs),
+        out_dtype=q.dtype, interpret=interpret,
+        with_counts=return_block_counts,
+    )(lens, tbl, q.reshape(B, Kv, g, D), k_pages, v_pages)
+    out = outs[0].reshape(B, H, D)
     if return_block_counts:
-        return out, counts
+        return out, outs[1].reshape(B, Kv)
     return out
 
 
 def decode_attention_pallas(q, k_cache, v_cache, lengths, *, bc: int = 512,
-                            interpret: bool = True,
+                            interpret: Optional[bool] = None,
                             return_block_counts: bool = False):
-    """q: [B,H,D]; k/v_cache: [B,C,Kv,D]; lengths: int [B] -> [B,H,D].
+    """q: [B,H,D]; k/v_cache: [B,Kv,C,D]; lengths: int [B] -> [B,H,D].
 
     ``lengths[b]`` is the number of leading cache positions row b attends
     over (the KV ledger's context length); 0 yields a zero output row.
-    ``bc`` is shrunk to the largest divisor of C when it does not tile.
+    ``bc`` is shrunk to a tile-aligned divisor of C when it does not tile
+    (``largest_block_size``). ``interpret`` defaults to "not on a TPU".
     """
     B, H, D = q.shape
-    C, Kv = k_cache.shape[1], k_cache.shape[2]
+    Kv, C = k_cache.shape[1], k_cache.shape[2]
     g = H // Kv
-    bc = largest_block_size(C, bc)
-    n_c = C // bc
-
-    qg = q.reshape(B, Kv, g, D)
+    bc = largest_block_size(C, bc, sublane_tile(k_cache.dtype))
     lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, C)
-
-    kv_map = dense_kv_index_map(bc)
-
-    kernel = functools.partial(_decode_kernel, bc=bc, n_c_steps=n_c,
-                               scale=1.0 / math.sqrt(D))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, Kv, n_c),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, D), lambda b, kv, c, lens: (b, kv, 0, 0)),
-            pl.BlockSpec((1, bc, 1, D), kv_map),
-            pl.BlockSpec((1, bc, 1, D), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, D), lambda b, kv, c, lens: (b, kv, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, kv, c, lens: (b, kv)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
-        ],
-    )
-    out, counts = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, Kv, g, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, Kv), jnp.int32)],
-        interpret=interpret,
-    )(lens, qg, k_cache, v_cache)
-    out = out.reshape(B, H, D)
+    outs = _decode_call(
+        _decode_kernel, n_prefetch=1, B=B, Kv=Kv, g=g, D=D,
+        n_steps=C // bc, blk=bc, kv_map=dense_kv_index_map(bc),
+        out_dtype=q.dtype, interpret=interpret,
+        with_counts=return_block_counts,
+    )(lens, q.reshape(B, Kv, g, D), k_cache, v_cache)
+    out = outs[0].reshape(B, H, D)
     if return_block_counts:
-        return out, counts
+        return out, outs[1].reshape(B, Kv)
     return out

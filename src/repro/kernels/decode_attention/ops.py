@@ -5,10 +5,8 @@ from functools import partial
 
 import jax
 
-from repro.kernels import on_tpu
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_pallas, largest_block_size,
-    paged_decode_attention_pallas)
+    decode_attention_pallas, paged_decode_attention_pallas)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 paged_decode_attention_ref)
 
@@ -16,33 +14,28 @@ from repro.kernels.decode_attention.ref import (decode_attention_ref,
 @partial(jax.jit, static_argnames=("bc", "use_kernel"))
 def decode_attention(q, k_cache, v_cache, lengths, bc: int = 512,
                      use_kernel: bool = True):
-    """q: [B,H,D]; k/v_cache: [B,C,Kv,D]; lengths: int [B] -> [B,H,D].
+    """q: [B,H,D]; k/v_cache: [B,Kv,C,D]; lengths: int [B] -> [B,H,D].
 
-    Any cache length runs: a non-tiling ``bc`` falls back to the largest
-    block size dividing C (C=600 at bc=512 runs at bc=300); only
-    pathological lengths whose best divisor is tiny go to the oracle.
-    """
-    C = k_cache.shape[1]
-    bc_ = largest_block_size(C, bc)
-    if not use_kernel or (bc_ < 16 and C > 16):
+    A non-tiling ``bc`` shrinks to the largest tile-aligned block size
+    dividing C (C=600 at bc=512 runs at bc=200); a cache with no such
+    block raises ``ValueError``. ``use_kernel=False`` is the explicit
+    oracle path."""
+    if not use_kernel:
         return decode_attention_ref(q, k_cache, v_cache, lengths)
-    return decode_attention_pallas(q, k_cache, v_cache, lengths, bc=bc_,
-                                   interpret=not on_tpu())
+    return decode_attention_pallas(q, k_cache, v_cache, lengths, bc=bc)
 
 
 @partial(jax.jit, static_argnames=("use_kernel",))
 def decode_attention_paged(q, k_pages, v_pages, lengths, block_table,
                            use_kernel: bool = True):
-    """Paged-KV decode attention: q [B,H,D]; k/v_pages [P,bs,Kv,D];
+    """Paged-KV decode attention: q [B,H,D]; k/v_pages [P,Kv,bs,D];
     lengths int [B]; block_table int [B,max_blocks] -> [B,H,D].
 
-    The kernel's block size IS the page size, so there is no divisor
-    fallback — pools with pages too small to tile a TPU lane (< 16) take
-    the gather-based oracle instead."""
-    bs = k_pages.shape[1]
-    if not use_kernel or bs < 16:
+    The kernel's block size IS the page size, and a page block spans the
+    pool's whole (bs, D) tail, so every page size tiles.
+    ``use_kernel=False`` is the explicit gather-based oracle path."""
+    if not use_kernel:
         return paged_decode_attention_ref(q, k_pages, v_pages, lengths,
                                           block_table)
     return paged_decode_attention_pallas(q, k_pages, v_pages, lengths,
-                                         block_table,
-                                         interpret=not on_tpu())
+                                         block_table)

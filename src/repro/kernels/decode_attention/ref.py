@@ -1,5 +1,6 @@
-"""Pure-jnp oracle for single-token GQA decode attention over a ragged KV
-cache: row b attends the first ``lengths[b]`` cache positions."""
+"""Pure-jnp oracle for single-token GQA decode attention over a ragged,
+head-major KV cache: row b attends the first ``lengths[b]`` cache
+positions."""
 from __future__ import annotations
 
 import math
@@ -11,34 +12,34 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def decode_attention_ref(q, k_cache, v_cache, lengths):
-    """q: [B,H,D]; k/v_cache: [B,C,Kv,D]; lengths: int [B] -> [B,H,D].
+    """q: [B,H,D]; k/v_cache: [B,Kv,C,D]; lengths: int [B] -> [B,H,D].
 
     Length-0 rows (freshly-freed slots) return exact zeros — a dense
     softmax over an all-masked row would return the mean of V instead.
     """
     B, H, D = q.shape
-    C, Kv = k_cache.shape[1], k_cache.shape[2]
+    Kv, C = k_cache.shape[1], k_cache.shape[2]
     g = H // Kv
     lengths = jnp.asarray(lengths, jnp.int32)
     qh = q.reshape(B, Kv, g, D).astype(jnp.float32)
     valid = jnp.arange(C)[None, :] < lengths[:, None]          # [B, C]
-    logits = jnp.einsum("bkgd,bskd->bkgs", qh,
+    logits = jnp.einsum("bkgd,bksd->bkgs", qh,
                         k_cache.astype(jnp.float32)) / math.sqrt(D)
     logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bksd->bkgd", p, v_cache.astype(jnp.float32))
     out = jnp.where((lengths > 0)[:, None, None, None], out, 0.0)
     return out.reshape(B, H, D).astype(q.dtype)
 
 
 def gather_pages(pages, block_table):
-    """[P,bs,Kv,D] pages + int [B,n] table -> dense [B, n*bs, Kv, D].
+    """[P,Kv,bs,D] pages + int [B,n] table -> dense [B, Kv, n*bs, D].
     Unallocated entries (< 0) clamp to page 0; their positions sit past
     the row's length, so the masked attention never reads them."""
     B, n = block_table.shape
-    bs, Kv, D = pages.shape[1], pages.shape[2], pages.shape[3]
+    Kv, bs, D = pages.shape[1], pages.shape[2], pages.shape[3]
     tbl = jnp.maximum(jnp.asarray(block_table, jnp.int32), 0)
-    return pages[tbl].reshape(B, n * bs, Kv, D)
+    return pages[tbl].transpose(0, 2, 1, 3, 4).reshape(B, Kv, n * bs, D)
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, lengths, block_table):

@@ -15,11 +15,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -84,7 +87,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
                            bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: [B,S,H,D]; k/v: [B,S,Kv,D] -> [B,S,H,D]."""
     B, S, H, D = q.shape
     Kv = k.shape[2]
@@ -118,6 +121,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)

@@ -5,7 +5,6 @@ from functools import partial
 
 import jax
 
-from repro.kernels import on_tpu
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
@@ -19,4 +18,4 @@ def flash_attention(q, k, v, causal: bool = True, window=None,
     if not use_kernel or S % bq_ or S % bk_:
         return flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  bq=bq_, bk=bk_, interpret=not on_tpu())
+                                  bq=bq_, bk=bk_)

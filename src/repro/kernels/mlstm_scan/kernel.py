@@ -14,11 +14,14 @@ with the [bs, D] streaming blocks.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, cin_ref, nin_ref,
@@ -63,7 +66,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, cin_ref, nin_ref,
 
 
 def mlstm_scan_pallas(q, k, v, i_gate, log_f, C0, n0, m0, *, bs: int = 128,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """q,k,v: [B,H,S,D]; i_gate/log_f: [B,H,S]; C0: [B,H,D,D];
     n0: [B,H,D]; m0: [B,H]. Returns (h [B,H,S,D], C, n, m)."""
     B, H, S, D = q.shape
@@ -103,6 +106,6 @@ def mlstm_scan_pallas(q, k, v, i_gate, log_f, C0, n0, m0, *, bs: int = 128,
             pltpu.VMEM((D,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v, i_gate, log_f, C0, n0, m0e)
     return h, C, n, m[..., 0]

@@ -5,7 +5,6 @@ from functools import partial
 
 import jax
 
-from repro.kernels import on_tpu
 from repro.kernels.mlstm_scan.kernel import mlstm_scan_pallas
 from repro.kernels.mlstm_scan.ref import mlstm_scan_ref
 
@@ -17,5 +16,4 @@ def mlstm_scan(q, k, v, i_gate, log_f, C0, n0, m0, bs: int = 128,
     bs_ = min(bs, S)
     if not use_kernel or S % bs_:
         return mlstm_scan_ref(q, k, v, i_gate, log_f, C0, n0, m0)
-    return mlstm_scan_pallas(q, k, v, i_gate, log_f, C0, n0, m0, bs=bs_,
-                             interpret=not on_tpu())
+    return mlstm_scan_pallas(q, k, v, i_gate, log_f, C0, n0, m0, bs=bs_)

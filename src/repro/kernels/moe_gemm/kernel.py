@@ -15,11 +15,14 @@ VMEM footprint per step (bc=128, bh=512, M<=4096, bf16):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
@@ -43,7 +46,7 @@ def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
 
 
 def moe_gemm_pallas(x, w_gate, w_up, w_down, *, bc: int = 128,
-                    bh: int = 512, interpret: bool = True):
+                    bh: int = 512, interpret: Optional[bool] = None):
     """x: [E, C, M]; w_gate/w_up: [E, M, H]; w_down: [E, H, M] -> [E, C, M]."""
     E, C, M = x.shape
     H = w_gate.shape[-1]
@@ -66,5 +69,5 @@ def moe_gemm_pallas(x, w_gate, w_up, w_down, *, bc: int = 128,
         out_specs=pl.BlockSpec((1, bc, M), lambda e, c, h: (e, c, 0)),
         out_shape=jax.ShapeDtypeStruct((E, C, M), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, M), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w_gate, w_up, w_down)

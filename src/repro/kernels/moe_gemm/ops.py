@@ -5,7 +5,6 @@ from functools import partial
 
 import jax
 
-from repro.kernels import on_tpu
 from repro.kernels.moe_gemm.kernel import moe_gemm_pallas
 from repro.kernels.moe_gemm.ref import moe_gemm_ref
 
@@ -21,5 +20,4 @@ def moe_gemm(x, w_gate, w_up, w_down, bc: int = 128, bh: int = 512,
     bh_ = min(bh, H)
     if not use_kernel or C % bc_ or H % bh_:
         return moe_gemm_ref(x, w_gate, w_up, w_down)
-    return moe_gemm_pallas(x, w_gate, w_up, w_down, bc=bc_, bh=bh_,
-                           interpret=not on_tpu())
+    return moe_gemm_pallas(x, w_gate, w_up, w_down, bc=bc_, bh=bh_)

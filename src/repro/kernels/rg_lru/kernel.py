@@ -12,11 +12,14 @@ having over lax.scan on TPU.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, carry_ref, *,
@@ -44,7 +47,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, carry_ref, *,
 
 
 def rg_lru_scan_pallas(a, b, h0, *, bs: int = 256, bw: int = 512,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """a, b: [B, S, W]; h0: [B, W] -> (h [B, S, W], h_last [B, W])."""
     B, S, W = a.shape
     bs = min(bs, S)
@@ -70,6 +73,6 @@ def rg_lru_scan_pallas(a, b, h0, *, bs: int = 256, bw: int = 512,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a, b, h0)
     return h, h_last

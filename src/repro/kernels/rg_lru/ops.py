@@ -5,7 +5,6 @@ from functools import partial
 
 import jax
 
-from repro.kernels import on_tpu
 from repro.kernels.rg_lru.kernel import rg_lru_scan_pallas
 from repro.kernels.rg_lru.ref import rg_lru_scan_ref
 
@@ -17,5 +16,4 @@ def rg_lru_scan(a, b, h0, bs: int = 256, bw: int = 512,
     bs_, bw_ = min(bs, S), min(bw, W)
     if not use_kernel or S % bs_ or W % bw_:
         return rg_lru_scan_ref(a, b, h0)
-    return rg_lru_scan_pallas(a, b, h0, bs=bs_, bw=bw_,
-                              interpret=not on_tpu())
+    return rg_lru_scan_pallas(a, b, h0, bs=bs_, bw=bw_)

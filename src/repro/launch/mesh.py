@@ -1,4 +1,4 @@
-"""Production mesh builders.
+"""Mesh builders: every mesh in the repo comes from ``make_mesh``.
 
 Defined as FUNCTIONS (not module-level constants) so importing this module
 never touches jax device state — the dry-run sets
@@ -7,17 +7,29 @@ and only then calls make_production_mesh().
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None):
+    """A mesh with Auto axis types. ``jax.make_mesh`` alone gives Explicit
+    axes, under which GSPMD no longer propagates shardings through the
+    engine's attention einsums (they fail as illegally sharded). Pass
+    ``devices`` to build over a described topology's devices."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: (16, 16) ("data", "model") = 256 chips.
     Multi-pod:  (2, 16, 16) ("pod", "data", "model") = 512 chips."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(
-        shape, axes,
-        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh(model: int = 1, data: int = 0):
@@ -25,6 +37,4 @@ def make_host_mesh(model: int = 1, data: int = 0):
     n = len(jax.devices())
     model = max(1, min(model, n))
     data = data or n // model
-    return jax.make_mesh(
-        (data, model), ("data", "model"),
-        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return make_mesh((data, model), ("data", "model"))

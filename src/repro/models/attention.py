@@ -2,12 +2,16 @@
 full or ring-buffer KV caches, and MLA (DeepSeek-V2 latent attention).
 
 All apply functions are pure; KV caches are explicit pytrees:
-  full cache: {"k": [B,C,Kv,Dh], "v": [B,C,Kv,Dh], "index": i32[]}
+  full cache: {"k": [B,Kv,C,Dh], "v": [B,Kv,C,Dh], "index": i32[]}
   ring cache: same arrays with C == window; writes wrap at C.
 MLA cache:    {"ckv": [B,C,r], "kpe": [B,C,Dh], "index": i32[]}
+K/V caches are head-major so the decode kernel streams (positions, Dh)
+blocks of one KV head, which tile on the TPU; ``SEQ_AXIS`` names each
+cache entry's position axis.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -18,6 +22,9 @@ from repro.configs.base import ModelConfig
 from repro.models.layers import apply_rope, dense_apply, dense_init
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+#: position axis of each cache entry (K/V head-major, MLA latents not)
+SEQ_AXIS = {"k": 2, "v": 2, "ckv": 1, "kpe": 1}
 
 
 # ---------------------------------------------------------------------------
@@ -60,8 +67,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int,
             "index": jnp.zeros((), jnp.int32),
         }
     return {
-        "k": jnp.zeros((batch, capacity, cfg.num_kv_heads, hd), dtype),
-        "v": jnp.zeros((batch, capacity, cfg.num_kv_heads, hd), dtype),
+        "k": jnp.zeros((batch, cfg.num_kv_heads, capacity, hd), dtype),
+        "v": jnp.zeros((batch, cfg.num_kv_heads, capacity, hd), dtype),
         "index": jnp.zeros((), jnp.int32),
     }
 
@@ -194,7 +201,7 @@ def _project_qkv(params, cfg: ModelConfig, x, positions):
     v = dense_apply(params["wv"], x).reshape(B, S, cfg.num_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    return q, k, v, {"k": k, "v": v}
+    return q, k, v, {"k": k.swapaxes(1, 2), "v": v.swapaxes(1, 2)}
 
 
 def attention_fullseq(params, cfg: ModelConfig, x, positions,
@@ -211,7 +218,8 @@ def attention_fullseq(params, cfg: ModelConfig, x, positions,
     if impl == "flash" and cfg.mla_kv_lora_rank == 0:
         from repro.kernels.flash_attention import ops as flash_ops
         out = flash_ops.flash_attention(q, k, v, causal=True, window=window)
-    elif impl == "chunked" or (impl == "xla" and S > 2048):
+    elif impl == "chunked" or (impl in ("xla", "decode_kernel")
+                               and S > 2048):
         out = _flash_sdpa_xla(q, k, v, positions[0], positions[0], window)
     else:
         mask = _causal_mask(positions[0], positions[0], window)
@@ -224,19 +232,25 @@ def attention_fullseq(params, cfg: ModelConfig, x, positions,
     return out, new_cache
 
 
+def _cache_len(cfg: ModelConfig, cache) -> int:
+    name = "ckv" if cfg.mla_kv_lora_rank else "k"
+    return cache[name].shape[SEQ_AXIS[name]]
+
+
 def _prefill_cache(cfg: ModelConfig, cache, to_cache, S: int):
-    C = (cache["ckv"] if cfg.mla_kv_lora_rank else cache["k"]).shape[1]
+    C = _cache_len(cfg, cache)
     new = dict(cache)
     keep = min(S, C)
     for name, val in to_cache.items():
-        seg = val[:, S - keep:S]
+        ax = SEQ_AXIS[name]
+        seg = jax.lax.slice_in_dim(val, S - keep, S, axis=ax)
         if keep == C and S % C:
             # ring-cache invariant: slot s holds absolute position == s (mod
             # C). Token at absolute pos p lands in slot p % C; the kept
             # segment covers positions [S-C, S), so roll by (S-C) % C == S%C.
-            seg = jnp.roll(seg, S % C, axis=1)
+            seg = jnp.roll(seg, S % C, axis=ax)
         new[name] = jax.lax.dynamic_update_slice_in_dim(
-            cache[name], seg.astype(cache[name].dtype), 0, axis=1)
+            cache[name], seg.astype(cache[name].dtype), 0, axis=ax)
     new["index"] = jnp.asarray(S, jnp.int32)
     return new
 
@@ -262,15 +276,14 @@ def use_seqsharded_decode(cfg: ModelConfig, mesh, axis: str,
 
 def _decode_core_seqsharded(q, k_new, v_new, cache_k, cache_v, index,
                             mesh, axis: str, batch_axes, is_ring: bool):
-    """q: [B,1,H,Dh]; k_new/v_new: [B,1,Kv,Dh]; cache_[kv]: [B,C,Kv,Dh]
+    """q: [B,1,H,Dh]; k_new/v_new: [B,Kv,1,Dh]; cache_[kv]: [B,Kv,C,Dh]
     sequence-sharded over ``axis``. Returns (out [B,1,H,Dh], new caches).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mo = mesh.shape[axis]
-    B, C = cache_k.shape[0], cache_k.shape[1]
-    Kv, Dh = cache_k.shape[2], cache_k.shape[3]
+    B, Kv = cache_k.shape[0], cache_k.shape[1]
+    C, Dh = cache_k.shape[2], cache_k.shape[3]
     H = q.shape[2]
     g = H // Kv
     C_loc = C // mo
@@ -284,9 +297,9 @@ def _decode_core_seqsharded(q, k_new, v_new, cache_k, cache_v, index,
         in_range = (loc >= 0) & (loc < C_loc)
         loc_c = jnp.clip(loc, 0, C_loc - 1)
         upd_k = jax.lax.dynamic_update_slice_in_dim(
-            ck, k_new.astype(ck.dtype), loc_c, 1)
+            ck, k_new.astype(ck.dtype), loc_c, 2)
         upd_v = jax.lax.dynamic_update_slice_in_dim(
-            cv, v_new.astype(cv.dtype), loc_c, 1)
+            cv, v_new.astype(cv.dtype), loc_c, 2)
         ck = jnp.where(in_range, upd_k, ck)
         cv = jnp.where(in_range, upd_v, cv)
 
@@ -299,7 +312,7 @@ def _decode_core_seqsharded(q, k_new, v_new, cache_k, cache_v, index,
             valid = slots <= index
 
         qh = q.reshape(q.shape[0], Kv, g, Dh)
-        logits = jnp.einsum("bkgd,bskd->bkgs", qh, ck.astype(qh.dtype),
+        logits = jnp.einsum("bkgd,bksd->bkgs", qh, ck.astype(qh.dtype),
                             preferred_element_type=jnp.float32) * scale
         logits = jnp.where(valid[None, None, None], logits, NEG_INF)
         m_loc = logits.max(-1)
@@ -307,18 +320,18 @@ def _decode_core_seqsharded(q, k_new, v_new, cache_k, cache_v, index,
         p = jnp.exp(logits - m[..., None])
         p = jnp.where(valid[None, None, None], p, 0.0)
         l = jax.lax.psum(p.sum(-1), axis)
-        o = jnp.einsum("bkgs,bskd->bkgd", p.astype(cv.dtype), cv)
+        o = jnp.einsum("bkgs,bksd->bkgd", p.astype(cv.dtype), cv)
         o = jax.lax.psum(o.astype(jnp.float32), axis)
         out = (o / jnp.maximum(l, 1e-30)[..., None])
         return out.reshape(q.shape[0], 1, H, Dh).astype(q.dtype), ck, cv
 
-    cache_spec = P(b, axis, None, None)
-    out, ck, cv = shard_map(
+    cache_spec = P(b, None, axis, None)
+    out, ck, cv = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, None, None), P(b, None, None, None),
                   P(b, None, None, None), cache_spec, cache_spec, P()),
         out_specs=(P(b, None, None, None), cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )(q[:, 0], k_new, v_new, cache_k, cache_v, index)
     return out, ck, cv
 
@@ -326,6 +339,28 @@ def _decode_core_seqsharded(q, k_new, v_new, cache_k, cache_v, index,
 # ---------------------------------------------------------------------------
 # single-token decode
 # ---------------------------------------------------------------------------
+
+def _per_device(kernel, mesh, axis: str, q, k, v, lens, *table,
+                paged: bool = False):
+    """Run a decode kernel on a mesh. GSPMD cannot partition a Mosaic
+    kernel, so on more than one device each device runs it on its own KV
+    heads (split over ``axis``; the GQA groups stay whole, since H and Kv
+    split alike) and its own batch rows (over the data axes that divide
+    B). Page pools hold every row's pages, so they split by head only."""
+    if mesh is None or mesh.size == 1:
+        return kernel(q, k, v, lens, *table)
+    from jax.sharding import PartitionSpec as P
+    from repro.sharding.partition import batch_pspec
+    b = batch_pspec(q.shape[0], mesh, exclude=(axis,))[0]
+    kv_spec = (P(None, axis, None, None) if paged
+               else P(b, axis, None, None))
+    in_specs = (P(b, axis, None), kv_spec, kv_spec, P(b))
+    if table:
+        in_specs += (P(b, None),)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(b, axis, None),
+                         check_vma=False)(q, k, v, lens, *table)
+
 
 def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
                      ctx=None, lengths=None, block_table=None):
@@ -350,12 +385,23 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
                  else index[:, None].astype(jnp.int32))
     q, k, v, to_cache = _project_qkv(params, cfg, x, positions)
 
-    C = (cache["ckv"] if cfg.mla_kv_lora_rank else cache["k"]).shape[1]
+    C = _cache_len(cfg, cache)
     is_ring = cfg.attention in ("sliding", "local")
+    if impl == "decode_kernel" and (cfg.mla_kv_lora_rank or is_ring):
+        raise ValueError(
+            "the decode kernel takes full-attention GQA caches "
+            f"(attention={cfg.attention!r}, mla={cfg.mla_kv_lora_rank}); "
+            "pass attn_impl='xla' for this model")
 
     mesh = getattr(ctx, "mesh", None)
     axis = getattr(ctx, "expert_axis", "model")
     if index.ndim == 0 and use_seqsharded_decode(cfg, mesh, axis, C):
+        if impl == "decode_kernel":
+            raise ValueError(
+                f"{cfg.num_kv_heads} KV heads do not divide the "
+                f"{mesh.shape[axis]}-way {axis!r} axis: the cache is "
+                "sequence-sharded and the decode kernel cannot take it; "
+                "pass attn_impl='xla'")
         from repro.sharding.partition import batch_pspec
         bspec = batch_pspec(cache["k"].shape[0], mesh)
         b_axes = bspec[0] if bspec != jax.sharding.PartitionSpec(None) else None
@@ -372,13 +418,19 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
     if index.ndim == 0:
         for name, val in to_cache.items():
             new_cache[name] = jax.lax.dynamic_update_slice_in_dim(
-                cache[name], val.astype(cache[name].dtype), slot, axis=1)
+                cache[name], val.astype(cache[name].dtype), slot,
+                axis=SEQ_AXIS[name])
     else:
         # per-sample indices (continuous batching): scatter one row each
         batch_ix = jnp.arange(B)
         for name, val in to_cache.items():
-            new_cache[name] = cache[name].at[batch_ix, slot].set(
-                val[:, 0].astype(cache[name].dtype))
+            dt = cache[name].dtype
+            if SEQ_AXIS[name] == 2:
+                new_cache[name] = cache[name].at[batch_ix, :, slot].set(
+                    val[:, :, 0].astype(dt))
+            else:
+                new_cache[name] = cache[name].at[batch_ix, slot].set(
+                    val[:, 0].astype(dt))
     new_cache["index"] = index + 1
 
     # per-row attended prefix (non-ring): the ledger's context length when
@@ -403,25 +455,26 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
             lens = jnp.minimum(index.astype(jnp.int32) + 1, C)
         mask = slots[None, None, :] < lens[:, None, None]      # [B, 1, C]
 
-    if cfg.mla_kv_lora_rank:
-        ckv_all, kpe_all = new_cache["ckv"], new_cache["kpe"]
-        kv = dense_apply(params["w_ukv"], ckv_all.astype(x.dtype))
-        kv = kv.reshape(B, C, cfg.num_heads, 2 * hd)
-        k_all, v_all = jnp.split(kv, 2, axis=-1)
-        k_all = k_all + kpe_all.astype(x.dtype)[:, :, None, :]
-    else:
-        k_all, v_all = (new_cache["k"].astype(x.dtype),
-                        new_cache["v"].astype(x.dtype))
-
-    if impl == "decode_kernel" and cfg.mla_kv_lora_rank == 0 and not is_ring:
+    if impl == "decode_kernel":
         # the serving path: ragged Pallas kernel streams ceil(len/bc)
         # blocks per row instead of the dense [B, C] cache
         from repro.kernels.decode_attention import ops as dec_ops
-        bc = getattr(ctx, "decode_bc", None)
-        out = dec_ops.decode_attention(q[:, 0], k_all, v_all, lens,
-                                       bc=bc or 512)
+        bc = getattr(ctx, "decode_bc", None) or 512
+        out = _per_device(
+            functools.partial(dec_ops.decode_attention, bc=bc), mesh, axis,
+            q[:, 0], new_cache["k"].astype(x.dtype),
+            new_cache["v"].astype(x.dtype), lens)
         out = out[:, None]
     else:
+        if cfg.mla_kv_lora_rank:
+            ckv_all, kpe_all = new_cache["ckv"], new_cache["kpe"]
+            kv = dense_apply(params["w_ukv"], ckv_all.astype(x.dtype))
+            kv = kv.reshape(B, C, cfg.num_heads, 2 * hd)
+            k_all, v_all = jnp.split(kv, 2, axis=-1)
+            k_all = k_all + kpe_all.astype(x.dtype)[:, :, None, :]
+        else:
+            k_all = new_cache["k"].astype(x.dtype).swapaxes(1, 2)
+            v_all = new_cache["v"].astype(x.dtype).swapaxes(1, 2)
         out = _sdpa(q, k_all, v_all, mask)
     out = dense_apply(params["wo"], out.reshape(B, 1, -1))
     return out, new_cache
@@ -430,7 +483,7 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
 def _attention_decode_paged(params, cfg: ModelConfig, x, cache, impl, ctx,
                             lengths, block_table):
     """Paged-KV decode: the cache's k/v are physical page pools
-    ``[P, bs, Kv, D]`` shared by every slot, and ``block_table`` (int
+    ``[P, Kv, bs, D]`` shared by every slot, and ``block_table`` (int
     [B, max_blocks]) maps each row's logical blocks to pages (< 0 =
     unallocated). The new token scatters into its row's tail page at
     ``index % bs``; dead rows (no pages) clamp to the reserved scratch
@@ -442,7 +495,7 @@ def _attention_decode_paged(params, cfg: ModelConfig, x, cache, impl, ctx,
             "paged KV decode requires full-attention GQA "
             f"(attention={cfg.attention!r}, mla={cfg.mla_kv_lora_rank})")
     B = x.shape[0]
-    bs = cache["k"].shape[1]
+    bs = cache["k"].shape[2]
     C = block_table.shape[1] * bs
     index = jnp.asarray(cache["index"])
     if index.ndim == 0:
@@ -455,8 +508,8 @@ def _attention_decode_paged(params, cfg: ModelConfig, x, cache, impl, ctx,
     phys = jnp.maximum(tbl[jnp.arange(B), pos // bs], 0)
     new_cache = dict(cache)
     for name, val in to_cache.items():
-        new_cache[name] = cache[name].at[phys, pos % bs].set(
-            val[:, 0].astype(cache[name].dtype))
+        new_cache[name] = cache[name].at[phys, :, pos % bs].set(
+            val[:, :, 0].astype(cache[name].dtype))
     new_cache["index"] = index + 1
 
     if lengths is not None:
@@ -466,13 +519,17 @@ def _attention_decode_paged(params, cfg: ModelConfig, x, cache, impl, ctx,
 
     if impl == "decode_kernel":
         from repro.kernels.decode_attention import ops as dec_ops
-        out = dec_ops.decode_attention_paged(q[:, 0], new_cache["k"],
-                                             new_cache["v"], lens, tbl)
+        out = _per_device(dec_ops.decode_attention_paged,
+                          getattr(ctx, "mesh", None),
+                          getattr(ctx, "expert_axis", "model"),
+                          q[:, 0], new_cache["k"], new_cache["v"], lens,
+                          tbl, paged=True)
         out = out[:, None]
     else:
         from repro.kernels.decode_attention.ref import gather_pages
         k_all = gather_pages(new_cache["k"], tbl).astype(x.dtype)
         v_all = gather_pages(new_cache["v"], tbl).astype(x.dtype)
+        k_all, v_all = k_all.swapaxes(1, 2), v_all.swapaxes(1, 2)
         mask = (jnp.arange(C, dtype=jnp.int32)[None, None, :]
                 < lens[:, None, None])                         # [B, 1, C]
         out = _sdpa(q, k_all, v_all, mask)

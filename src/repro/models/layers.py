@@ -2,8 +2,9 @@
 
 Parameters are nested dicts of jnp arrays. Every layer provides
 ``init_*(key, ...) -> params`` and a pure apply function. Weights are
-created in float32 and cast to the compute dtype at apply time by the
-caller (mixed-precision policy lives in repro.models.transformer).
+created in float32; apply functions cast them to the compute dtype, which
+is free when they are already held in it (the serving engine holds its
+weights in its serving dtype).
 """
 from __future__ import annotations
 
@@ -47,8 +48,10 @@ def embedding_apply(p, tokens, dtype=jnp.bfloat16):
 
 
 def embedding_attend(p, x):
-    """Tied readout: logits = x @ E^T (computed in float32)."""
-    return jnp.asarray(x, jnp.float32) @ p["embedding"].astype(jnp.float32).T
+    """Tied readout: logits = x @ E^T, accumulated in float32."""
+    e = p["embedding"]
+    return jnp.dot(x.astype(e.dtype), e.T,
+                   preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -272,36 +272,76 @@ class Model:
         self.num_groups = len(self.kinds) // len(self.group)
 
     # ---- init -----------------------------------------------------------
-    def init(self, key) -> Dict[str, Any]:
+    def init(self, key, dtype=None, shardings=None) -> Dict[str, Any]:
+        """Float32 parameters. With ``dtype``, the same values cast to
+        it: each layer is built and cast under a jit compiled once per
+        layer kind and called per layer, so no float32 copy of the whole
+        model is ever live and the compile does not grow with depth.
+        ``shardings`` (a tree like the result; layers of one kind share a
+        placement) places each array as it is made."""
         cfg = self.cfg
-        keys = jax.random.split(key, 8)
-        params: Dict[str, Any] = {
-            "embed": embedding_init(keys[0], cfg.vocab_size, cfg.d_model),
-            "final_norm": rmsnorm_init(cfg.d_model),
-        }
-        if not cfg.tie_embeddings:
-            params["lm_head"] = dense_init(keys[1], cfg.d_model,
-                                           cfg.vocab_size)
         cross = cfg.is_encoder_decoder
+        jits: Dict[Any, Callable] = {}
+
+        def make(name, fn, k, sharding_of):
+            if dtype is None:
+                return fn(k)
+            if name not in jits:
+                jits[name] = jax.jit(
+                    lambda kk: jax.tree.map(lambda a: a.astype(dtype),
+                                            fn(kk)),
+                    out_shardings=(sharding_of(shardings)
+                                   if shardings is not None else None))
+            return jits[name](k)
+
+        def top(keys):
+            p: Dict[str, Any] = {
+                "embed": embedding_init(keys[0], cfg.vocab_size, cfg.d_model),
+                "final_norm": rmsnorm_init(cfg.d_model),
+            }
+            if not cfg.tie_embeddings:
+                p["lm_head"] = dense_init(keys[1], cfg.d_model,
+                                          cfg.vocab_size)
+            return p
+
+        keys = jax.random.split(key, 8)
+        params = make("top", top, keys,
+                      lambda s: {n: s[n] for n in ("embed", "final_norm",
+                                                   "lm_head") if n in s})
         if self.scan_layers:
             def init_group(gkey):
                 gks = jax.random.split(gkey, len(self.group))
                 return [init_layer(gks[i], cfg, kind, self.E_pad, cross)
                         for i, kind in enumerate(self.group)]
-            gkeys = jax.random.split(keys[2], self.num_groups)
-            params["layer_groups"] = jax.vmap(init_group)(gkeys)
+
+            def groups(k):
+                return jax.vmap(init_group)(
+                    jax.random.split(k, self.num_groups))
+            params["layer_groups"] = make("groups", groups, keys[2],
+                                          lambda s: s["layer_groups"])
         else:
             lkeys = jax.random.split(keys[2], len(self.kinds))
-            params["layers"] = [init_layer(lkeys[i], cfg, kind, self.E_pad,
-                                           cross)
-                                for i, kind in enumerate(self.kinds)]
+            params["layers"] = [
+                make(("layer", kind),
+                     functools.partial(init_layer, cfg=cfg, kind=kind,
+                                       num_experts_padded=self.E_pad,
+                                       cross=cross),
+                     lkeys[i], lambda s, i=i: s["layers"][i])
+                for i, kind in enumerate(self.kinds)]
         if cfg.is_encoder_decoder:
             ekeys = jax.random.split(keys[3], cfg.num_encoder_layers + 1)
-            params["enc_layers"] = [init_layer(ekeys[i], cfg, "attn_mlp")
-                                    for i in range(cfg.num_encoder_layers)]
-            params["enc_norm"] = rmsnorm_init(cfg.d_model)
+            params["enc_layers"] = [
+                make("enc_layer",
+                     functools.partial(init_layer, cfg=cfg, kind="attn_mlp"),
+                     ekeys[i], lambda s, i=i: s["enc_layers"][i])
+                for i in range(cfg.num_encoder_layers)]
+            params["enc_norm"] = make(
+                "enc_norm", lambda _: rmsnorm_init(cfg.d_model), keys[3],
+                lambda s: s["enc_norm"])
         if cfg.family == "vlm":
-            params["proj"] = dense_init(keys[4], cfg.d_model, cfg.d_model)
+            params["proj"] = make(
+                "proj", lambda k: dense_init(k, cfg.d_model, cfg.d_model),
+                keys[4], lambda s: s["proj"])
         return params
 
     # ---- encoder (audio family) ------------------------------------------
@@ -400,10 +440,13 @@ class Model:
         return x, new_caches, aux
 
     def _readout(self, params, x):
+        """Float32 logits, accumulated in float32 from operands in the
+        head's own dtype (no float32 copy of a bf16 head)."""
         if self.cfg.tie_embeddings:
             return embedding_attend(params["embed"], x)
-        return dense_apply(params["lm_head"],
-                           x.astype(jnp.float32))
+        w = params["lm_head"]["kernel"]
+        return jnp.dot(x.astype(w.dtype), w,
+                       preferred_element_type=jnp.float32)
 
     # ---- caches ------------------------------------------------------------
     def init_cache(self, batch: int, seq_len: int, dtype=jnp.bfloat16):

@@ -41,7 +41,8 @@ def device_mesh(min_devices: int = 2):
     n = jax.device_count()
     if n < min_devices or n % 2:
         return None
-    return jax.make_mesh((n // 2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((n // 2, 2), ("data", "model"))
 
 
 @dataclass

@@ -156,7 +156,6 @@ def measure_all_to_all(mesh=None, axis: str = "model",
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     dtype = dtype or jnp.float32
     sizes = COMM_SWEEP_BYTES if sizes_bytes is None else sizes_bytes
     itemsize = jnp.dtype(dtype).itemsize
@@ -177,8 +176,8 @@ def measure_all_to_all(mesh=None, axis: str = "model",
                 return jax.lax.all_to_all(xl, axis, split_axis=0,
                                           concat_axis=1, tiled=True)
 
-            f = jax.jit(shard_map(a2a, mesh=mesh, in_specs=P(axis),
-                                  out_specs=P(axis)))
+            f = jax.jit(jax.shard_map(a2a, mesh=mesh, in_specs=P(axis),
+                                      out_specs=P(axis)))
             z_dev = float(mo * c * itemsize)
         else:
             # proxy: a bytes-proportional on-device copy. Keeps the fit
@@ -223,8 +222,8 @@ def measure_decode_attention(shapes: Optional[Sequence[Tuple[int, int, float]]]
         # exactly the bytes the sample claims
         c_eff = max(int(C * fill), 16)
         q = jax.random.normal(key, (B, kv, d), dtype)
-        k = jax.random.normal(key, (B, c_eff, kv, d), dtype)
-        v = jax.random.normal(key, (B, c_eff, kv, d), dtype)
+        k = jax.random.normal(key, (B, kv, c_eff, d), dtype)
+        v = jax.random.normal(key, (B, kv, c_eff, d), dtype)
         lens = jnp.full((B,), c_eff, jnp.int32)
         out.xs.append(float(B * c_eff * kv * 2 * d * itemsize))
         out.ts.append(time_fn(f, q, k, v, lens, warmup=warmup, iters=iters))

@@ -162,8 +162,6 @@ def analyze(name: str, compiled, model_flops_global: float, chips: int,
             peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW,
             link_bw: float = LINK_BW) -> Roofline:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     stats = parse_collectives(compiled.as_text())

@@ -76,6 +76,35 @@ from repro.sched import (FinDEPPolicy, OccupancySummary, PlanCache,
                          SchedulePolicy)
 
 
+def check_decode_kernel(cfg: ModelConfig, *, mesh=None,
+                        max_context: int, kv_layout: str = "dense",
+                        decode_bc: Optional[int] = None,
+                        dtype=jnp.float32) -> None:
+    """Raise ``ValueError`` unless the ragged decode kernel can serve every
+    attention layer of ``cfg`` at these cache shapes: full-attention GQA
+    caches (no MLA latents, no ring windows), KV heads that the mesh's
+    expert axis divides (each device runs the kernel on its own heads),
+    and, for the dense layout, a context length the kernel can tile."""
+    from repro.kernels.decode_attention.kernel import (largest_block_size,
+                                                       sublane_tile)
+    if not cfg.uses_attention:
+        return                  # no layer decodes through a KV cache
+    if cfg.mla_kv_lora_rank or cfg.attention in ("sliding", "local"):
+        raise ValueError(
+            "the decode kernel takes full-attention GQA caches; "
+            f"{cfg.name} has attention={cfg.attention!r}, "
+            f"mla={cfg.mla_kv_lora_rank}: pass attn_impl='xla'")
+    mo = mesh.shape.get("model", 1) if mesh is not None else 1
+    if cfg.num_kv_heads % mo:
+        raise ValueError(
+            f"{cfg.num_kv_heads} KV heads do not divide the mesh's "
+            f"{mo}-way 'model' axis; the decode kernel runs on each "
+            "device's own KV heads: pass attn_impl='xla'")
+    if kv_layout == "dense":
+        largest_block_size(max_context, decode_bc or 512,
+                           sublane_tile(dtype))
+
+
 @dataclass
 class EngineStats:
     prefill_tokens: int = 0
@@ -215,9 +244,14 @@ class ServingEngine:
                 refresher=self.drift.refresher if self.drift else None,
                 timer=self.telemetry, metrics=self.metrics)
         # decode attention defaults to the ragged Pallas kernel: per-slot
-        # ledger lengths let it skip KV blocks past each row's context
-        # (attention_decode falls back to dense SDPA for MLA/ring caches);
-        # attn_impl="xla" restores the dense path for A/B parity checks
+        # ledger lengths let it skip KV blocks past each row's context.
+        # The choice is settled here: a model or cache shape the kernel
+        # cannot take raises now, not at the first decode trace;
+        # attn_impl="xla" is the explicit dense path (A/B parity checks)
+        if attn_impl == "decode_kernel":
+            check_decode_kernel(cfg, mesh=mesh, max_context=max_context,
+                                kv_layout=kv_layout, decode_bc=decode_bc,
+                                dtype=dtype)
         ctx = ExecutionContext(
             mesh=mesh,
             attn_impl=attn_impl,
@@ -249,8 +283,20 @@ class ServingEngine:
         self._validated_programs: set = set()
         self.cfg = cfg
         self.model = build_model(cfg, ctx=ctx, dtype=dtype)
-        self.params = params if params is not None else self.model.init(
-            jax.random.PRNGKey(seed))
+        # on a mesh the parameters are placed ONCE: routed experts split
+        # over the expert axis (what the DEP executor's shard_map
+        # expects), everything else replicated
+        self.param_shardings = None
+        if mesh is not None:
+            from repro.sharding.partition import expert_parallel_shardings
+            shapes = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
+            self.param_shardings = expert_parallel_shardings(
+                shapes, mesh, ctx.expert_axis)
+        if params is None:
+            params = self._init_params(jax.random.PRNGKey(seed), dtype)
+        if self.param_shardings is not None:
+            params = jax.device_put(params, self.param_shardings)
+        self.params = params
         # expert placement subsystem (observe -> place -> plan): gate loads
         # feed an EWMA tracker; a threshold breach re-solves the expert ->
         # rank map (+ hot replicas) on the refresh worker; the new layout
@@ -332,6 +378,12 @@ class ServingEngine:
         self.waiting: List[Request] = []
         self.finished: List[Request] = []
         self.stats = EngineStats()
+        #: float32 logits [num_slots, V] of the last decode step (rows of
+        #: dead slots included), and (slots, logits [n, V] at each row's
+        #: padded bucket end) of the last prefill chunk: what parity
+        #: checks compare
+        self.last_logits = None
+        self.last_prefill_logits = None
         # only the executor-visible task graph (keyed by r2/order/m_e) is
         # a static argument: plans differing in modeled throughput share
         # one compiled program, so retraces are bounded by distinct
@@ -345,6 +397,18 @@ class ServingEngine:
         self._h_decode = self._h_prefill = None
         if self.metrics is not None:
             self._register_metrics(self.metrics)
+
+    def _init_params(self, key, dtype):
+        """The engine's own weights, held in the serving dtype. Float32
+        engines keep the eager initializer, so their weights stay
+        bit-identical to ``Model.init`` (tests compare against it); any
+        other dtype initializes and casts layer by layer under jit, so the
+        float32 intermediates never all live on the device at once, and
+        lands each array on its placement directly."""
+        if jnp.dtype(dtype) == jnp.float32:
+            return self.model.init(key)
+        return self.model.init(key, dtype=dtype,
+                               shardings=self.param_shardings)
 
     # ------------------------------------------------------------------
     # observability (repro.obs): metrics registration, phase spans
@@ -640,10 +704,15 @@ class ServingEngine:
         gather = np.asarray(old.perm)[inv_new]
         if not np.array_equal(gather, np.arange(new.num_experts)):
             idx = jnp.asarray(gather)
-            for layer in self.params["layers"]:
+            for i, layer in enumerate(self.params["layers"]):
                 if "moe" in layer and "experts" in layer["moe"]:
-                    layer["moe"]["experts"] = jax.tree.map(
-                        lambda a: a[idx], layer["moe"]["experts"])
+                    experts = jax.tree.map(lambda a: a[idx],
+                                           layer["moe"]["experts"])
+                    if self.param_shardings is not None:
+                        experts = jax.device_put(
+                            experts, self.param_shardings["layers"][i]
+                            ["moe"]["experts"])
+                    layer["moe"]["experts"] = experts
         self.placement = new
         if self.metrics is not None:
             self.metrics.counter(
@@ -755,7 +824,7 @@ class ServingEngine:
             with self._phase("prefill_chunk", bucket=group.bucket,
                              reqs=len(reqs)):
                 if self._track_load:
-                    _, prefilled, mstats = self.model.prefill(
+                    logits, prefilled, mstats = self.model.prefill(
                         self.params, jnp.asarray(toks),
                         seq_budget=self.max_context,
                         plan=self._exec_program(plan, streams=n_mb),
@@ -764,12 +833,13 @@ class ServingEngine:
                         return_moe_stats=True,
                         capacity_scale=self._capacity_scale(skew))
                 else:
-                    _, prefilled = self.model.prefill(
+                    logits, prefilled = self.model.prefill(
                         self.params, jnp.asarray(toks),
                         seq_budget=self.max_context,
                         plan=self._exec_program(plan, streams=n_mb))
                     mstats = None
                 jax.block_until_ready(prefilled)
+            self.last_prefill_logits = (list(slots), logits[:, 0])
             if mstats is not None:
                 self.load_tracker.observe(np.asarray(mstats.load))
                 self.stats.dropped_tokens += int(mstats.dropped)
@@ -854,7 +924,7 @@ class ServingEngine:
         # use_topk is static: when no live request truncates, the compiled
         # program skips the per-slot [B, V] threshold sort entirely
         nxt = sample(key, logits[:, -1], temps, top_ks if use_topk else 0)
-        return nxt[:, None], caches, mstats
+        return nxt[:, None], caches, mstats, logits[:, -1]
 
     # ------------------------------------------------------------------
     # paged-KV capacity management
@@ -955,7 +1025,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         with self._phase("decode_step", step=self.stats.steps,
                          live=len(live)):
-            nxt, new_caches, mstats = self._decode_jit(
+            nxt, new_caches, mstats, self.last_logits = self._decode_jit(
                 self.params, self.last_tokens, self.kv.caches, self.temps,
                 self.top_ks, sub, lengths, tables,
                 plan=self._exec_program(plan), use_topk=use_topk,

@@ -7,7 +7,7 @@ context even though most conversations use a fraction of it. This module
 replaces that layout with fixed-size blocks (vLLM's PagedAttention
 layout, adapted to the repo's ledger/kernel contracts):
 
-  ``BlockPool``      physical pages ``[num_blocks, block_size, Kv, D]``
+  ``BlockPool``      physical pages ``[num_blocks, Kv, block_size, D]``
                      per layer, a free-list + per-page refcounts, and
                      watermark accounting. ONE pool indexes every layer:
                      page p of layer t's arrays belongs to the same
@@ -290,7 +290,7 @@ class PagedKVCacheManager(KVCacheManager):
     Same slot/ledger surface (the engine's bookkeeping is unchanged);
     underneath, each slot maps its logical blocks to pool pages through a
     ``[num_slots, max_blocks]`` table, attention-layer caches are page
-    pools ``[num_blocks, block_size, Kv, D]``, and ``merge_prefill``
+    pools ``[num_blocks, Kv, block_size, D]``, and ``merge_prefill``
     scatters prefill rows page-by-page — skipping pages served by the
     prefix cache. ``model=None`` still gives a ledger-only manager
     (tables/pool/prefix fully functional, no device arrays) for tests
@@ -571,7 +571,7 @@ class PagedKVCacheManager(KVCacheManager):
                              "holds no caches")
         import jax.numpy as jnp
         # one page pool per layer, by initializing the model's cache with
-        # batch=num_blocks, context=block_size: [P, bs, Kv, D] per array.
+        # batch=num_blocks, context=block_size: [P, Kv, bs, D] per array.
         # Page p means the same logical block in every layer, so a single
         # block table drives the whole model.
         caches = self.model.init_cache(self.pool.num_blocks,
@@ -656,13 +656,14 @@ class PagedKVCacheManager(KVCacheManager):
                 page_ids: List[int] = []
                 blocks = []
                 for j, assignment in enumerate(assignments):
-                    row = c_new[name][j]              # [bucket, Kv, D]
+                    row = c_new[name][j]              # [Kv, bucket, D]
                     n_l = len(assignment)
-                    pad = n_l * bs - row.shape[0]
+                    pad = n_l * bs - row.shape[1]
                     if pad > 0:
-                        row = jnp.pad(row, [(0, pad)] + [(0, 0)] *
-                                      (row.ndim - 1))
-                    row = row[:n_l * bs].reshape((n_l, bs) + row.shape[1:])
+                        row = jnp.pad(row, [(0, 0), (0, pad), (0, 0)])
+                    Kv, D = row.shape[0], row.shape[2]
+                    row = row[:, :n_l * bs].reshape(
+                        Kv, n_l, bs, D).swapaxes(0, 1)  # [n_l, Kv, bs, D]
                     fresh = [l for l, _, cached in assignment if not cached]
                     if not fresh:
                         continue

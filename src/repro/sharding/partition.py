@@ -158,6 +158,20 @@ def params_shardings(params, cfg: ModelConfig, mesh: Mesh,
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
 
 
+def expert_parallel_shardings(params, mesh: Mesh, model_axis: str = "model"):
+    """The serving placement: routed experts' weights split over
+    ``model_axis`` on their expert dim (the layout ``core.dep``'s
+    shard_map expects), every other parameter replicated. ``params`` may
+    be arrays or ``jax.eval_shape`` structs."""
+    def one(path, leaf):
+        if "experts" in _path_names(path):
+            # [..., E, in, out]: any leading dims are layer stacking
+            return NamedSharding(
+                mesh, P(*([None] * (leaf.ndim - 3)), model_axis, None, None))
+        return NamedSharding(mesh, P())
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
 def batch_pspec(global_batch: int, mesh: Mesh,
                 exclude: Tuple[str, ...] = ("model",)) -> P:
     """Shard the batch dim over as many data axes as divide it."""
@@ -188,13 +202,13 @@ def cache_pspecs(cache, cfg: ModelConfig, mesh: Mesh, global_batch: int,
         if nd <= 0:                         # cache index scalar
             return P(*lead) if stacked else P()
         if any(n in ("k", "v") for n in names) and nd == 4:
-            C, kv = leaf.shape[-3], leaf.shape[-2]
+            kv, C = leaf.shape[-3], leaf.shape[-2]     # head-major
             mo = mesh.shape[model_axis]
             if kv % mo == 0:    # kv-head sharding when it divides
-                return P(*lead, b_axes, None, model_axis, None)
+                return P(*lead, b_axes, model_axis, None, None)
             if C % mo == 0:     # else sequence-sharded: served by the
                                 # shard_map distributed-flash decode core
-                return P(*lead, b_axes, model_axis, None, None)
+                return P(*lead, b_axes, None, model_axis, None)
             return P(*lead, b_axes, None, None, None)
         if any(n in ("ckv", "kpe") for n in names) and nd == 3:
             return P(*lead, b_axes, None, None)

@@ -200,7 +200,7 @@ def test_exec_interleaved_error_names_both_tasks():
 # ---------------------------------------------------------------------------
 
 def test_production_index_maps_clean():
-    assert check_dense_index_map(60, 16, [0, 1, 15, 16, 17, 59, 60]) == []
+    assert check_dense_index_map(40, 16, [0, 1, 7, 8, 9, 39, 40]) == []
     assert check_flash_index_map(2, 8, 2, 4, 4) == []
 
 

@@ -6,14 +6,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
-
-# some subprocess snippets build explicit-axis-type meshes; the streams
-# parity test uses a plain mesh and runs everywhere
-needs_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType unavailable in this jax version")
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -28,7 +21,6 @@ def run_sub(code: str, devices: int = 4) -> str:
     return r.stdout
 
 
-@needs_axis_type
 @pytest.mark.slow
 def test_dep_seq_mode_matches_dense_oracle():
     out = run_sub(textwrap.dedent("""
@@ -38,8 +30,8 @@ def test_dep_seq_mode_matches_dense_oracle():
         from repro.models.transformer import ExecutionContext
         from repro.core import dep
         from repro.core.solver import Plan
-        mesh = jax.make_mesh((2,2), ("data","model"),
-            axis_types=(jax.sharding.AxisType.Auto,)*2)
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,2), ("data","model"))
         cfg = get_smoke_config("qwen2-moe-a2.7b")
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=8.0))
@@ -65,7 +57,6 @@ def test_dep_seq_mode_matches_dense_oracle():
     assert out.count("ok") == 4
 
 
-@needs_axis_type
 @pytest.mark.slow
 def test_dep_decode_mode_and_grads():
     out = run_sub(textwrap.dedent("""
@@ -75,8 +66,8 @@ def test_dep_decode_mode_and_grads():
         from repro.models.transformer import ExecutionContext
         from repro.core import dep
         from repro.core.solver import Plan
-        mesh = jax.make_mesh((2,2), ("data","model"),
-            axis_types=(jax.sharding.AxisType.Auto,)*2)
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,2), ("data","model"))
         cfg = get_smoke_config("qwen2-moe-a2.7b")
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=8.0))
@@ -119,23 +110,22 @@ def test_dep_decode_mode_and_grads():
     assert "ok decode" in out and "ok grads" in out
 
 
-@needs_axis_type
 @pytest.mark.slow
 def test_seqsharded_decode_attention_matches_local():
     out = run_sub(textwrap.dedent("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.models.attention import _decode_core_seqsharded
-        mesh = jax.make_mesh((2,2), ("data","model"),
-            axis_types=(jax.sharding.AxisType.Auto,)*2)
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,2), ("data","model"))
         key = jax.random.PRNGKey(0)
         B, C, Kv, H, D = 4, 64, 2, 8, 32
         ks = jax.random.split(key, 5)
         q = jax.random.normal(ks[0], (B, 1, H, D), jnp.float32)
-        kn = jax.random.normal(ks[1], (B, 1, Kv, D), jnp.float32)
-        vn = jax.random.normal(ks[2], (B, 1, Kv, D), jnp.float32)
-        ck = jax.random.normal(ks[3], (B, C, Kv, D), jnp.float32)
-        cv = jax.random.normal(ks[4], (B, C, Kv, D), jnp.float32)
+        kn = jax.random.normal(ks[1], (B, Kv, 1, D), jnp.float32)
+        vn = jax.random.normal(ks[2], (B, Kv, 1, D), jnp.float32)
+        ck = jax.random.normal(ks[3], (B, Kv, C, D), jnp.float32)
+        cv = jax.random.normal(ks[4], (B, Kv, C, D), jnp.float32)
         index = jnp.asarray(37, jnp.int32)
         with mesh:
             out, nk, nv = jax.jit(lambda *a: _decode_core_seqsharded(
@@ -143,14 +133,15 @@ def test_seqsharded_decode_attention_matches_local():
                 q, kn, vn, ck, cv, index)
         # local reference
         import math
-        ck2 = ck.at[:, 37].set(kn[:, 0]); cv2 = cv.at[:, 37].set(vn[:, 0])
+        ck2 = ck.at[:, :, 37].set(kn[:, :, 0])
+        cv2 = cv.at[:, :, 37].set(vn[:, :, 0])
         valid = jnp.arange(C) <= 37
         g = H // Kv
         qh = q[:, 0].reshape(B, Kv, g, D)
-        lg = jnp.einsum("bkgd,bskd->bkgs", qh, ck2) / math.sqrt(D)
+        lg = jnp.einsum("bkgd,bksd->bkgs", qh, ck2) / math.sqrt(D)
         lg = jnp.where(valid[None,None,None], lg, -1e30)
         p = jax.nn.softmax(lg, -1)
-        ref = jnp.einsum("bkgs,bskd->bkgd", p, cv2).reshape(B,1,H,D)
+        ref = jnp.einsum("bkgs,bksd->bkgd", p, cv2).reshape(B,1,H,D)
         err = float(jnp.max(jnp.abs(out - ref)))
         assert err < 1e-5, err
         assert float(jnp.max(jnp.abs(nk - ck2))) < 1e-6
@@ -174,7 +165,8 @@ def test_interleaved_streams_bit_identical_to_off():
         from repro.models.transformer import ExecutionContext
         from repro.core import dep
         from repro.core.taskgraph import ExecProgram, lower_exec
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = get_smoke_config("qwen2-moe-a2.7b")
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=8.0))

@@ -1,4 +1,6 @@
 """Serving engine: continuous batching correctness and lifecycle."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,8 +28,11 @@ def _greedy_ref(model, params, prompt, n, budget=128):
                                   "xlstm-1.3b"])
 def test_engine_matches_greedy_reference(arch):
     cfg = get_smoke_config(arch)
+    # the decode kernel takes full-attention caches; RecurrentGemma's
+    # local-attention ring cache is served by the dense path, explicitly
     eng = ServingEngine(cfg, num_slots=3, max_context=128,
-                        dtype=jnp.float32)
+                        attn_impl="xla" if cfg.family == "hybrid"
+                        else "decode_kernel", dtype=jnp.float32)
     model = build_model(cfg, dtype=jnp.float32)
     prompt = list(np.random.RandomState(0).randint(
         0, cfg.vocab_size, size=7))
@@ -109,3 +114,61 @@ def test_sampler_greedy_vs_temperature():
     # top-k=1 equals greedy regardless of temperature
     tk = sample(key, logits, jnp.full((2,), 5.0), top_k=1)
     np.testing.assert_array_equal(np.asarray(tk), [1, 0])
+
+
+# ---------------------------------------------------------------------------
+# construction: decode implementation settled, weights in the serving dtype
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch,kw", [
+    ("deepseek-v2-lite", {}),                  # MLA latent cache
+    ("recurrentgemma-9b", {}),                 # local-attention ring cache
+    ("qwen2-1.5b", {"max_context": 1021}),     # prime C > bc: cannot tile
+])
+def test_decode_kernel_refuses_at_construction(arch, kw):
+    """attn_impl="decode_kernel" is settled when the engine is built: a
+    model or cache shape the kernel cannot take raises there, instead of
+    quietly serving through the dense path."""
+    cfg = get_smoke_config(arch)
+    kw = dict({"max_context": 64}, **kw)
+    with pytest.raises(ValueError):
+        ServingEngine(cfg, num_slots=2, dtype=jnp.float32, **kw)
+    # the dense path stays available when asked for by name
+    ServingEngine(cfg, num_slots=2, attn_impl="xla", dtype=jnp.float32,
+                  **kw).close()
+
+
+def test_engine_weights_in_serving_dtype():
+    """An engine's own weights are held in its dtype, cast once at
+    construction, with Model.init's values: exact in float32, and
+    within bf16 rounding (2^-9 relative, 2^-8 where the jitted
+    initializer's float32 scaling rounds one ulp apart) otherwise."""
+    cfg = get_smoke_config("qwen2-moe-a2.7b")
+    ref = build_model(cfg, dtype=jnp.float32).init(jax.random.PRNGKey(3))
+    eng = ServingEngine(cfg, num_slots=2, max_context=64,
+                        dtype=jnp.bfloat16, seed=3)
+    assert {a.dtype for a in jax.tree.leaves(eng.params)} == \
+        {jnp.dtype(jnp.bfloat16)}
+    for a, b in zip(jax.tree.leaves(eng.params), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   rtol=2 ** -8, atol=0)
+    eng.close()
+    eng32 = ServingEngine(cfg, num_slots=2, max_context=64,
+                          dtype=jnp.float32, seed=3)
+    assert all(bool(jnp.array_equal(a, b)) for a, b in
+               zip(jax.tree.leaves(eng32.params), jax.tree.leaves(ref)))
+    eng32.close()
+
+
+def test_compile_cache_helper_defers_to_environment(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and the helper
+    sets nothing; otherwise the cache is the repository's fixed
+    .jax_cache (asserted on the path, not turned on in tests)."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    assert str(compile_cache.REPO_CACHE_DIR) == os.path.join(root,
+                                                            ".jax_cache")

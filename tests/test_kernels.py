@@ -63,8 +63,8 @@ def test_decode_attention(B, C, H, Kv, D, dtype):
     import numpy as np
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, C, Kv, D), dtype)
-    v = jax.random.normal(ks[2], (B, C, Kv, D), dtype)
+    k = jax.random.normal(ks[1], (B, Kv, C, D), dtype)
+    v = jax.random.normal(ks[2], (B, Kv, C, D), dtype)
     lengths = jnp.asarray([(3 * C) // 4, 1, C][:B], jnp.int32)
     y = decode_attention(q, k, v, lengths, bc=128)
     r = decode_attention_ref(q, k, v, lengths)

@@ -88,6 +88,25 @@ def test_scan_layers_equals_loop(arch):
     assert float(abs(a1 - a2)) < 1e-6
 
 
+@pytest.mark.parametrize("arch,scan", [
+    ("qwen2-moe-a2.7b", False), ("qwen2-moe-a2.7b", True),
+    ("internvl2-1b", False), ("seamless-m4t-large-v2", False),
+    ("recurrentgemma-9b", True)])
+def test_init_in_dtype_casts_float32_init(arch, scan):
+    """``init(key, dtype=...)`` (built layer by layer under jit) holds
+    the float32 initializer's values in ``dtype``: within bf16 rounding,
+    2^-8 relative where jit scales a float32 value one ulp apart."""
+    model = build_model(get_smoke_config(arch), scan_layers=scan,
+                        dtype=jnp.float32)
+    ref = model.init(KEY)
+    got = model.init(KEY, dtype=jnp.bfloat16)
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   rtol=2 ** -8, atol=0)
+
+
 def test_chunked_ce_equals_naive():
     cfg, model, params, tokens, _ = _setup("qwen2-1.5b")
     for chunk in (4, 8, 23, 64):

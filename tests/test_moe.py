@@ -5,7 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import MoEConfig
 from repro.models import moe as moe_lib

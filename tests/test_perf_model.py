@@ -1,7 +1,8 @@
 """alpha-beta performance models (paper Eqs. 7-9, Fig. 7 methodology)."""
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import DepClusterConfig
 from repro.core.perf_model import (PAPER_A6000, TPU_V5E, AlphaBeta,

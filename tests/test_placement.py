@@ -366,7 +366,8 @@ def test_replicated_executor_bit_parity_and_drops():
         from repro.core import dep
         from repro.core.solver import Plan
         from repro.placement import Placement, rebalance
-        mesh = jax.make_mesh((2,2), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,2), ("data","model"))
         cfg = get_smoke_config("qwen2-moe-a2.7b")
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=8.0))
@@ -438,7 +439,8 @@ def test_engine_rebalance_end_to_end():
         from repro.runtime.engine import ServingEngine
         from repro.runtime.request import Request
         from repro.sched import FinDEPPolicy
-        mesh = jax.make_mesh((2,2), ("data","model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2,2), ("data","model"))
         cfg = get_smoke_config("qwen2-moe-a2.7b")
         def make(**kw):
             plr = FinDEPPlanner(cfg, DepClusterConfig(4, 2, 2),

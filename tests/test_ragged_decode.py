@@ -38,8 +38,8 @@ KEY = jax.random.PRNGKey(7)
 def _qkv(B, C, H, Kv, D, dtype=jnp.float32):
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, C, Kv, D), dtype)
-    v = jax.random.normal(ks[2], (B, C, Kv, D), dtype)
+    k = jax.random.normal(ks[1], (B, Kv, C, D), dtype)
+    v = jax.random.normal(ks[2], (B, Kv, C, D), dtype)
     return q, k, v
 
 
@@ -80,11 +80,12 @@ def test_kernel_parity_under_jit_and_ops_wrapper():
                                atol=1e-5)
 
 
-@pytest.mark.parametrize("C,bc,expect_bc", [(600, 512, 300), (384, 512, 384),
+@pytest.mark.parametrize("C,bc,expect_bc", [(600, 512, 200), (384, 512, 384),
                                             (384, 128, 128)])
 def test_kernel_nondividing_cache_lengths(C, bc, expect_bc):
     """C % bc != 0 used to raise AssertionError after bc = min(bc, C);
-    now the kernel runs at the largest block size dividing C."""
+    now the kernel runs at the largest block size dividing C that the
+    TPU can tile (a multiple of the 8-row float32 sublane tile)."""
     assert largest_block_size(C, bc) == expect_bc
     B, H, Kv, D = 3, 4, 2, 32
     q, k, v = _qkv(B, C, H, Kv, D)
@@ -120,15 +121,21 @@ def test_kernel_block_skip_counts():
 
 
 def test_ops_pathological_length_falls_back_to_ref():
-    """A prime cache length has no usable block size; the wrapper must
-    still be correct (oracle path)."""
+    """A prime cache length has no tile-aligned divisor. Within one block
+    (C <= bc) the kernel streams all of C as a single block, which the
+    TPU tiles as the whole dim; past one block the wrapper refuses
+    instead of quietly running the oracle, and ``use_kernel=False`` is
+    the explicit oracle path."""
     B, C, H, Kv, D = 2, 127, 4, 2, 32
     q, k, v = _qkv(B, C, H, Kv, D)
     lengths = jnp.asarray([50, 127], jnp.int32)
-    y = decode_attention(q, k, v, lengths)
     r = decode_attention_ref(q, k, v, lengths)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(r), rtol=1e-5,
-                               atol=1e-5)
+    for y in (decode_attention(q, k, v, lengths),
+              decode_attention(q, k, v, lengths, bc=64, use_kernel=False)):
+        np.testing.assert_allclose(np.asarray(y), np.asarray(r), rtol=1e-5,
+                                   atol=1e-5)
+    with pytest.raises(ValueError, match="cannot tile"):
+        decode_attention(q, k, v, lengths, bc=64)
 
 
 # ---------------------------------------------------------------------------
@@ -199,8 +206,8 @@ def test_engine_finishes_request_at_kv_cap():
     # the last cache row holds the same (single-write) KV in both runs
     for c1, c2 in zip(eng.kv.caches, eng2.kv.caches):
         if isinstance(c1, dict) and "k" in c1:
-            np.testing.assert_array_equal(np.asarray(c1["k"][0, C - 1]),
-                                          np.asarray(c2["k"][0, C - 1]))
+            np.testing.assert_array_equal(np.asarray(c1["k"][0, :, C - 1]),
+                                          np.asarray(c2["k"][0, :, C - 1]))
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """Exact event-order simulator vs the paper's closed forms (Eq. 13)."""
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.analytic import (ORDER_AASS, ORDER_ASAS, StageTimes,
                                  makespan_closed_form, makespan_naive,

@@ -3,7 +3,8 @@ solver latency (< 1 s claim)."""
 import time
 
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs.base import DepClusterConfig
 from repro.core.analytic import ORDER_ASAS, ORDERS

@@ -352,7 +352,7 @@ def test_executor_bit_parity_graph_vs_legacy_loop():
         from repro.core import dep
         from repro.core.solver import Plan
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
 
         # ---- the pre-refactor executor loop, verbatim ----------------
         def legacy_shared_schedule(order, shared_fn, shared_x, r2):
@@ -396,8 +396,7 @@ def test_executor_bit_parity_graph_vs_legacy_loop():
             return jnp.concatenate(outs, axis=1), shared_out
         # --------------------------------------------------------------
 
-        mesh = jax.sharding.Mesh(
-            np.array(jax.devices()).reshape(2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = get_smoke_config("qwen2-moe-a2.7b")
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
             cfg.moe, capacity_factor=8.0))
@@ -438,7 +437,7 @@ def test_executor_bit_parity_graph_vs_legacy_loop():
 
             in_spec = P("data", "model", None)
             with mesh:
-                y_old, _ = jax.jit(shard_map(
+                y_old, _ = jax.jit(jax.shard_map(
                     local, mesh=mesh,
                     in_specs=(in_spec,
                               jax.tree.map(lambda _: P(),
@@ -449,7 +448,7 @@ def test_executor_bit_parity_graph_vs_legacy_loop():
                               jax.tree.map(lambda _: P(),
                                            params["shared"])),
                     out_specs=(in_spec, P()),
-                    check_rep=False))(x, params["router"],
+                    check_vma=False))(x, params["router"],
                                       params["experts"], params["shared"])
             diff = float(jnp.max(jnp.abs(y_new - y_old)))
             assert diff == 0.0, (r2, order, m_e, diff)
