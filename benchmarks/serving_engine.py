@@ -71,7 +71,6 @@ def run(policy: str = "findep", admission: str = "fcfs",
         rows.append(csv_row(
             f"serving_engine.{arch}", dt / max(tok, 1) * 1e6,
             f"decode_tokens={tok};tokens_per_s={tok/dt:.1f};"
-            f"engine_tps={eng.stats.throughput():.1f};"
             f"ttft_ms={np.mean([r.ttft for r in reqs])*1e3:.1f}" + sched))
     return rows, info
 

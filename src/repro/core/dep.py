@@ -189,17 +189,19 @@ def _graph_expert_alltoall(program: tg.ExecProgram, buffers, expert_params,
     hot_out = {}
 
     def on_a2e(t):     # [E_pad, c, M] -> [E_loc, mo*c, M]
-        buf = jax.lax.dynamic_slice_in_dim(
-            buffers, (t.mb * r2 + t.chunk) * chunk, chunk, 1)
-        dispatched[(t.mb, t.chunk)] = jax.lax.all_to_all(
-            buf, axis, split_axis=0, concat_axis=1, tiled=True)
+        with jax.named_scope("moe/dispatch"):
+            buf = jax.lax.dynamic_slice_in_dim(
+                buffers, (t.mb * r2 + t.chunk) * chunk, chunk, 1)
+            dispatched[(t.mb, t.chunk)] = jax.lax.all_to_all(
+                buf, axis, split_axis=0, concat_axis=1, tiled=True)
         return dispatched[(t.mb, t.chunk)]
 
     def on_shared(t):
         if shared_fn is None:
             return None
-        part = _shared_part(shared_fn, shared_x,
-                            t.mb * n_seg + t.chunk, r1 * n_seg)
+        with jax.named_scope("moe/shared_expert"):
+            part = _shared_part(shared_fn, shared_x,
+                                t.mb * n_seg + t.chunk, r1 * n_seg)
         shared_parts[(t.mb, t.chunk)] = part
         return part
 
@@ -210,9 +212,10 @@ def _graph_expert_alltoall(program: tg.ExecProgram, buffers, expert_params,
         return out
 
     def on_e2a(t):     # [E_loc, mo*c, M] -> [E_pad, c, M]
-        out = jax.lax.all_to_all(ffn_out.pop((t.mb, t.chunk)), axis,
-                                 split_axis=1, concat_axis=0,
-                                 tiled=True)
+        with jax.named_scope("moe/combine"):
+            out = jax.lax.all_to_all(ffn_out.pop((t.mb, t.chunk)), axis,
+                                     split_axis=1, concat_axis=0,
+                                     tiled=True)
         outs[(t.mb, t.chunk)] = out
         return out
 
@@ -259,15 +262,17 @@ def _graph_replicated_experts(program: tg.ExecProgram, local_buf,
     shared_parts = {}
 
     def on_a2e(t):
-        sliced[(t.mb, t.chunk)] = jax.lax.dynamic_slice_in_dim(
-            local_buf, (t.mb * r2 + t.chunk) * chunk, chunk, 1)
+        with jax.named_scope("moe/dispatch"):
+            sliced[(t.mb, t.chunk)] = jax.lax.dynamic_slice_in_dim(
+                local_buf, (t.mb * r2 + t.chunk) * chunk, chunk, 1)
         return sliced[(t.mb, t.chunk)]
 
     def on_shared(t):
         if shared_fn is None:
             return None
-        part = _shared_part(shared_fn, shared_x,
-                            t.mb * n_seg + t.chunk, r1 * n_seg)
+        with jax.named_scope("moe/shared_expert"):
+            part = _shared_part(shared_fn, shared_x,
+                                t.mb * n_seg + t.chunk, r1 * n_seg)
         shared_parts[(t.mb, t.chunk)] = part
         return part
 
@@ -284,6 +289,39 @@ def _graph_replicated_experts(program: tg.ExecProgram, local_buf,
                   if shared_parts else None)
     out = jnp.concatenate([outs[k] for k in sorted(outs)], axis=1)
     return out, shared_out
+
+
+def _token_layout(mesh, axis: str, B: int, S: int):
+    """How ``moe_apply_dep`` splits a [B, S] input: (``seq_mode``, the
+    data axes that shard the batch, and the tokens each shard routes).
+    Sequence mode splits S over the expert axis; otherwise tokens are
+    replicated over it."""
+    data_axes = tuple(a for a in mesh.axis_names if a != axis)
+    mo = mesh.shape[axis]
+    seq_mode = S % mo == 0 and S >= mo
+    dp = _mesh_prod(mesh, data_axes)
+    b_shard = data_axes if (B % dp == 0 and B >= dp) else ()
+    t_loc = (B // _mesh_prod(mesh, b_shard)) * (S // mo if seq_mode else S)
+    return seq_mode, b_shard, t_loc
+
+
+def expert_rows(B: int, S: int, mcfg: MoEConfig, ctx,
+                num_experts_padded: int, plan=None,
+                capacity_scale: float = 1.0) -> int:
+    """Rows one DEP layer's routed-expert FFN computes for a [B, S] input
+    over the whole mesh, padding included: each token shard's [E_pad, C]
+    dispatch buffers, C sized as ``moe_apply_dep`` sizes it."""
+    mesh = ctx.mesh
+    seq_mode, b_shard, t_loc = _token_layout(mesh, ctx.expert_axis, B, S)
+    graph = as_exec_program(plan).graph
+    E_pad = num_experts_padded or mcfg.num_experts
+    cap = moe_lib.expert_capacity(t_loc, mcfg, E_pad,
+                                  multiple_of=graph.r1 * graph.r2
+                                  * graph.m_e,
+                                  scale=capacity_scale)
+    shards = _mesh_prod(mesh, b_shard) * (
+        mesh.shape[ctx.expert_axis] if seq_mode else 1)
+    return shards * E_pad * cap
 
 
 def moe_apply_dep(params, x, mcfg: MoEConfig, ctx, num_experts_padded: int,
@@ -312,7 +350,6 @@ def moe_apply_dep(params, x, mcfg: MoEConfig, ctx, num_experts_padded: int,
     mesh = ctx.mesh
     assert mesh is not None, "DEP impl needs a mesh"
     axis = ctx.expert_axis
-    data_axes = tuple(a for a in mesh.axis_names if a != axis)
     B, S, M = x.shape
     mo = mesh.shape[axis]
     E_pad = num_experts_padded or mcfg.num_experts
@@ -332,9 +369,7 @@ def moe_apply_dep(params, x, mcfg: MoEConfig, ctx, num_experts_padded: int,
     # schedule-free callers (m_e hint absent -> 1) are unchanged.
     cap_multiple = graph.r1 * graph.r2 * graph.m_e
 
-    seq_mode = S % mo == 0 and S >= mo
-    dp = _mesh_prod(mesh, data_axes)
-    b_shard = data_axes if (B % dp == 0 and B >= dp) else ()
+    seq_mode, b_shard, _ = _token_layout(mesh, axis, B, S)
     n_devices = _mesh_prod(mesh, mesh.axis_names)
 
     has_shared = "shared" in params
@@ -410,8 +445,9 @@ def moe_apply_dep(params, x, mcfg: MoEConfig, ctx, num_experts_padded: int,
             # SHARED boundary indices, same lowering as the sequence path)
             mo_idx = jax.lax.axis_index(axis)
             E_loc = E_pad // mo
-            local_buf = jax.lax.dynamic_slice_in_dim(
-                info.buffers, mo_idx * E_loc, E_loc, 0)
+            with jax.named_scope("moe/dispatch"):
+                local_buf = jax.lax.dynamic_slice_in_dim(
+                    info.buffers, mo_idx * E_loc, E_loc, 0)
             local_out, shared_out = _graph_replicated_experts(
                 program, local_buf, experts_loc,
                 shared_fn=shared_fn, shared_x=xf)   # [E_loc, cap, M]
@@ -420,14 +456,16 @@ def moe_apply_dep(params, x, mcfg: MoEConfig, ctx, num_experts_padded: int,
             # [T, M] output and the transport is a psum of that —
             # (E_pad*cap)/T ~ top_k*cf times fewer bytes than psum-ing
             # the padded dispatch buffers.
-            pad = jnp.zeros((E_pad - E_loc,) + local_out.shape[1:],
-                            local_out.dtype)
-            out_local_layout = jnp.roll(
-                jnp.concatenate([local_out, pad], axis=0),
-                mo_idx * E_loc, axis=0)
+            with jax.named_scope("moe/combine"):
+                pad = jnp.zeros((E_pad - E_loc,) + local_out.shape[1:],
+                                local_out.dtype)
+                out_local_layout = jnp.roll(
+                    jnp.concatenate([local_out, pad], axis=0),
+                    mo_idx * E_loc, axis=0)
             y_partial = moe_lib.moe_combine(info, out_local_layout, T_loc,
                                             x_loc.dtype)
-            y = jax.lax.psum(y_partial, axis)
+            with jax.named_scope("moe/combine"):
+                y = jax.lax.psum(y_partial, axis)
             if shared_out is not None:
                 y = y + shared_out
             aux = jax.lax.psum(info.aux, all_axes) / n_devices

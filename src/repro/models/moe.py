@@ -60,13 +60,15 @@ def route_topk(router_params, x_flat, mcfg: MoEConfig,
                num_experts_padded: int = 0) -> Routing:
     """x_flat: [T, M] -> top-k routing per token (paper §2.1)."""
     E_pad = num_experts_padded or mcfg.num_experts
-    logits = dense_apply(router_params, x_flat).astype(jnp.float32)
-    if E_pad > mcfg.num_experts:                  # mask padded experts
-        neg = jnp.full((E_pad - mcfg.num_experts,), -1e30, jnp.float32)
-        logits = logits.at[..., mcfg.num_experts:].set(neg)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, mcfg.top_k)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe/router"):
+        logits = dense_apply(router_params, x_flat).astype(jnp.float32)
+        if E_pad > mcfg.num_experts:              # mask padded experts
+            neg = jnp.full((E_pad - mcfg.num_experts,), -1e30, jnp.float32)
+            logits = logits.at[..., mcfg.num_experts:].set(neg)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, mcfg.top_k)
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True),
+                                        1e-9)
     return Routing(weights=weights, experts=experts.astype(jnp.int32),
                    probs=probs)
 
@@ -83,20 +85,26 @@ def load_balance_loss(routing: Routing, mcfg: MoEConfig) -> jax.Array:
 
 # ---------------------------------------------------------------------------
 # expert FFN (stacked einsum over the expert dimension)
+#
+# Each stage of the layer runs under a named scope (moe/router,
+# moe/dispatch, moe/expert_ffn, moe/combine, moe/shared_expert): op
+# metadata only, by which a device trace attributes time to the stage.
 # ---------------------------------------------------------------------------
 
 def expert_ffn(expert_params, x):
     """x: [E, C, M] -> [E, C, M] (one SwiGLU FFN per expert, Eq. 3)."""
     dt = x.dtype
-    g = jnp.einsum("ecm,emh->ech", x, expert_params["gate"].astype(dt))
-    u = jnp.einsum("ecm,emh->ech", x, expert_params["up"].astype(dt))
-    return jnp.einsum("ech,ehm->ecm", jax.nn.silu(g) * u,
-                      expert_params["down"].astype(dt))
+    with jax.named_scope("moe/expert_ffn"):
+        g = jnp.einsum("ecm,emh->ech", x, expert_params["gate"].astype(dt))
+        u = jnp.einsum("ecm,emh->ech", x, expert_params["up"].astype(dt))
+        return jnp.einsum("ech,ehm->ecm", jax.nn.silu(g) * u,
+                          expert_params["down"].astype(dt))
 
 
 def shared_expert_apply(params, x):
     """Dense shared-expert path (paper Eq. 2); fused over N_shared."""
-    return mlp_apply(params["shared"], x)
+    with jax.named_scope("moe/shared_expert"):
+        return mlp_apply(params["shared"], x)
 
 
 # ---------------------------------------------------------------------------
@@ -112,13 +120,15 @@ def moe_apply_dense(params, x, mcfg: MoEConfig, num_experts_padded: int = 0,
     xf = x.reshape(-1, M)
     routing = route_topk(params["router"], xf, mcfg, num_experts_padded)
     E_pad = num_experts_padded or mcfg.num_experts
-    # combine weights per (token, expert): [T, E]
-    cw = jnp.zeros((xf.shape[0], E_pad), x.dtype)
-    cw = cw.at[jnp.arange(xf.shape[0])[:, None],
-               routing.experts].add(routing.weights.astype(x.dtype))
-    all_out = expert_ffn(params["experts"],
-                         jnp.broadcast_to(xf, (E_pad,) + xf.shape))
-    y = jnp.einsum("te,etm->tm", cw, all_out)
+    with jax.named_scope("moe/dispatch"):
+        # combine weights per (token, expert): [T, E]
+        cw = jnp.zeros((xf.shape[0], E_pad), x.dtype)
+        cw = cw.at[jnp.arange(xf.shape[0])[:, None],
+                   routing.experts].add(routing.weights.astype(x.dtype))
+        every = jnp.broadcast_to(xf, (E_pad,) + xf.shape)
+    all_out = expert_ffn(params["experts"], every)
+    with jax.named_scope("moe/combine"):
+        y = jnp.einsum("te,etm->tm", cw, all_out)
     if "shared" in params:
         y = y + shared_expert_apply(params, xf)
     aux = load_balance_loss(routing, mcfg)
@@ -168,6 +178,17 @@ def expert_capacity(num_tokens: int, mcfg: MoEConfig,
     return ((cap + multiple_of - 1) // multiple_of) * multiple_of
 
 
+def expert_rows(num_tokens: int, mcfg: MoEConfig,
+                num_experts_padded: int = 0, impl: str = "capacity") -> int:
+    """Rows one MoE layer's routed-expert FFN computes for ``num_tokens``
+    tokens on one device, padding included: every expert on every token
+    (``impl="dense"``), else the capacity dispatch's [E, C] buffers."""
+    E = num_experts_padded or mcfg.num_experts
+    if impl == "dense":
+        return E * num_tokens
+    return E * expert_capacity(num_tokens, mcfg, num_experts_padded)
+
+
 def moe_dispatch(params, xf, mcfg: MoEConfig, capacity: int,
                  num_experts_padded: int = 0,
                  expert_map: Optional[jax.Array] = None) -> DispatchInfo:
@@ -183,25 +204,26 @@ def moe_dispatch(params, xf, mcfg: MoEConfig, capacity: int,
     T, M = xf.shape
     E_pad = num_experts_padded or mcfg.num_experts
     routing = route_topk(params["router"], xf, mcfg, num_experts_padded)
-    experts = routing.experts
-    load = jax.nn.one_hot(experts, E_pad,
-                          dtype=jnp.float32).sum(axis=(0, 1))          # [E]
-    if expert_map is not None:
-        experts = expert_map.astype(jnp.int32)[experts]
-    # position of each (token, k) within its expert, in token order
-    onehot = jax.nn.one_hot(experts, E_pad, dtype=jnp.int32)          # [T,k,E]
-    flat = onehot.reshape(T * mcfg.top_k, E_pad)
-    pos = jnp.cumsum(flat, axis=0) - flat                              # [Tk,E]
-    slot = (pos * flat).sum(-1).reshape(T, mcfg.top_k)                 # [T,k]
-    keep = slot < capacity
-    weights = jnp.where(keep, routing.weights, 0.0)
-    slot_c = jnp.where(keep, slot, capacity)     # drops -> scratch slot C
-    dropped = (~keep).sum().astype(jnp.int32)
-    buffers = jnp.zeros((E_pad, capacity + 1, M), xf.dtype)
-    buffers = buffers.at[experts.reshape(-1),
-                         slot_c.reshape(-1)].add(
-        jnp.repeat(xf[:, None], mcfg.top_k, 1).reshape(-1, M))
-    aux = load_balance_loss(routing, mcfg)
+    with jax.named_scope("moe/dispatch"):
+        experts = routing.experts
+        load = jax.nn.one_hot(experts, E_pad,
+                              dtype=jnp.float32).sum(axis=(0, 1))      # [E]
+        if expert_map is not None:
+            experts = expert_map.astype(jnp.int32)[experts]
+        # position of each (token, k) within its expert, in token order
+        onehot = jax.nn.one_hot(experts, E_pad, dtype=jnp.int32)      # [T,k,E]
+        flat = onehot.reshape(T * mcfg.top_k, E_pad)
+        pos = jnp.cumsum(flat, axis=0) - flat                          # [Tk,E]
+        slot = (pos * flat).sum(-1).reshape(T, mcfg.top_k)             # [T,k]
+        keep = slot < capacity
+        weights = jnp.where(keep, routing.weights, 0.0)
+        slot_c = jnp.where(keep, slot, capacity)  # drops -> scratch slot C
+        dropped = (~keep).sum().astype(jnp.int32)
+        buffers = jnp.zeros((E_pad, capacity + 1, M), xf.dtype)
+        buffers = buffers.at[experts.reshape(-1),
+                             slot_c.reshape(-1)].add(
+            jnp.repeat(xf[:, None], mcfg.top_k, 1).reshape(-1, M))
+        aux = load_balance_loss(routing, mcfg)
     return DispatchInfo(buffers=buffers[:, :capacity], combine=weights,
                         slot=slot_c, experts=experts, aux=aux,
                         load=load, dropped=dropped)
@@ -211,16 +233,15 @@ def moe_combine(info: DispatchInfo, expert_out: jax.Array, T: int,
                 dtype) -> jax.Array:
     """Gather expert outputs back per token and apply combine weights."""
     M = expert_out.shape[-1]
-    C = expert_out.shape[1]
-    padded = jnp.concatenate(
-        [expert_out, jnp.zeros((expert_out.shape[0], 1, M),
-                               expert_out.dtype)], axis=1)
-    gathered = padded[info.experts.reshape(-1),
-                      info.slot.reshape(-1)]                 # [Tk, M]
-    gathered = gathered.reshape(T, -1, M)
-    y = jnp.einsum("tk,tkm->tm", info.combine.astype(dtype),
-                   gathered.astype(dtype))
-    return y
+    with jax.named_scope("moe/combine"):
+        padded = jnp.concatenate(
+            [expert_out, jnp.zeros((expert_out.shape[0], 1, M),
+                                   expert_out.dtype)], axis=1)
+        gathered = padded[info.experts.reshape(-1),
+                          info.slot.reshape(-1)]             # [Tk, M]
+        gathered = gathered.reshape(T, -1, M)
+        return jnp.einsum("tk,tkm->tm", info.combine.astype(dtype),
+                          gathered.astype(dtype))
 
 
 def moe_apply_capacity(params, x, mcfg: MoEConfig,

@@ -186,15 +186,15 @@ def apply_layer(p, cfg: ModelConfig, kind: str, x, positions,
 
     if kind in ("attn_mlp", "attn_moe", "attn"):
         h = rmsnorm_apply(p["ln1"], x, cfg.norm_eps)
-        if mode == "decode":
-            a, cache = attn.attention_decode(p["attn"], local_cfg, h, cache,
-                                             impl=ctx.attn_impl, ctx=ctx,
-                                             lengths=lengths,
-                                             block_table=block_table)
-        else:
-            a, cache = attn.attention_fullseq(p["attn"], local_cfg, h,
-                                              positions, cache,
-                                              impl=ctx.attn_impl)
+        with jax.named_scope("attention"):
+            if mode == "decode":
+                a, cache = attn.attention_decode(
+                    p["attn"], local_cfg, h, cache, impl=ctx.attn_impl,
+                    ctx=ctx, lengths=lengths, block_table=block_table)
+            else:
+                a, cache = attn.attention_fullseq(p["attn"], local_cfg, h,
+                                                  positions, cache,
+                                                  impl=ctx.attn_impl)
         x = x + a
         if memory is not None and "cross" in p:
             hx = rmsnorm_apply(p["ln_x"], x, cfg.norm_eps)
@@ -442,11 +442,35 @@ class Model:
     def _readout(self, params, x):
         """Float32 logits, accumulated in float32 from operands in the
         head's own dtype (no float32 copy of a bf16 head)."""
-        if self.cfg.tie_embeddings:
-            return embedding_attend(params["embed"], x)
-        w = params["lm_head"]["kernel"]
-        return jnp.dot(x.astype(w.dtype), w,
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("lm_head"):
+            if self.cfg.tie_embeddings:
+                return embedding_attend(params["embed"], x)
+            w = params["lm_head"]["kernel"]
+            return jnp.dot(x.astype(w.dtype), w,
+                           preferred_element_type=jnp.float32)
+
+    # ---- expert rows -------------------------------------------------------
+    @property
+    def routed_per_token(self) -> int:
+        """Token-expert assignments one token makes over all MoE layers."""
+        n = self.kinds.count("attn_moe")
+        return n * self.cfg.moe.top_k if n else 0
+
+    def expert_rows(self, batch: int, seq: int, plan=None,
+                    capacity_scale: float = 1.0) -> int:
+        """Rows the routed-expert FFN computes over all MoE layers for a
+        [batch, seq] call, padding included, as ``moe_impl`` sizes them:
+        every expert on every token on the dense path, the dispatch
+        buffers' E_pad x capacity on the capacity and DEP paths."""
+        n = self.kinds.count("attn_moe")
+        if not n:
+            return 0
+        if self.ctx.moe_impl == "dep":
+            from repro.core import dep as dep_lib
+            return n * dep_lib.expert_rows(batch, seq, self.cfg.moe, self.ctx,
+                                           self.E_pad, plan, capacity_scale)
+        return n * moe_lib.expert_rows(batch * seq, self.cfg.moe, self.E_pad,
+                                       self.ctx.moe_impl)
 
     # ---- caches ------------------------------------------------------------
     def init_cache(self, batch: int, seq_len: int, dtype=jnp.bfloat16):

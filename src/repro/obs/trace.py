@@ -32,14 +32,25 @@ emits the exact same ops — tracing off compiles the identical program
 chunk boundaries (``maybe_fence``) so phase spans bound device work
 instead of async dispatch; it is off by default because extra syncs cost
 wall time (the compiled program is identical either way).
+
+Every ``span`` also enters ``jax.profiler.TraceAnnotation("<track>.<name>")``
+so, under a running profiler, the same interval sits on the host plane of
+the profiler's trace, on the device trace's clock. Two hooks add spans the
+host loop does not open itself: ``watch_gc`` records each Python
+collection (``gc``, with its generation), and ``watch_compiles`` records
+each backend compile (``compile``) while a tracer is scoped.
 """
 from __future__ import annotations
 
+import gc
 import time
+import weakref
 from contextlib import contextmanager
 from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax
 
 
 @dataclass(frozen=True)
@@ -81,7 +92,8 @@ class TraceRecorder:
         self.clock = clock
         self.origin = clock()
         self.spans: List[Span] = []
-        self.dropped = 0
+        self._gc_hook = None
+        self._gc_open = None
 
     # -- recording ------------------------------------------------------
     def add_span(self, name: str, track: str, start: float, end: float,
@@ -95,13 +107,15 @@ class TraceRecorder:
     @contextmanager
     def span(self, name: str, track: str = "engine", cat: str = "phase",
              **args):
-        """Time a block: records one span on exit (even on error)."""
+        """Time a block: records one span on exit (even on error), and
+        marks it on the profiler's trace as ``<track>.<name>``."""
         if not self.enabled:
             yield self
             return
         t0 = self.clock()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(f"{track}.{name}"):
+                yield self
         finally:
             self.add_span(name, track, t0, self.clock(), cat=cat, **args)
 
@@ -171,8 +185,42 @@ class TraceRecorder:
 
     def clear(self) -> None:
         self.spans = []
-        self.dropped = 0
         self.origin = self.clock()
+
+    # -- host events outside the loop's own spans -----------------------
+    def watch_gc(self) -> None:
+        """Record each Python garbage collection as a ``gc`` phase span
+        (``generation`` arg), also marked on the profiler's trace, until
+        ``unwatch_gc`` or until this recorder is collected (the hook holds
+        it weakly)."""
+        if not self.enabled or self._gc_hook is not None:
+            return
+        on_gc = weakref.WeakMethod(self._on_gc)
+
+        def hook(phase, info):
+            method = on_gc()
+            if method is not None:
+                method(phase, info)
+        self._gc_hook = weakref.finalize(self, _unhook, hook)
+        gc.callbacks.append(hook)
+
+    def unwatch_gc(self) -> None:
+        if self._gc_hook is not None:
+            self._gc_hook()
+            self._gc_hook = None
+        self._gc_open = None
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            mark = jax.profiler.TraceAnnotation("engine.gc")
+            mark.__enter__()
+            self._gc_open = (self.clock(), mark)
+        elif self._gc_open is not None:
+            t0, mark = self._gc_open
+            self._gc_open = None
+            mark.__exit__(None, None, None)
+            self.add_span("gc", "engine", t0, self.clock(),
+                          generation=info.get("generation"))
 
     def __len__(self) -> int:
         return len(self.spans)
@@ -200,6 +248,33 @@ def active_tracer() -> Optional[TraceRecorder]:
     consults. Must stay cheap: it runs once per executor walk."""
     t = _ACTIVE.get()
     return t if (t is not None and t.enabled) else None
+
+
+def _unhook(hook) -> None:
+    if hook in gc.callbacks:
+        gc.callbacks.remove(hook)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_watching_compiles = False
+
+
+def _on_compile(event: str, duration: float, **kw) -> None:
+    tracer = active_tracer()
+    if event == _COMPILE_EVENT and tracer is not None:
+        now = tracer.clock()
+        tracer.add_span("compile", "engine", now - duration, now)
+
+
+def watch_compiles() -> None:
+    """Record each backend compile (or persistent-cache load) made while
+    a tracer is scoped as a ``compile`` phase span ending when JAX reports
+    it. Registers one listener a process; without a scoped tracer it
+    records nothing."""
+    global _watching_compiles
+    if not _watching_compiles:
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _watching_compiles = True
 
 
 @contextmanager

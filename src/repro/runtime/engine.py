@@ -48,7 +48,7 @@ import time
 import warnings
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,8 +60,8 @@ from repro.core.planner import FinDEPPlanner
 from repro.core.solver import Plan
 from repro.models import build_model
 from repro.models.transformer import ExecutionContext, Model
-from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import TraceRecorder, use_tracer
+from repro.obs.metrics import Counter, MetricsRegistry
+from repro.obs.trace import TraceRecorder, use_tracer, watch_compiles
 from repro.placement import (ExpertLoadTracker, Placement, SkewSummary,
                              capacity_scale, max_rank_load, rebalance)
 from repro.profiling import (DriftMonitor, PeriodicRecalibrator, PlanRefresher,
@@ -128,12 +128,6 @@ class EngineStats:
         self.steps = 0
         self.dropped_tokens = 0
         self.start_t = None
-
-    def throughput(self) -> float:
-        if self.start_t is None:
-            return 0.0
-        dt = time.perf_counter() - self.start_t
-        return (self.prefill_tokens + self.decode_tokens) / max(dt, 1e-9)
 
 
 class ServingEngine:
@@ -211,8 +205,7 @@ class ServingEngine:
         # programs are bit-identical either way (test-locked)
         if tracer is True:
             tracer = TraceRecorder()
-        self.tracer: Optional[TraceRecorder] = \
-            tracer if isinstance(tracer, TraceRecorder) else None
+        self.tracer = tracer if isinstance(tracer, TraceRecorder) else None
         # metrics: a repro.obs.MetricsRegistry (shared across engines),
         # None for a fresh private one (default on — sources are only
         # polled at snapshot time), or False to disable
@@ -395,6 +388,7 @@ class ServingEngine:
         self._memory = None
         self._h_ttft = self._h_tpot = None
         self._h_decode = self._h_prefill = None
+        self._c_rows: Dict[Tuple[str, str], Counter] = {}
         if self.metrics is not None:
             self._register_metrics(self.metrics)
 
@@ -428,6 +422,15 @@ class ServingEngine:
         self._h_prefill = m.histogram(
             "repro_engine_prefill_chunk_seconds",
             "prefill chunk wall time")
+        # expert rows per phase: what the MoE buffers hold (computed) and
+        # what the router sent (routed); their ratio is the padded share
+        for kind, what in (("computed", "rows the routed-expert FFN "
+                            "computes, padding included"),
+                           ("routed", "token-expert assignments routed")):
+            for phase in ("prefill", "decode"):
+                self._c_rows[kind, phase] = m.counter(
+                    f"repro_engine_expert_rows_{kind}_total", what,
+                    labels={"phase": phase})
         m.register_source("repro_engine", self._engine_snapshot)
         m.register_reset(self.stats.reset)
         if self.plan_cache is not None:
@@ -451,7 +454,6 @@ class ServingEngine:
                 "decode_tokens_total": float(self.stats.decode_tokens),
                 "steps_total": float(self.stats.steps),
                 "dropped_tokens_total": float(self.stats.dropped_tokens),
-                "throughput_tokens_per_s": self.stats.throughput(),
                 "waiting": float(len(self.waiting)),
                 "live_slots": float(sum(r is not None
                                         for r in self.slots))}
@@ -480,6 +482,29 @@ class ServingEngine:
                 self.kv.paging.reset()
         if self.tracer is not None:
             self.tracer.clear()
+
+    @property
+    def tracer(self) -> Optional[TraceRecorder]:
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer: Optional[TraceRecorder]) -> None:
+        """Set the engine's recorder, or unset it with None. A set
+        recorder also records garbage collections, and compiles made
+        while it is scoped; unsetting it stops both."""
+        old = getattr(self, "_tracer", None)
+        if old is not None:
+            old.unwatch_gc()
+        self._tracer = tracer
+        if tracer is not None and tracer.enabled:
+            tracer.watch_gc()
+            watch_compiles()
+
+    def _count_rows(self, phase: str, computed: int, routed: int) -> None:
+        """Add one MoE call's expert rows to the phase's counters."""
+        if self._c_rows:
+            self._c_rows["computed", phase].inc(computed)
+            self._c_rows["routed", phase].inc(routed)
 
     @contextmanager
     def _phase(self, name: str, **args):
@@ -560,7 +585,10 @@ class ServingEngine:
                                    key=key, breakdown=breakdown)
 
     def close(self) -> None:
-        """Stop the background refresh/recalibration workers (if any)."""
+        """Stop the background refresh/recalibration workers (if any)
+        and the tracer's collection hook."""
+        if self._tracer is not None:
+            self._tracer.unwatch_gc()
         if self.drift is not None:
             self.drift.close()
         if self.recalibrator is not None:
@@ -783,31 +811,35 @@ class ServingEngine:
         if group.bucket == 0:
             # empty/single-token prompts: nothing to prefill, the (only)
             # prompt token is fed through the shared decode step
-            for slot, req in zip(group.slots, group.requests):
-                self.kv.reset_slot(slot)
-                self._activate(slot, req, prefilled=0)
+            with self._phase("kv_merge"):
+                for slot, req in zip(group.slots, group.requests):
+                    self.kv.reset_slot(slot)
+                    self._activate(slot, req, prefilled=0)
             return
-        skew = self._current_skew()
-        plan = self._resolve_plan("prefill", group.bucket,
-                                  len(group.requests), skew=skew)
-        plan_key = ("prefill", group.bucket, len(group.requests))
-        if skew is not None:
-            plan_key = plan_key + (skew,)
-        chunk = len(group.requests)
-        n_mb = 1
-        if plan is not None:
-            # chunk granularity comes from the lowered task graph — the
-            # number of AG micro-batches one plan iteration admits, times
-            # the per-micro-batch sample count — rather than re-deriving
-            # plan.r1 * plan.m_a by hand (one Plan->structure translation).
-            # The SAME n_mb is the stream split of the interleaved prefill
-            # program below: one prefill call covers the n_mb micro-batch
-            # streams the solver scheduled, and the MoE walk interleaves
-            # them instead of the host loop running them back-to-back.
-            from repro.core.taskgraph import ATTN, LoweringSpec, lower
-            graph = lower(plan, LoweringSpec(T=1))
-            n_mb = len(graph.tasks_of(ATTN, layer=0))
-            chunk = max(min(n_mb * max(int(plan.m_a), 1), chunk), 1)
+        with self._phase("plan"):
+            skew = self._current_skew()
+            plan = self._resolve_plan("prefill", group.bucket,
+                                      len(group.requests), skew=skew)
+            plan_key = ("prefill", group.bucket, len(group.requests))
+            if skew is not None:
+                plan_key = plan_key + (skew,)
+            chunk = len(group.requests)
+            n_mb = 1
+            if plan is not None:
+                # chunk granularity comes from the lowered task graph —
+                # the number of AG micro-batches one plan iteration
+                # admits, times the per-micro-batch sample count — rather
+                # than re-deriving plan.r1 * plan.m_a by hand (one
+                # Plan->structure translation). The SAME n_mb is the
+                # stream split of the interleaved prefill program below:
+                # one prefill call covers the n_mb micro-batch streams the
+                # solver scheduled, and the MoE walk interleaves them
+                # instead of the host loop running them back-to-back.
+                from repro.core.taskgraph import ATTN, LoweringSpec, lower
+                graph = lower(plan, LoweringSpec(T=1))
+                n_mb = len(graph.tasks_of(ATTN, layer=0))
+                chunk = max(min(n_mb * max(int(plan.m_a), 1), chunk), 1)
+            cap_scale = self._capacity_scale(skew)
         for ofs in range(0, len(group.requests), chunk):
             reqs = group.requests[ofs:ofs + chunk]
             slots = group.slots[ofs:ofs + chunk]
@@ -823,22 +855,28 @@ class ServingEngine:
             t0 = time.perf_counter()
             with self._phase("prefill_chunk", bucket=group.bucket,
                              reqs=len(reqs)):
-                if self._track_load:
-                    logits, prefilled, mstats = self.model.prefill(
-                        self.params, jnp.asarray(toks),
-                        seq_budget=self.max_context,
-                        plan=self._exec_program(plan, streams=n_mb),
-                        placement=self.placement
-                        if self._dep_active else None,
-                        return_moe_stats=True,
-                        capacity_scale=self._capacity_scale(skew))
-                else:
-                    logits, prefilled = self.model.prefill(
-                        self.params, jnp.asarray(toks),
-                        seq_budget=self.max_context,
-                        plan=self._exec_program(plan, streams=n_mb))
-                    mstats = None
-                jax.block_until_ready(prefilled)
+                with self._phase("prefill_dispatch"):
+                    program = self._exec_program(plan, streams=n_mb)
+                    if self._track_load:
+                        logits, prefilled, mstats = self.model.prefill(
+                            self.params, jnp.asarray(toks),
+                            seq_budget=self.max_context, plan=program,
+                            placement=self.placement
+                            if self._dep_active else None,
+                            return_moe_stats=True, capacity_scale=cap_scale)
+                    else:
+                        logits, prefilled = self.model.prefill(
+                            self.params, jnp.asarray(toks),
+                            seq_budget=self.max_context, plan=program)
+                        mstats = None
+                with self._phase("prefill_wait"):
+                    jax.block_until_ready(prefilled)
+            self._count_rows(
+                "prefill",
+                self.model.expert_rows(
+                    len(reqs), group.bucket, program,
+                    cap_scale if self._track_load else 1.0),
+                sum(lengths) * self.model.routed_per_token)
             self.last_prefill_logits = (list(slots), logits[:, 0])
             if mstats is not None:
                 self.load_tracker.observe(np.asarray(mstats.load))
@@ -847,15 +885,16 @@ class ServingEngine:
             # prediction for a remainder chunk so it isn't biased short
             self._observe("prefill", plan_key, time.perf_counter() - t0,
                           plan, predicted_scale=len(reqs) / chunk)
-            if self._paged:
-                # token ids key the prefix cache: shared full blocks map
-                # to already-resident pages and skip the copy
-                self.kv.merge_prefill(slots, prefilled, lengths,
-                                      tokens=token_rows)
-            else:
-                self.kv.merge_prefill(slots, prefilled, lengths)
-            for slot, req, Lp in zip(slots, reqs, lengths):
-                self._activate(slot, req, prefilled=Lp)
+            with self._phase("kv_merge"):
+                if self._paged:
+                    # token ids key the prefix cache: shared full blocks
+                    # map to already-resident pages and skip the copy
+                    self.kv.merge_prefill(slots, prefilled, lengths,
+                                          tokens=token_rows)
+                else:
+                    self.kv.merge_prefill(slots, prefilled, lengths)
+                for slot, req, Lp in zip(slots, reqs, lengths):
+                    self._activate(slot, req, prefilled=Lp)
 
     def _activate(self, slot: int, req: Request, prefilled: int):
         self.stats.ensure_started()
@@ -892,9 +931,10 @@ class ServingEngine:
         self._prefill_group(PrefillGroup(bucket, [slot], [req]))
 
     def _admit(self) -> StepPlan:
-        step_plan = self.scheduler.build_step(
-            self.waiting, self.kv, max_context=self.max_context,
-            exact_length=self.cfg.family in ("ssm", "hybrid"))
+        with self._phase("schedule"):
+            step_plan = self.scheduler.build_step(
+                self.waiting, self.kv, max_context=self.max_context,
+                exact_length=self.cfg.family in ("ssm", "hybrid"))
         now = time.perf_counter()
         for req in step_plan.rejected:
             self._finish(req, RequestState.REJECTED, now)
@@ -923,7 +963,9 @@ class ServingEngine:
             mstats = None
         # use_topk is static: when no live request truncates, the compiled
         # program skips the per-slot [B, V] threshold sort entirely
-        nxt = sample(key, logits[:, -1], temps, top_ks if use_topk else 0)
+        with jax.named_scope("sampler"):
+            nxt = sample(key, logits[:, -1], temps,
+                         top_ks if use_topk else 0)
         return nxt[:, None], caches, mstats, logits[:, -1]
 
     # ------------------------------------------------------------------
@@ -1008,37 +1050,62 @@ class ServingEngine:
         if self._pending_placement is not None:
             pending, self._pending_placement = self._pending_placement, None
             self._apply_placement(pending)
-        # decode plan solved on the ledger's real composition (live slots
-        # + context-length histogram) AND the observed routing-skew
-        # fingerprint; re-resolves only when either changes
-        occ = self.kv.occupancy()
-        skew = self._current_skew()
-        plan = self._resolve_plan("decode", occupancy=occ, skew=skew)
-        plan_key = (("decode", occ) if skew is None
-                    else ("decode", occ, skew))
-        self.key, sub = jax.random.split(self.key)
-        use_topk = any(r is not None and r.top_k > 0 for r in self.slots)
-        # the ledger's per-slot context lengths drive the attention mask
-        # AND the ragged kernel's block skip (dead slots decode as len 0)
-        lengths = jnp.asarray(self.kv.lengths(), jnp.int32)
-        tables = self.kv.table_array() if self._paged else None
+        with self._phase("plan"):
+            # decode plan solved on the ledger's real composition (live
+            # slots + context-length histogram) AND the observed
+            # routing-skew fingerprint; re-resolves only when either
+            # changes
+            occ = self.kv.occupancy()
+            skew = self._current_skew()
+            plan = self._resolve_plan("decode", occupancy=occ, skew=skew)
+            plan_key = (("decode", occ) if skew is None
+                        else ("decode", occ, skew))
+            program = self._exec_program(plan)
+            cap_scale = self._capacity_scale(skew)
+            self.key, sub = jax.random.split(self.key)
+            use_topk = any(r is not None and r.top_k > 0
+                           for r in self.slots)
+            # the ledger's per-slot context lengths drive the attention
+            # mask AND the ragged kernel's block skip (dead slots decode
+            # as len 0)
+            lengths = jnp.asarray(self.kv.lengths(), jnp.int32)
+            tables = self.kv.table_array() if self._paged else None
         t0 = time.perf_counter()
         with self._phase("decode_step", step=self.stats.steps,
                          live=len(live)):
-            nxt, new_caches, mstats, self.last_logits = self._decode_jit(
-                self.params, self.last_tokens, self.kv.caches, self.temps,
-                self.top_ks, sub, lengths, tables,
-                plan=self._exec_program(plan), use_topk=use_topk,
-                placement=self.placement if self._dep_active else None,
-                cap_scale=self._capacity_scale(skew),
-                collect_stats=self._track_load)
-            jax.block_until_ready(nxt)
+            with self._phase("decode_dispatch"):
+                nxt, new_caches, mstats, self.last_logits = \
+                    self._decode_jit(
+                        self.params, self.last_tokens, self.kv.caches,
+                        self.temps, self.top_ks, sub, lengths, tables,
+                        plan=program, use_topk=use_topk,
+                        placement=self.placement
+                        if self._dep_active else None,
+                        cap_scale=cap_scale,
+                        collect_stats=self._track_load)
+            with self._phase("decode_wait"):
+                jax.block_until_ready(nxt)
+        measured = time.perf_counter() - t0
+        self._count_rows(
+            "decode",
+            self.model.expert_rows(self.num_slots, 1, program, cap_scale),
+            len(live) * self.model.routed_per_token)
+        with self._phase("emit"):
+            self._emit(live, nxt, new_caches, mstats, plan_key, plan,
+                       measured)
+        self.stats.steps += 1
+        return True
+
+    def _emit(self, live: List[int], nxt, new_caches, mstats, plan_key,
+              plan: Optional[Plan], measured: float) -> None:
+        """After a decode step: observe its time, install its caches and
+        tokens, read the tokens back and hand each live request its own,
+        finishing those that are done."""
         # measured decode wall-time vs the plan's modeled makespan: this is
         # the observe edge of the profiling loop — a sustained residual
         # breach re-solves THIS occupancy's plan on the refresh worker, so
         # the step itself never waits on Algorithm 1
-        self._observe("decode", plan_key, time.perf_counter() - t0,
-                      plan)
+        self._observe("decode", plan_key, measured, plan)
         if mstats is not None:
             # the observe edge of the PLACEMENT loop: gate loads feed the
             # EWMA tracker, capacity-overflow drops surface in the stats,
@@ -1067,8 +1134,6 @@ class ServingEngine:
                              else RequestState.LENGTH_CAPPED, now)
                 self.slots[i] = None
                 self.kv.free(i)
-        self.stats.steps += 1
-        return True
 
     def run(self, max_steps: int = 10_000) -> List[Request]:
         """Drive the engine until idle (or ``max_steps``); returns the

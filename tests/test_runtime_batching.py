@@ -259,14 +259,13 @@ def test_engine_stats_clock_starts_on_work_and_resets():
     cfg = get_smoke_config("qwen2-1.5b")
     eng = ServingEngine(cfg, num_slots=1, max_context=64, dtype=jnp.float32)
     assert eng.stats.start_t is None         # construction != serving
-    assert eng.stats.throughput() == 0.0
     rng = np.random.RandomState(5)
     eng.submit(Request(prompt=_prompts(rng, cfg, (5,))[0],
                        max_new_tokens=2))
     assert eng.stats.start_t is not None     # clock armed by submit
     eng.run()
-    assert eng.stats.decode_tokens == 2 and eng.stats.throughput() > 0.0
+    assert eng.stats.decode_tokens == 2
     eng.stats.reset()                        # benchmark warmup path
     assert eng.stats.start_t is None
     assert eng.stats.decode_tokens == 0 and eng.stats.prefill_tokens == 0
-    assert eng.stats.steps == 0 and eng.stats.throughput() == 0.0
+    assert eng.stats.steps == 0
