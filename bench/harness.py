@@ -114,9 +114,6 @@ class Run:
     prefill_s: float
     trace: Optional[object] = None       # trace_reduce.Reduction
     traced_steps: List[StepRecord] = field(default_factory=list)
-    # the system's expert-row counters at the close ("<kind>.<phase>"),
-    # empty where the program keeps none
-    expert_rows: dict = field(default_factory=dict)
 
     def in_window(self, t: float) -> bool:
         return self.t0 <= t < self.t1
@@ -585,26 +582,19 @@ def run_cell(args, *, spec: dict, t_start: float, log: Callable = print,
     return result
 
 
-def keep_excerpt(tr, path: str, out: str, steps, phases,
-                 first: Optional[int] = None, n: int = 3) -> None:
+def keep_excerpt(tr, path: str, out: str, steps, phases, first: int = 3,
+                 n: int = 3) -> None:
     """Write ``n`` traced steps from the ``first``, with the host-clock
     phase spans and step starts that belong to them, as a small recorded
-    trace (``out``.pbtxt and ``out``.json) for a test of the reduction.
-    By default the steps start one before the first step, from the
-    fourth on, that prefills (else at the fourth), so that they hold
-    decode-only steps and a prefill."""
-    if first is None:
-        prefills = [i for i, s in enumerate(steps) if i >= 4 and any(
-            nm == "prefill_chunk" and s.start <= a < s.end
-            for nm, a, _ in phases)]
-        first = prefills[0] - 1 if prefills else 3
-    planes, paths = tr.load(path)
+    trace (``out``.pbtxt and ``out``.json) for a test of the reduction."""
+    from jax.profiler import ProfileData
+    planes = list(ProfileData.from_file(path).planes)
     _, marks = tr.host_marks(planes)
     sel = marks[first:first + n]
     host = steps[first:first + n]
     lo, hi = sel[0][0], sel[-1][1]
     with open(out + ".pbtxt", "w") as f:
-        f.write(tr.excerpt(planes, lo, hi, paths))
+        f.write(tr.excerpt(planes, lo, hi))
     a, b = host[0].start, host[-1].end
     with open(out + ".json", "w") as f:
         json.dump({"step_host_starts": [s.start - a for s in host],

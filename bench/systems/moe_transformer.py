@@ -135,8 +135,7 @@ class System:
 
     def record_phases(self) -> None:
         """From now on keep the engine's phase spans (``admit``,
-        ``prefill_chunk``, ``decode_step`` and, where the engine has them,
-        the finer spans inside) on the host clock."""
+        ``prefill_chunk``, ``decode_step``) on the host clock."""
         from repro.obs.trace import TraceRecorder
         self.engine.tracer = TraceRecorder()
 
@@ -151,17 +150,11 @@ class System:
     def timings(self) -> dict:
         """The engine's own fenced timings since ``reset_stats``: summed
         seconds of its decode steps and their count, and summed seconds of
-        its prefill chunks; and its expert-row counters, where it keeps
-        them (``expert_rows``: "computed.prefill", "routed.decode", ...)."""
+        its prefill chunks."""
         h = self.engine.metrics.histogram
         dec = h("repro_engine_decode_step_seconds")
-        # repro_engine_expert_rows_<kind>_total{phase=...}
-        rows = {f"{m.name.split('_')[-2]}.{dict(m.labels)['phase']}": m.value
-                for m in self.engine.metrics.metrics()
-                if m.name.startswith("repro_engine_expert_rows_")}
         return {"decode_s": dec.sum, "decode_n": dec.count,
-                "prefill_s": h("repro_engine_prefill_chunk_seconds").sum,
-                "expert_rows": rows}
+                "prefill_s": h("repro_engine_prefill_chunk_seconds").sum}
 
     def free(self) -> None:
         """Drop the engine's cache and close it; the weights stay with

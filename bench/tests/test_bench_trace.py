@@ -29,8 +29,8 @@ def _xspace(device_events, host_events):
 
 
 def _reduce(text, **kw):
-    planes, paths = tr.load_text(text)
-    return tr.reduce_planes(planes, paths=paths, **kw)
+    from jax.profiler import ProfileData
+    return tr.reduce_planes(ProfileData.from_text_proto(text).planes, **kw)
 
 
 def test_busy_union_kernels_and_gaps():
@@ -118,165 +118,3 @@ def test_recorded_chip_trace():
     r2 = tr.reduce_planes(list(again))
     assert (r2.kernel_count, r2.busy_s, r2.window_s) == \
         (r.kernel_count, r.busy_s, r.window_s)
-
-
-def _stat_xspace(device_events, host_events):
-    """Text XSpace whose device operations carry a scope path in their
-    event metadata, as a TPU trace has it: (name, start_ns, duration_ns,
-    path or None)."""
-    paths = {e[0]: e[3] for e in device_events}
-    ids = {n: i + 1 for i, n in enumerate(sorted(paths))}
-    dev = ['planes { id: 1 name: "/device:TPU:0"',
-           '  lines { id: 1 name: "XLA Ops" timestamp_ns: 0']
-    dev += [f"    events {{ metadata_id: {ids[n]} offset_ps: {s * 1000}"
-            f" duration_ps: {d * 1000} }}" for n, s, d, _ in device_events]
-    dev.append("  }")
-    for n, i in ids.items():
-        stat = (f' stats {{ metadata_id: 1 str_value: '
-                f'"{tr._quote(paths[n])}" }}' if paths[n] else "")
-        dev.append(f'  event_metadata {{ key: {i} value {{ id: {i} '
-                   f'name: "{n}"{stat} }} }}')
-    dev.append(f'  stat_metadata {{ key: 1 value {{ id: 1 name: '
-               f'"{tr.SCOPE_STAT}" }} }}')
-    dev.append("}")
-    host = _xspace([], host_events).split("\n", 1)[1]
-    host = host[host.index("planes { id: 2"):]
-    return "\n".join(dev) + "\n" + host
-
-
-def test_scope_time_and_busy_inside_span_marks():
-    # window 0..1000 ns. Decode-program ops under moe/expert_ffn (one
-    # path of two joined by ';'), the sampler, and a nested scope name
-    # that is not a scope read; an eager op with the same scope name but
-    # another program; an op with no path.
-    P = tr.SCOPE_PROGRAM
-    dev = [("fusion.1", 100, 50, f"{P}/moe/expert_ffn/dot_general"),
-           ("fusion.2", 160, 40,
-            f"{P}/moe/router/add;{P}/moe/expert_ffn/mul"),
-           ("sort.3", 300, 30, f"{P}/sampler/sort"),
-           ("fusion.4", 400, 20, f"{P}/xmoe/expert_ffn_x/add"),
-           ("dot.5", 600, 100, "jit(dot_general)/moe/expert_ffn/dot"),
-           ("copy.6", 800, 10, None),
-           ("fusion.1", 990, 30, f"{P}/moe/expert_ffn/dot_general")]
-    # two prefill marks, 550..750 and 780..880 (busy 600..700 and
-    # 800..810), and one that starts before the window
-    host = [("bench.traced", 0, 1000), ("engine.prefill_chunk", 550, 200),
-            ("engine.prefill_chunk", 780, 100),
-            ("engine.decode_step", -50, 250)]
-    r = _reduce(_stat_xspace(dev, host))
-    assert r.scope_s == {"moe/expert_ffn": pytest.approx(100e-9),
-                         "moe/router": pytest.approx(40e-9),
-                         "sampler": pytest.approx(30e-9)}
-    assert r.span_s["engine.prefill_chunk"] == pytest.approx(300e-9)
-    assert r.span_busy_s["engine.prefill_chunk"] == \
-        pytest.approx(110e-9)
-    assert r.span_s["engine.decode_step"] == pytest.approx(200e-9)
-    assert r.span_busy_s["engine.decode_step"] == pytest.approx(90e-9)
-    # a trace without paths or marks reads nothing, and raises nothing
-    bare = _reduce(_xspace([(n, s, d) for n, s, d, _ in dev],
-                           [("bench.traced", 0, 1000)]))
-    assert (bare.scope_s, bare.span_s, bare.span_busy_s) == ({}, {}, {})
-
-
-@pytest.mark.parametrize("path, scope, inside", [
-    ("jit(_decode_step)/moe/expert_ffn/dot_general", "moe/expert_ffn",
-     True),
-    ("jit(_decode_step)/jit(main)/moe/expert_ffn/jit(silu)/mul",
-     "moe/expert_ffn", True),
-    ("jit(_decode_step)/sampler", "sampler", False),
-    ("jit(_decode_step)/attention/jit(decode_attention)/while",
-     "attention", True),
-    ("jit(_decode_step)/lm_head_x/dot", "lm_head", False),
-    ("jit(prefill)/moe/expert_ffn/dot", "moe/expert_ffn", False),
-    ("jit(_decode_step)/moe/router/top_k;jit(_decode_step)/sampler/sort",
-     "sampler", True)])
-def test_in_scope_reads_whole_components_of_the_program(path, scope,
-                                                        inside):
-    assert tr.in_scope(path, scope) is inside
-
-
-def _reader(name):
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "reader_" + name, os.path.join(BENCH, "metrics", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def test_span_and_scope_readers_and_their_silence():
-    """The four readers of what the program itself marks: each reads its
-    field, and a program without the marks, scopes or counters (the
-    parent of the change that added them) gives nothing, not a zero."""
-    from types import SimpleNamespace as NS
-    steps = [NS(keys=[5, 9]), NS(keys=[]), NS(keys=[7])]
-    red = tr.Reduction(
-        window_s=1.0, busy_s=0.5, devices=1, kernel_s={}, kernel_count={},
-        top_ops=[], idle_gaps=[],
-        scope_s={"moe/expert_ffn": 0.02, "sampler": 0.008},
-        span_s={"engine.prefill_chunk": 0.4},
-        span_busy_s={"engine.prefill_chunk": 0.1})
-    run = NS(trace=red, traced_steps=steps,
-             expert_rows={"computed.prefill": 600.0,
-                          "routed.prefill": 24.0})
-    assert _reader("decode_expert_ffn_ms")(run) == pytest.approx(10.0)
-    assert _reader("decode_sampler_ms")(run) == pytest.approx(4.0)
-    assert _reader("prefill_device_busy_share")(run) == pytest.approx(25.0)
-    assert _reader("prefill_expert_row_share")(run) == pytest.approx(4.0)
-    bare = tr.Reduction(window_s=1.0, busy_s=0.5, devices=1, kernel_s={},
-                        kernel_count={}, top_ops=[], idle_gaps=[])
-    parent = NS(trace=bare, traced_steps=steps, expert_rows={})
-    for name in ("decode_expert_ffn_ms", "decode_sampler_ms",
-                 "prefill_device_busy_share", "prefill_expert_row_share"):
-        assert _reader(name)(parent) is None, name
-        assert _reader(name)(NS(trace=None, traced_steps=[],
-                                expert_rows={})) is None, name
-
-
-def test_excerpt_keeps_scope_paths_and_span_marks():
-    P = tr.SCOPE_PROGRAM
-    dev = [("fusion.1", 100, 50, f"{P}/moe/expert_ffn/dot_general"),
-           ("sort.3", 300, 30, f'{P}/sampler/sort "q"'),
-           ("copy.6", 800, 10, None)]
-    host = [("bench.traced", 0, 1000), ("engine.prefill_chunk", 550, 200),
-            ("engine.gc", 560, 10), ("other", 0, 5)]
-    planes, paths = tr.load_text(_stat_xspace(dev, host))
-    text = tr.excerpt(planes, 0, 1e18, paths)
-    assert "other" not in text
-    r = _reduce(text)
-    assert r.scope_s == {"moe/expert_ffn": pytest.approx(50e-9),
-                         "sampler": pytest.approx(30e-9)}
-    assert set(r.span_s) == {"engine.prefill_chunk", "engine.gc"}
-
-
-def test_recorded_engine_spans_trace():
-    """Three steps of qwen2moe.chat traced on a TPU v5e chip with the
-    program's own marks (``run.py --trace 1 --keep-trace``): a decode
-    step, a step that prefills one prompt and decodes, a decode step.
-    Read by hand from the text: 54 operations whose metadata path lies
-    under ``jit(_decode_step)/.../moe/expert_ffn/`` (6 layers x 3 matrices
-    x 3 steps) lasting 24,776,535 ns in all; 45 under ``sampler`` lasting
-    11,324,248 ns; one ``engine.prefill_chunk`` mark of 422,437,319 ns,
-    inside which the union of the device's operations lasts 52,159,442
-    ns."""
-    import gzip
-    import json
-    from types import SimpleNamespace as NS
-    data = os.path.join(BENCH, "tests", "data", "v5e-engine-spans")
-    with gzip.open(data + ".pbtxt.gz", "rt") as f:
-        planes, paths = tr.load_text(f.read())
-    with open(data + ".json") as f:
-        host = json.load(f)
-    r = tr.reduce_planes(planes, paths=paths, phases=host["phases"],
-                         step_host_starts=host["step_host_starts"])
-    run = NS(trace=r, traced_steps=[NS(keys=[1])] * 3, expert_rows={})
-    assert _reader("decode_expert_ffn_ms")(run) == \
-        pytest.approx(24.776535 / 3)
-    assert _reader("decode_sampler_ms")(run) == \
-        pytest.approx(11.324248 / 3)
-    assert _reader("prefill_device_busy_share")(run) == \
-        pytest.approx(100 * 52159442 / 422437319)
-    # every idle gap is named after a span finer than the step
-    assert r.idle_gaps and {n for n, _ in r.idle_gaps} <= {
-        "schedule", "plan", "decode_dispatch", "decode_wait", "emit",
-        "prefill_dispatch", "prefill_wait", "kv_merge", "gc", "compile"}

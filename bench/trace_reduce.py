@@ -19,18 +19,6 @@ numbers the per-layer metrics read.
 * Each idle gap is named after what the host was doing at its middle: the
   innermost engine phase, ``step_other`` inside a step but outside every
   phase, or ``between_steps``.
-* A scope's time is the summed duration of the operations of one program
-  (the decode step's, ``jit(_decode_step)``) whose scope path holds the
-  scope's name as whole path components. The path is the stat
-  ``SCOPE_STAT`` of the operation's event metadata
-  (``jit(_decode_step)/moe/expert_ffn/ecm,emh->ech/dot_general:``), which
-  ``ProfileData`` does not expose: ``scope_paths`` reads it from the
-  serialized XSpace.
-* Busy time inside a host span: where the program itself marks its spans
-  on the profiler's trace (``engine.<name>`` annotations on a host
-  plane), the part of their union in which some operation ran, averaged
-  over the devices; no alignment is needed, the marks are on the
-  profiler's clock.
 """
 from __future__ import annotations
 
@@ -39,20 +27,11 @@ import glob
 import os
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 WINDOW_MARK = "bench.traced"
 STEP_MARK = "bench.step"
 OPS_LINE = "XLA Ops"
-#: the op stat that holds an operation's scope path
-SCOPE_STAT = "tf_op"
-#: the program whose operations' scopes are read
-SCOPE_PROGRAM = "jit(_decode_step)"
-#: the named scopes read, as the program names them
-SCOPES = ("attention", "moe/router", "moe/dispatch", "moe/expert_ffn",
-          "moe/combine", "moe/shared_expert", "lm_head", "sampler")
-#: prefix of the program's own span marks on a host plane
-SPAN_MARK = "engine."
 
 
 @dataclass
@@ -65,9 +44,6 @@ class Reduction:
     top_ops: List[Tuple[str, float]]
     idle_gaps: List[Tuple[str, float]]
     idle_by_phase: Dict[str, float] = field(default_factory=dict)
-    scope_s: Dict[str, float] = field(default_factory=dict)  # per device
-    span_s: Dict[str, float] = field(default_factory=dict)   # mark -> union
-    span_busy_s: Dict[str, float] = field(default_factory=dict)
 
 
 def find_xplane(directory: str) -> str:
@@ -97,78 +73,6 @@ def is_kernel(event_name: str, kernel: str) -> bool:
                             and op[len(kernel) + 1:].isdigit())
 
 
-def _space_class():
-    """A message class that reads, of a serialized XSpace, each plane's
-    name and its event and stat metadata (field numbers of
-    ``tsl/profiler/protobuf/xplane.proto``); every other field, the
-    events included, is skipped."""
-    from google.protobuf import descriptor_pb2, descriptor_pool
-    from google.protobuf import message_factory
-    F = descriptor_pb2.FieldDescriptorProto
-    fd = descriptor_pb2.FileDescriptorProto(name="bench_xspace.proto",
-                                            package="bench_xspace")
-    one, many = F.LABEL_OPTIONAL, F.LABEL_REPEATED
-
-    def message(name, *fields):
-        m = fd.message_type.add(name=name)
-        for fname, number, ftype, label, tname in fields:
-            f = m.field.add(name=fname, number=number, type=ftype,
-                            label=label)
-            if tname:
-                f.type_name = ".bench_xspace." + tname
-
-    message("Stat", ("metadata_id", 1, F.TYPE_INT64, one, None),
-            ("str_value", 5, F.TYPE_STRING, one, None),
-            ("ref_value", 7, F.TYPE_UINT64, one, None))
-    message("EventMetadata", ("name", 2, F.TYPE_STRING, one, None),
-            ("stats", 5, F.TYPE_MESSAGE, many, "Stat"))
-    message("StatMetadata", ("name", 2, F.TYPE_STRING, one, None))
-    # a map is a repeated message of (key = 1, value = 2)
-    message("EventMetadataEntry", ("key", 1, F.TYPE_INT64, one, None),
-            ("value", 2, F.TYPE_MESSAGE, one, "EventMetadata"))
-    message("StatMetadataEntry", ("key", 1, F.TYPE_INT64, one, None),
-            ("value", 2, F.TYPE_MESSAGE, one, "StatMetadata"))
-    message("Plane", ("name", 2, F.TYPE_STRING, one, None),
-            ("event_metadata", 4, F.TYPE_MESSAGE, many,
-             "EventMetadataEntry"),
-            ("stat_metadata", 5, F.TYPE_MESSAGE, many, "StatMetadataEntry"))
-    message("Space", ("planes", 1, F.TYPE_MESSAGE, many, "Plane"))
-    pool = descriptor_pool.DescriptorPool()
-    pool.Add(fd)
-    return message_factory.GetMessageClass(
-        pool.FindMessageTypeByName("bench_xspace.Space"))
-
-
-def scope_paths(serialized: bytes) -> Dict[str, Dict[str, str]]:
-    """Per device plane, the scope path (``SCOPE_STAT``) of each operation
-    name that has one; distinct paths of one name are joined by ``;``."""
-    space = _space_class()()
-    space.ParseFromString(serialized)
-    out: Dict[str, Dict[str, str]] = {}
-    for plane in space.planes:
-        if not plane.name.startswith("/device:"):
-            continue
-        names = {e.key: e.value.name for e in plane.stat_metadata}
-        keys = {k for k, n in names.items() if n == SCOPE_STAT}
-        paths: Dict[str, set] = defaultdict(set)
-        for e in plane.event_metadata:
-            for st in e.value.stats:
-                if st.metadata_id in keys:
-                    paths[e.value.name].add(
-                        st.str_value or names.get(st.ref_value, ""))
-        out[plane.name] = {n: ";".join(sorted(p)) for n, p in paths.items()}
-    return out
-
-
-def in_scope(path: str, scope: str) -> bool:
-    """Whether a scope path (several may be joined by ``;``) names an
-    operation of ``SCOPE_PROGRAM`` under ``scope``: the scope's components
-    appear whole, in order, after the program's."""
-    n = len(SCOPE_PROGRAM)
-    return any(p.startswith(SCOPE_PROGRAM + "/") and f"/{scope}/" in p[n:]
-               for p in path.split(";"))
-
-
 def union(intervals: Sequence[Tuple[float, float]]
           ) -> List[Tuple[float, float]]:
     out: List[List[float]] = []
@@ -182,37 +86,6 @@ def union(intervals: Sequence[Tuple[float, float]]
 
 def _clip(iv, lo, hi):
     return [(max(a, lo), min(b, hi)) for a, b in iv if b > lo and a < hi]
-
-
-def overlap(a: Sequence[Tuple[float, float]],
-            b: Sequence[Tuple[float, float]]) -> float:
-    """Length of the intersection of two sorted disjoint interval lists."""
-    i = j = 0
-    total = 0.0
-    while i < len(a) and j < len(b):
-        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
-        if hi > lo:
-            total += hi - lo
-        if a[i][1] < b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def span_marks(planes, lo: float, hi: float
-               ) -> Dict[str, List[Tuple[float, float]]]:
-    """The program's own span marks (``engine.<name>``) on every host
-    line: per name, the union of their intervals inside [lo, hi)."""
-    marks: Dict[str, list] = defaultdict(list)
-    for plane in planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for name, a, b in _events(line):
-                if name.startswith(SPAN_MARK):
-                    marks[name].append((a, b))
-    return {n: _clip(union(iv), lo, hi) for n, iv in marks.items()}
 
 
 def host_marks(planes) -> Tuple[List[Tuple[float, float]],
@@ -263,15 +136,11 @@ def _phase_at(t, phases, steps):
 def reduce_planes(planes, kernels: Sequence[str] = ("decode_attention",),
                   phases: Sequence[Tuple[str, float, float]] = (),
                   step_host_starts: Sequence[float] = (),
-                  top: int = 10,
-                  paths: Optional[Dict[str, Dict[str, str]]] = None
-                  ) -> Reduction:
+                  top: int = 10) -> Reduction:
     """``planes``: a ``jax.profiler.ProfileData``'s planes. ``phases``:
     the engine's phase spans on the host clock (seconds);
     ``step_host_starts``: the host-clock start of each traced step, in
-    order, matching the step marks; ``paths``: the operations' scope
-    paths (``scope_paths``), from which ``scope_s`` holds the time of each
-    of ``SCOPES`` that some operation has."""
+    order, matching the step marks."""
     planes = list(planes)
     windows, steps = host_marks(planes)
     devices = [p for p in planes if p.name.startswith("/device:TPU:")]
@@ -285,20 +154,14 @@ def reduce_planes(planes, kernels: Sequence[str] = ("decode_attention",),
     kernel_ns: Dict[str, float] = defaultdict(float)
     kernel_n: Dict[str, int] = defaultdict(int)
     op_ns: Dict[str, float] = defaultdict(float)
-    scope_ns: Dict[str, float] = defaultdict(float)
-    marks = span_marks(planes, lo, hi)
-    mark_busy: Dict[str, float] = defaultdict(float)
     gaps_all = []
     ph = align_phases(steps, list(step_host_starts), list(phases))
     for plane in devices:
         ivs = []
-        plane_paths = (paths or {}).get(plane.name, {})
         for line in plane.lines:
             if line.name != OPS_LINE:
                 continue
-            for e in line.events:
-                name = e.name
-                a, b = e.start_ns, e.start_ns + e.duration_ns
+            for name, a, b in _events(line):
                 if b <= lo or a >= hi:
                     continue
                 a, b = max(a, lo), min(b, hi)
@@ -308,14 +171,8 @@ def reduce_planes(planes, kernels: Sequence[str] = ("decode_attention",),
                     if is_kernel(name, k):
                         kernel_ns[k] += b - a
                         kernel_n[k] += 1
-                path = plane_paths.get(name)
-                for sc in SCOPES if path else ():
-                    if in_scope(path, sc):
-                        scope_ns[sc] += b - a
         u = _clip(union(ivs), lo, hi)
         busy.append(sum(b - a for a, b in u))
-        for name, iv in marks.items():
-            mark_busy[name] += overlap(u, iv)
         edges = [lo] + [x for iv in u for x in iv] + [hi]
         for a, b in zip(edges[::2], edges[1::2]):
             if b > a:
@@ -325,7 +182,6 @@ def reduce_planes(planes, kernels: Sequence[str] = ("decode_attention",),
         by_phase[name] += d / 1e9 / len(devices)
     gaps_all.sort(key=lambda g: -g[1])
     tops = sorted(op_ns.items(), key=lambda kv: -kv[1])[:top]
-    n = len(devices)
     return Reduction(
         window_s=window_ns / 1e9,
         busy_s=sum(busy) / len(devices) / 1e9,
@@ -333,12 +189,8 @@ def reduce_planes(planes, kernels: Sequence[str] = ("decode_attention",),
         kernel_s={k: kernel_ns[k] / 1e9 / len(devices) for k in kernels},
         kernel_count={k: kernel_n[k] for k in kernels},
         top_ops=[(n, d / 1e9 / len(devices)) for n, d in tops],
-        idle_gaps=[(g, d / 1e9) for g, d in gaps_all[:top]],
-        idle_by_phase=dict(by_phase),
-        scope_s={sc: t / 1e9 / n for sc, t in scope_ns.items()},
-        span_s={m: sum(b - a for a, b in iv) / 1e9
-                for m, iv in marks.items()},
-        span_busy_s={m: mark_busy[m] / 1e9 / n for m in marks})
+        idle_gaps=[(n, d / 1e9) for n, d in gaps_all[:top]],
+        idle_by_phase=dict(by_phase))
 
 
 def _quote(text: str) -> str:
@@ -346,21 +198,18 @@ def _quote(text: str) -> str:
     return text.replace("\\", "\\\\").replace('"', '\\"')
 
 
-def excerpt(planes, lo_ns: float, hi_ns: float,
-            paths: Optional[Dict[str, Dict[str, str]]] = None) -> str:
-    """The events of the device planes' ``XLA Ops`` lines, with each
-    operation's scope path (``SCOPE_STAT``), and the host marks (the
-    harness's and the program's own ``engine.<name>``) that overlap
-    [lo_ns, hi_ns), clipped to it, as a text XSpace with times from
-    ``lo_ns``: a small recorded trace for a test of this reduction."""
+def excerpt(planes, lo_ns: float, hi_ns: float) -> str:
+    """The events of the device planes' ``XLA Ops`` lines and the host
+    marks that overlap [lo_ns, hi_ns), clipped to it, as a text XSpace
+    with times from ``lo_ns``: a small recorded trace for a test of this
+    reduction."""
     out = []
     for pid, plane in enumerate(planes, 1):
         if plane.name.startswith("/device:TPU:"):
             keep = lambda line, name: line.name == OPS_LINE  # noqa: E731
         elif plane.name.startswith("/host:"):
-            keep = lambda line, name: (name in (WINDOW_MARK,  # noqa: E731
-                                                STEP_MARK)
-                                       or name.startswith(SPAN_MARK))
+            keep = lambda line, name: name in (WINDOW_MARK,  # noqa: E731
+                                               STEP_MARK)
         else:
             continue
         names: Dict[str, int] = {}
@@ -381,47 +230,20 @@ def excerpt(planes, lo_ns: float, hi_ns: float,
             lines.append("\n".join(body + ["  }"]))
         if not lines:
             continue
-        plane_paths = (paths or {}).get(plane.name, {})
-
-        def stat(n):
-            if n not in plane_paths:
-                return ""
-            return (f' stats {{ metadata_id: 1 str_value: '
-                    f'"{_quote(plane_paths[n])}" }}')
         meta = [f'  event_metadata {{ key: {i} value {{ id: {i} name: '
-                f'"{_quote(n)}"{stat(n)} }} }}' for n, i in names.items()]
-        if any(n in plane_paths for n in names):
-            meta.append(f'  stat_metadata {{ key: 1 value {{ id: 1 name: '
-                        f'"{SCOPE_STAT}" }} }}')
+                f'"{_quote(n)}" }} }}' for n, i in names.items()]
         out.append("\n".join([f'planes {{ id: {pid} name: "{plane.name}"']
                              + lines + meta + ["}"]))
     return "\n".join(out) + "\n"
 
 
-def load(path: str):
-    """(planes, scope paths) of an ``.xplane.pb`` file, or of an XSpace in
-    text form (``.pbtxt``)."""
-    if path.endswith(".pbtxt"):
-        with open(path) as f:
-            return load_text(f.read())
-    with open(path, "rb") as f:
-        return _load(f.read())
-
-
-def load_text(text: str):
-    """(planes, scope paths) of an XSpace in text form."""
-    from jax.profiler import ProfileData
-    return _load(ProfileData.text_proto_to_serialized_xspace(text))
-
-
-def _load(serialized: bytes):
-    from jax.profiler import ProfileData
-    planes = ProfileData.from_serialized_xspace(serialized).planes
-    return list(planes), scope_paths(serialized)
-
-
 def reduce_file(path: str, **kw) -> Reduction:
     """Reduce an ``.xplane.pb`` file, or an XSpace in text form
     (``.pbtxt``)."""
-    planes, paths = load(path)
-    return reduce_planes(planes, paths=paths, **kw)
+    from jax.profiler import ProfileData
+    if path.endswith(".pbtxt"):
+        with open(path) as f:
+            data = ProfileData.from_text_proto(f.read())
+    else:
+        data = ProfileData.from_file(path)
+    return reduce_planes(data.planes, **kw)
