@@ -34,8 +34,8 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 # (8.09 GB of weights, 1.61 GB of K/V cache), 1.62 GB of outputs (the
 # updated cache) and 0.15 GB of temporaries: 11.5 GB. A seventh layer
 # would add 1.14 GB of weights and 0.54 GB of cache in and out (13.2 GB)
-# and leave too little for the eager prefill of the 1100-token prompt,
-# whose no-drop expert buffers take about 2 GB; eight layers need 14.9 GB.
+# and leave too little for the prefill of the 1100-token prompt, whose
+# no-drop expert buffers take about 2 GB; eight layers need 14.9 GB.
 ONE_CHIP_LAYERS = 6
 ONE_CHIP_SLOTS = 16
 ONE_CHIP_CONTEXT = 2048
@@ -338,8 +338,8 @@ def four_chip_phase(devices, cfg=None, *, num_slots=FOUR_CHIP_SLOTS,
         print(f"peak_bytes_in_use {(d.memory_stats() or {}).get('peak_bytes_in_use')} on {d}")
 
     # the same weights and tokens through capacity MoE on the same mesh,
-    # op by op as the engine prefills (the 24 layers share their compiled
-    # ops, where one jit of the decode step would compile all 24 again)
+    # op by op (the 24 layers share their compiled ops, where one jit of
+    # the prefill and the decode step would compile all 24 again)
     cap = build_model(cfg, ctx=ExecutionContext(
         mesh=mesh, attn_impl="decode_kernel", moe_impl="capacity"),
         dtype=jnp.bfloat16)

@@ -379,6 +379,15 @@ class Model:
         path; stats_sink: optional list collecting per-MoE-layer
         ``moe.MoEStats`` (Python-loop path only — scanned layers skip
         collection). Returns (logits, new_caches, aux)."""
+        x, new_caches, aux_total = self._hidden(
+            params, tokens, extra_embeds, memory, caches, plan, placement,
+            stats_sink, capacity_scale)
+        return self._head(params, x), new_caches, aux_total
+
+    def _hidden(self, params, tokens, extra_embeds, memory, caches, plan,
+                placement, stats_sink, capacity_scale):
+        """The layers of ``forward``: (hidden states [B, S, M] before the
+        final norm, new_caches, aux)."""
         cfg = self.cfg
         plan = plan if plan is not None else self.plan
         if cfg.is_encoder_decoder and memory is None and extra_embeds is not None:
@@ -410,10 +419,12 @@ class Model:
                     fn = jax.checkpoint(layer_fn, static_argnums=(1,))
                 x, new_caches[i], aux = fn(params["layers"][i], kind, x, cache)
                 aux_total = aux_total + aux
+        return x, new_caches, aux_total
 
-        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-        logits = self._readout(params, x)
-        return logits, new_caches, aux_total
+    def _head(self, params, x):
+        """Final norm and output head: float32 logits of hidden rows."""
+        x = rmsnorm_apply(params["final_norm"], x, self.cfg.norm_eps)
+        return self._readout(params, x)
 
     def _scan_groups(self, params, x, caches, layer_fn):
         """lax.scan over stacked pattern groups."""
@@ -503,16 +514,17 @@ class Model:
             off = extra_embeds.shape[1]         # logits include image slots
         caches = self.init_cache(B, budget, cache_dtype or self.dtype)
         sink = [] if return_moe_stats else None
-        logits, caches, _ = self.forward(params, tokens, extra_embeds,
-                                         memory, caches, plan=plan,
-                                         placement=placement,
-                                         stats_sink=sink,
-                                         capacity_scale=capacity_scale)
+        x, caches, _ = self._hidden(params, tokens, extra_embeds, memory,
+                                    caches, plan, placement, sink,
+                                    capacity_scale)
+        # the head runs on the returned rows only: the other S-1 rows'
+        # [B, S, V] float32 logits would be computed and thrown away
         if last_positions is not None:
             pos = jnp.asarray(last_positions, jnp.int32) + off
-            last = logits[jnp.arange(B), pos][:, None]      # [B, 1, V]
+            x = x[jnp.arange(B), pos][:, None]              # [B, 1, M]
         else:
-            last = logits[:, -1:]
+            x = x[:, -1:]
+        last = self._head(params, x)                        # [B, 1, V]
         if return_moe_stats:
             return last, caches, _stack_moe_stats(sink)
         return last, caches
@@ -554,8 +566,7 @@ class Model:
                 x, nc, a = layer_fn(params["layers"][i], kind, x, caches[i])
                 new_caches.append(nc)
                 aux = aux + a
-        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-        logits = self._readout(params, x)
+        logits = self._head(params, x)
         if return_moe_stats:
             return logits, new_caches, _stack_moe_stats(sink or [])
         return logits, new_caches

@@ -7,8 +7,9 @@ The engine owns almost nothing anymore — each iteration is
      prompts, admit under the configured admission policy (fcfs / spf /
      token_budget), allocate KV slots, group admitted requests by padded
      prefill bucket;
-  2. one batched ``model.prefill`` per ``PrefillGroup`` (chunked by the
-     resolved plan's r1·m_a granularity), scattered into per-slot caches
+  2. one batched, jitted ``model.prefill`` per ``PrefillGroup`` (chunked
+     by the resolved plan's r1·m_a granularity; one compiled program per
+     [rows, bucket] shape and static key), scattered into per-slot caches
      by the ``KVCacheManager``;
   3. one ``model.decode_step`` over the full slot batch, with per-slot
      temperature/top-k sampling; finished slots are evicted and their
@@ -385,10 +386,18 @@ class ServingEngine:
             self._decode_step,
             static_argnames=("plan", "use_topk", "placement",
                              "cap_scale", "collect_stats"))
+        # the prefill likewise: one program per [rows, bucket] token shape
+        # and static key, with the weights a traced argument (never baked
+        # into the program as constants)
+        self._prefill_jit = jax.jit(
+            self._prefill,
+            static_argnames=("plan", "placement", "cap_scale",
+                             "collect_stats", "seq_budget"))
         self._memory = None
         self._h_ttft = self._h_tpot = None
         self._h_decode = self._h_prefill = None
         self._c_rows: Dict[Tuple[str, str], Counter] = {}
+        self._c_prefill_traces: Optional[Counter] = None
         if self.metrics is not None:
             self._register_metrics(self.metrics)
 
@@ -431,6 +440,9 @@ class ServingEngine:
                 self._c_rows[kind, phase] = m.counter(
                     f"repro_engine_expert_rows_{kind}_total", what,
                     labels={"phase": phase})
+        self._c_prefill_traces = m.counter(
+            "repro_engine_prefill_traces_total",
+            "prefill programs traced: distinct token shapes and static keys")
         m.register_source("repro_engine", self._engine_snapshot)
         m.register_reset(self.stats.reset)
         if self.plan_cache is not None:
@@ -857,27 +869,21 @@ class ServingEngine:
                              reqs=len(reqs)):
                 with self._phase("prefill_dispatch"):
                     program = self._exec_program(plan, streams=n_mb)
-                    if self._track_load:
-                        logits, prefilled, mstats = self.model.prefill(
-                            self.params, jnp.asarray(toks),
-                            seq_budget=self.max_context, plan=program,
-                            placement=self.placement
-                            if self._dep_active else None,
-                            return_moe_stats=True, capacity_scale=cap_scale)
-                    else:
-                        logits, prefilled = self.model.prefill(
-                            self.params, jnp.asarray(toks),
-                            seq_budget=self.max_context, plan=program)
-                        mstats = None
+                    logits, prefilled, mstats = self._prefill_jit(
+                        self.params, toks, plan=program,
+                        placement=self.placement
+                        if self._dep_active else None,
+                        cap_scale=cap_scale,
+                        collect_stats=self._track_load,
+                        seq_budget=self.max_context)
                 with self._phase("prefill_wait"):
                     jax.block_until_ready(prefilled)
             self._count_rows(
                 "prefill",
-                self.model.expert_rows(
-                    len(reqs), group.bucket, program,
-                    cap_scale if self._track_load else 1.0),
+                self.model.expert_rows(len(reqs), group.bucket, program,
+                                       cap_scale),
                 sum(lengths) * self.model.routed_per_token)
-            self.last_prefill_logits = (list(slots), logits[:, 0])
+            self.last_prefill_logits = (list(slots), logits)
             if mstats is not None:
                 self.load_tracker.observe(np.asarray(mstats.load))
                 self.stats.dropped_tokens += int(mstats.dropped)
@@ -943,6 +949,23 @@ class ServingEngine:
         return step_plan
 
     # ------------------------------------------------------------------
+    def _prefill(self, params, tokens, last_positions=None, plan=None,
+                 placement=None, cap_scale=1.0, collect_stats=False,
+                 seq_budget=None):
+        """The body of ``_prefill_jit``: ``model.prefill`` of ``tokens``
+        [rows, bucket] into fresh caches of ``seq_budget`` rows. Returns
+        (float32 logits [rows, V] at each row's ``last_positions``, or at
+        the bucket end; the caches; the stacked ``MoEStats``, or None
+        unless ``collect_stats``)."""
+        # Python runs this body only while JAX traces a new program
+        if self._c_prefill_traces is not None:
+            self._c_prefill_traces.inc()
+        out = self.model.prefill(
+            params, tokens, seq_budget=seq_budget, plan=plan,
+            last_positions=last_positions, placement=placement,
+            return_moe_stats=collect_stats, capacity_scale=cap_scale)
+        return out[0][:, 0], out[1], out[2] if collect_stats else None
+
     def _decode_step(self, params, tokens, caches, temps, top_ks, key,
                      lengths, block_tables=None, plan=None, use_topk=False,
                      placement=None, cap_scale=1.0, collect_stats=False):

@@ -51,6 +51,31 @@ def test_decode_matches_forward(arch):
     assert max(errs) < 1e-4, (arch, max(errs))
 
 
+@pytest.mark.parametrize("last", [False, True],
+                         ids=["bucket_end", "last_positions"])
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "qwen2-moe-a2.7b",
+                                  "internvl2-1b"])
+def test_prefill_head_matches_forward_rows(arch, last):
+    """``prefill`` applies the output head to the returned rows only; its
+    logits are the full-sequence head's [B, S, V] logits at those rows
+    (image slots included in the positions of a vlm)."""
+    cfg, model, params, tokens, extra = _setup(arch)
+    full, _, _ = model.forward(params, tokens, extra_embeds=extra)
+    off = extra.shape[1] if extra is not None else 0
+    pos = np.asarray([5, S - 3]) if last else None
+    lg, _ = model.prefill(params, tokens, extra_embeds=extra,
+                          last_positions=pos)
+    rows = (pos if last else np.full(B, S - 1)) + off
+    want = full[jnp.arange(B), rows]
+    assert lg.shape == (B, 1, full.shape[-1])
+    # the head multiplies B rows where forward multiplies B x S; the CPU
+    # may block the two products differently, so the float32 sums agree
+    # to rounding, not always bit for bit (equal on the text configs
+    # here, 2.5e-6 apart on the vlm); a wrong row is ~1 off
+    np.testing.assert_allclose(np.asarray(lg[:, 0]), np.asarray(want),
+                               rtol=0, atol=1e-5)
+
+
 def test_sliding_window_ring_cache_decode():
     cfg = dataclasses.replace(get_smoke_config("qwen2-1.5b"),
                               attention="sliding", sliding_window=8)

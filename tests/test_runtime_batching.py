@@ -90,6 +90,86 @@ def test_prefill_last_positions_gathers_per_row_logits():
 
 
 # ---------------------------------------------------------------------------
+# the jitted prefill: parity with the eager model, and its trace counter
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def moe_engine():
+    """A float32 engine over the smoke qwen2-moe config with expert-load
+    telemetry on, so its prefill also returns the MoE stats."""
+    return ServingEngine(get_smoke_config("qwen2-moe-a2.7b"), num_slots=3,
+                         max_context=64, dtype=jnp.float32,
+                         track_expert_load=True)
+
+
+@pytest.mark.parametrize("last", [False, True],
+                         ids=["bucket_end", "last_positions"])
+@pytest.mark.parametrize("rows", [1, 3])
+@pytest.mark.parametrize("bucket", [8, 16])
+def test_jitted_prefill_matches_eager_model(moe_engine, bucket, rows, last):
+    """The engine's one-program prefill returns the eager ``Model.prefill``
+    (every operation dispatched on its own, under ``jax.disable_jit``):
+    the same logits at the returned rows, the same caches, and the same
+    expert loads and dropped count."""
+    eng = moe_engine
+    rng = np.random.RandomState(bucket * 10 + rows)
+    toks = rng.randint(0, eng.cfg.vocab_size,
+                       size=(rows, bucket)).astype(np.int32)
+    pos = rng.randint(0, bucket, size=rows).astype(np.int32) if last \
+        else None
+    logits, caches, mstats = eng._prefill_jit(
+        eng.params, toks, pos, collect_stats=True, seq_budget=64)
+    with jax.disable_jit():
+        want, want_caches, want_stats = eng.model.prefill(
+            eng.params, jnp.asarray(toks), seq_budget=64,
+            last_positions=pos, return_moe_stats=True)
+    # one program lets XLA fuse and reorder the float32 sums that the
+    # eager path runs as separate operations: values agree to rounding
+    # (at most 3.4e-6 here, on the CPU), not bit for bit. 1e-5 is a few
+    # float32 ulps of these logits and caches (|x| < 4) and far below
+    # the error of a wrong row or position (~1).
+    np.testing.assert_allclose(np.asarray(logits),
+                               np.asarray(want[:, 0]), rtol=0, atol=1e-5)
+    got_leaves = jax.tree_util.tree_leaves(caches)
+    want_leaves = jax.tree_util.tree_leaves(want_caches)
+    assert len(got_leaves) == len(want_leaves)
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=0, atol=1e-5)
+    assert np.array_equal(np.asarray(mstats.load),
+                          np.asarray(want_stats.load))
+    assert int(mstats.dropped) == int(want_stats.dropped)
+
+
+def test_prefill_traces_counter_counts_programs():
+    """``repro_engine_prefill_traces_total`` rises by one for a prompt in
+    a new bucket, by nothing for another prompt in a bucket already
+    compiled, and ``reset_stats()`` clears it."""
+    cfg = get_smoke_config("qwen2-1.5b")
+    eng = ServingEngine(cfg, num_slots=2, max_context=256,
+                        dtype=jnp.float32)
+    traces = eng.metrics.counter("repro_engine_prefill_traces_total")
+    rng = np.random.RandomState(3)
+
+    def serve(n):
+        eng.submit(Request(prompt=list(rng.randint(0, cfg.vocab_size,
+                                                   size=n)),
+                           max_new_tokens=1))
+        eng.run()
+        return traces.value
+
+    assert traces.value == 0
+    assert serve(10) == 1                  # bucket 64: a new program
+    assert serve(40) == 1                  # bucket 64 again: none
+    assert serve(100) == 2                 # bucket 128: a new program
+    assert eng.metrics.snapshot()["repro_engine_prefill_traces_total"] == 2
+    eng.reset_stats()
+    assert traces.value == 0
+    assert serve(70) == 0                  # bucket 128, compiled before
+
+
+# ---------------------------------------------------------------------------
 # KV ledger accounting
 # ---------------------------------------------------------------------------
 
