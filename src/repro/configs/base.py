@@ -40,6 +40,25 @@ class MoEConfig:
     capacity_factor: float = 1.25    # per-expert capacity = cf * tokens*topk/E
     moe_layer_start: int = 0         # first layer index that is MoE
     moe_layer_every: int = 1         # 1 => every layer from start is MoE
+    #: rescale the top-k router weights to sum to one (False: the softmax
+    #: probabilities of the chosen experts, as DeepSeek-V2 publishes)
+    norm_topk_prob: bool = True
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN RoPE scaling as DeepSeek-V2's ``rope_scaling`` states it: the
+    frequencies blend interpolated (``factor``) and original ones over a
+    ramp set by ``beta_fast``/``beta_slow`` rotations within
+    ``original_max_position`` positions, and attention logits scale by
+    ``mscale(factor, mscale_all_dim)**2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -69,6 +88,7 @@ class ModelConfig:
     attention: str = "full"          # ATTENTION_KINDS
     sliding_window: int = 4096       # used when attention == "sliding"/"local"
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None
     qkv_bias: bool = False
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
@@ -79,9 +99,13 @@ class ModelConfig:
     num_encoder_layers: int = 0
     # --- multimodal stub frontends (vlm/audio carve-out) ---
     frontend_tokens: int = 0         # patch/frame embeddings prepended
-    # --- MLA (DeepSeek-V2 style latent attention) ---
+    # --- MLA (DeepSeek-V2 latent attention): head_dim is the per-head
+    # no-rope query/key width, mla_rope_dim the rope part (one rope key
+    # shared by every head), mla_v_dim the value width (0 -> head_dim) ---
     mla_kv_lora_rank: int = 0
     mla_q_lora_rank: int = 0
+    mla_rope_dim: int = 0
+    mla_v_dim: int = 0
     # citation for the exact config
     source: str = ""
 
@@ -90,6 +114,10 @@ class ModelConfig:
         assert self.attention in ATTENTION_KINDS, self.attention
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        # YaRN's logit scale is applied by the MLA attention only
+        assert self.rope_scaling is None or self.mla_kv_lora_rank
+        if self.mla_kv_lora_rank and self.mla_v_dim == 0:
+            object.__setattr__(self, "mla_v_dim", self.head_dim)
         assert self.num_heads % max(self.num_kv_heads, 1) == 0
 
     # ---- derived quantities -------------------------------------------------
@@ -114,8 +142,11 @@ class ModelConfig:
         kv = self.num_kv_heads * Hd
         attn = M * q + 2 * M * kv + q * M
         if self.mla_kv_lora_rank:
-            attn = M * self.mla_kv_lora_rank * 2 + self.mla_kv_lora_rank * (
-                2 * self.num_heads * Hd) + q * M
+            r, dr, dv = (self.mla_kv_lora_rank, self.mla_rope_dim,
+                         self.mla_v_dim)
+            H = self.num_heads
+            attn = (M * H * (Hd + dr) + M * (r + dr) + r
+                    + r * H * (Hd + dv) + H * dv * M)
         dense_ffn = 3 * M * self.ffn_dim if self.ffn_dim else 0
         per_layer = attn + dense_ffn
         n = self.num_layers * per_layer
@@ -169,6 +200,8 @@ class ModelConfig:
             frontend_tokens=min(self.frontend_tokens, 16),
             mla_kv_lora_rank=min(self.mla_kv_lora_rank, 64),
             mla_q_lora_rank=min(self.mla_q_lora_rank, 64),
+            mla_rope_dim=min(self.mla_rope_dim, 32),
+            mla_v_dim=0,
         )
         if self.moe is not None:
             kw["moe"] = dataclasses.replace(

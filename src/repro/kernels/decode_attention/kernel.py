@@ -101,8 +101,10 @@ def paged_kv_index_map(bs: int):
     return kv_map
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, bc: int,
-                   n_c_steps: int, scale: float, with_counts: bool):
+def _flash(len_ref, q_ref, load_kv, o_ref, rest, *, bc: int,
+           n_c_steps: int, scale: float, with_counts: bool):
+    """The flash loop every mode shares: ``load_kv()`` reads this grid
+    step's (K [bc, Dk], V [bc, Dv]) block."""
     if with_counts:
         cnt_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -123,9 +125,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, bc: int,
 
     @pl.when(start < length)
     def _compute():
-        q = q_ref[0, 0]                              # [g, D]
-        k = k_ref[0, 0]                              # [bc, D]
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]                              # [g, Dk]
+        k, v = load_kv()                             # [bc, Dk], [bc, Dv]
         pos = start + jax.lax.broadcasted_iota(jnp.int32, (1, bc), 1)
         valid = pos < length                         # [1, bc]
         logits = jax.lax.dot_general(
@@ -153,6 +154,11 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, bc: int,
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, **kw):
+    _flash(len_ref, q_ref, lambda: (k_ref[0, 0], v_ref[0, 0]), o_ref, rest,
+           **kw)
+
+
 def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
                          *rest, **kw):
     # the block table is consumed by the K/V index_maps (it decides WHICH
@@ -161,37 +167,51 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
     _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *rest, **kw)
 
 
+def _latent_decode_kernel(len_ref, q_ref, kv_ref, o_ref, *rest, dv: int,
+                          **kw):
+    # one streamed block serves as keys (all its columns) and values (the
+    # first ``dv``): the latent cache is read once
+    def load():
+        kv = kv_ref[0, 0]
+        return kv, kv[:, :dv]
+    _flash(len_ref, q_ref, load, o_ref, rest, **kw)
+
+
 def _decode_call(kernel, *, n_prefetch: int, B: int, Kv: int, g: int,
                  D: int, n_steps: int, blk: int, kv_map, out_dtype,
-                 interpret: Optional[bool], with_counts: bool):
-    """The pallas_call both modes share: only the number of prefetched
-    scalars, the K/V index_map and the block length differ."""
+                 interpret: Optional[bool], with_counts: bool,
+                 n_kv: int = 2, Dv: Optional[int] = None,
+                 scale: Optional[float] = None):
+    """The pallas_call every mode shares: the number of prefetched
+    scalars, the K/V index_map, the block length, and the number of
+    streamed caches (K and V, or one latent cache whose first ``Dv``
+    columns are the values) differ."""
+    Dv = D if Dv is None else Dv
+
     def head(b, kv, c, *_):
         return (b, kv, 0, 0)
 
-    out_specs = [pl.BlockSpec((1, 1, g, D), head)]
-    out_shape = [jax.ShapeDtypeStruct((B, Kv, g, D), out_dtype)]
+    out_specs = [pl.BlockSpec((1, 1, g, Dv), head)]
+    out_shape = [jax.ShapeDtypeStruct((B, Kv, g, Dv), out_dtype)]
     if with_counts:
         out_specs.append(pl.BlockSpec((1, 1, 1, 1), head))
         out_shape.append(jax.ShapeDtypeStruct((B, Kv, 1, 1), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=(B, Kv, n_steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, D), head),
-            pl.BlockSpec((1, 1, blk, D), kv_map),
-            pl.BlockSpec((1, 1, blk, D), kv_map),
-        ],
+        in_specs=[pl.BlockSpec((1, 1, g, D), head)]
+        + [pl.BlockSpec((1, 1, blk, D), kv_map)] * n_kv,
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, D), jnp.float32),
+            pltpu.VMEM((g, Dv), jnp.float32),
         ],
     )
-    kernel = functools.partial(kernel, bc=blk, n_c_steps=n_steps,
-                               scale=1.0 / math.sqrt(D),
-                               with_counts=with_counts)
+    kernel = functools.partial(
+        kernel, bc=blk, n_c_steps=n_steps,
+        scale=1.0 / math.sqrt(D) if scale is None else scale,
+        with_counts=with_counts)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret_mode(interpret),
@@ -257,4 +277,33 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, *, bc: int = 512,
     out = outs[0].reshape(B, H, D)
     if return_block_counts:
         return out, outs[1].reshape(B, Kv)
+    return out
+
+
+def latent_decode_attention_pallas(q, cache, lengths, *, dv: int,
+                                   scale: float, bc: int = 512,
+                                   interpret: Optional[bool] = None,
+                                   return_block_counts: bool = False):
+    """Latent mode (absorbed MLA decode): q: [B,H,Dk]; cache: [B,1,C,Dk],
+    one latent stream per row shared by all H query heads; lengths: int
+    [B] -> [B,H,dv].
+
+    Keys are the cache's Dk columns, values its first ``dv`` columns of
+    the same block, so each block is streamed once. ``scale`` multiplies
+    the logits (MLA's is not 1/sqrt(Dk)). Same flash loop, dense index
+    map and block skip as ``decode_attention_pallas``."""
+    B, H, D = q.shape
+    C = cache.shape[2]
+    bc = largest_block_size(C, bc, sublane_tile(cache.dtype))
+    lens = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, C)
+    outs = _decode_call(
+        functools.partial(_latent_decode_kernel, dv=dv), n_prefetch=1, B=B,
+        Kv=1, g=H, D=D, n_steps=C // bc, blk=bc,
+        kv_map=dense_kv_index_map(bc), out_dtype=q.dtype,
+        interpret=interpret, with_counts=return_block_counts, n_kv=1,
+        Dv=dv, scale=scale,
+    )(lens, q.reshape(B, 1, H, D), cache)
+    out = outs[0].reshape(B, H, dv)
+    if return_block_counts:
+        return out, outs[1].reshape(B)
     return out

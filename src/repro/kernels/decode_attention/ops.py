@@ -6,8 +6,10 @@ from functools import partial
 import jax
 
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    decode_attention_pallas, latent_decode_attention_pallas,
+    paged_decode_attention_pallas)
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
+                                                latent_decode_attention_ref,
                                                 paged_decode_attention_ref)
 
 
@@ -39,3 +41,16 @@ def decode_attention_paged(q, k_pages, v_pages, lengths, block_table,
                                           block_table)
     return paged_decode_attention_pallas(q, k_pages, v_pages, lengths,
                                          block_table)
+
+
+@partial(jax.jit, static_argnames=("dv", "scale", "bc", "use_kernel"))
+def decode_attention_latent(q, cache, lengths, *, dv: int, scale: float,
+                            bc: int = 512, use_kernel: bool = True):
+    """Absorbed-MLA decode attention: q [B,H,Dk]; cache [B,1,C,Dk] whose
+    first ``dv`` columns are the values; lengths int [B] -> [B,H,dv].
+    ``use_kernel=False`` is the explicit oracle path."""
+    if not use_kernel:
+        return latent_decode_attention_ref(q, cache, lengths, dv=dv,
+                                           scale=scale)
+    return latent_decode_attention_pallas(q, cache, lengths, dv=dv,
+                                          scale=scale, bc=bc)

@@ -32,6 +32,22 @@ def decode_attention_ref(q, k_cache, v_cache, lengths):
     return out.reshape(B, H, D).astype(q.dtype)
 
 
+def latent_decode_attention_ref(q, cache, lengths, *, dv: int,
+                               scale: float):
+    """Oracle for the latent mode: q: [B,H,Dk]; cache: [B,1,C,Dk] (keys
+    all Dk columns, values the first ``dv``); lengths: int [B] ->
+    [B,H,dv]. Length-0 rows return exact zeros."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    lat = cache[:, 0].astype(jnp.float32)                      # [B,C,Dk]
+    valid = jnp.arange(lat.shape[1])[None, :] < lengths[:, None]
+    logits = jnp.einsum("bhd,bcd->bhc", q.astype(jnp.float32), lat) * scale
+    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhc,bcd->bhd", p, lat[..., :dv])
+    out = jnp.where((lengths > 0)[:, None, None], out, 0.0)
+    return out.astype(q.dtype)
+
+
 def gather_pages(pages, block_table):
     """[P,Kv,bs,D] pages + int [B,n] table -> dense [B, Kv, n*bs, D].
     Unallocated entries (< 0) clamp to page 0; their positions sit past

@@ -55,7 +55,7 @@ def adapt_for_shape(cfg: ModelConfig, shape: ShapeConfig) -> ModelConfig:
             and cfg.uses_attention and not cfg.subquadratic):
         return dataclasses.replace(cfg, attention="sliding",
                                    sliding_window=LONG_CONTEXT_WINDOW,
-                                   mla_kv_lora_rank=0)
+                                   mla_kv_lora_rank=0, rope_scaling=None)
     return cfg
 
 

@@ -4,10 +4,21 @@ full or ring-buffer KV caches, and MLA (DeepSeek-V2 latent attention).
 All apply functions are pure; KV caches are explicit pytrees:
   full cache: {"k": [B,Kv,C,Dh], "v": [B,Kv,C,Dh], "index": i32[]}
   ring cache: same arrays with C == window; writes wrap at C.
-MLA cache:    {"ckv": [B,C,r], "kpe": [B,C,Dh], "index": i32[]}
-K/V caches are head-major so the decode kernel streams (positions, Dh)
+  MLA cache:  {"latent": [B,1,C,W], "index": i32[]}: per position the
+              normed compressed KV (r) and the roped key (dr) all heads
+              share, laid out as one KV head, zero-padded to W, a whole
+              number of 128-lane rows (``mla_cache_width``).
+Caches are head-major so the decode kernel streams (positions, width)
 blocks of one KV head, which tile on the TPU; ``SEQ_AXIS`` names each
 cache entry's position axis.
+
+MLA (arXiv:2405.04434, as DeepSeek-V2's modeling code computes it): per
+head q = [q_nope ‖ rope(q_pe)]; [c ‖ k_pe] = x·W_kv_a, c_kv = RMSNorm(c),
+k_pe roped once for all heads; [k_nope ‖ v] = c_kv·W_kv_b per head;
+scores (q_nope·k_nope + q_pe·k_pe)·mscale²/sqrt(dn + dr). The prefill
+expands K and V through W_kv_b for the prompt; the decode step absorbs
+W_kv_b into the query (q_nope·W_uk) and the output (·W_uv) and attends
+over the latent cache itself.
 """
 from __future__ import annotations
 
@@ -19,12 +30,41 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, dense_apply, dense_init
+from repro.models.layers import (apply_rope, dense_apply, dense_init,
+                                 rmsnorm_apply, rmsnorm_init, yarn_mscale)
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-#: position axis of each cache entry (K/V head-major, MLA latents not)
-SEQ_AXIS = {"k": 2, "v": 2, "ckv": 1, "kpe": 1}
+#: position axis of each cache entry
+SEQ_AXIS = {"k": 2, "v": 2, "latent": 2}
+
+
+def mla_dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
+    """(kv_lora_rank r, no-rope q/k width dn, rope width dr, value width
+    dv) of an MLA config."""
+    return (cfg.mla_kv_lora_rank, cfg.head_dim, cfg.mla_rope_dim,
+            cfg.mla_v_dim)
+
+
+def mla_cache_width(cfg: ModelConfig) -> int:
+    """Width W of a latent cache row: r + dr rounded up to 128 lanes
+    (576 -> 640). The TPU tiles a bf16 row-major cache as 128-lane rows
+    either way; an unpadded 576-wide cache gets a position-minor device
+    layout instead, which the decode kernel cannot read, and every decode
+    step then relays out each layer's whole cache in and out."""
+    r, _, dr, _ = mla_dims(cfg)
+    return -(-(r + dr) // 128) * 128
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """MLA's softmax scale: 1/sqrt(dn + dr), times YaRN's
+    mscale(factor, mscale_all_dim)**2 where rope scaling states one."""
+    _, dn, dr, _ = mla_dims(cfg)
+    scale = 1.0 / math.sqrt(dn + dr)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 # ---------------------------------------------------------------------------
@@ -34,15 +74,15 @@ SEQ_AXIS = {"k": 2, "v": 2, "ckv": 1, "kpe": 1}
 def attention_init(key, cfg: ModelConfig):
     hd = cfg.head_dim
     if cfg.mla_kv_lora_rank:
-        kq, kkv, kup, kpe, ko = jax.random.split(key, 5)
-        r = cfg.mla_kv_lora_rank
+        kq, ka, kb, ko = jax.random.split(key, 4)
+        r, dn, dr, dv = mla_dims(cfg)
+        H = cfg.num_heads
         return {
-            "wq": dense_init(kq, cfg.d_model, cfg.num_heads * hd,
-                             bias=cfg.qkv_bias),
-            "w_dkv": dense_init(kkv, cfg.d_model, r),
-            "w_ukv": dense_init(kup, r, cfg.num_heads * 2 * hd),
-            "w_kpe": dense_init(kpe, cfg.d_model, hd),
-            "wo": dense_init(ko, cfg.num_heads * hd, cfg.d_model),
+            "wq": dense_init(kq, cfg.d_model, H * (dn + dr)),
+            "w_kv_a": dense_init(ka, cfg.d_model, r + dr),
+            "kv_norm": rmsnorm_init(r),
+            "w_kv_b": dense_init(kb, r, H * (dn + dv)),
+            "wo": dense_init(ko, H * dv, cfg.d_model),
         }
     kq, kk, kv, ko = jax.random.split(key, 4)
     return {
@@ -62,8 +102,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, capacity: int,
     hd = cfg.head_dim
     if cfg.mla_kv_lora_rank:
         return {
-            "ckv": jnp.zeros((batch, capacity, cfg.mla_kv_lora_rank), dtype),
-            "kpe": jnp.zeros((batch, capacity, hd), dtype),
+            "latent": jnp.zeros((batch, 1, capacity, mla_cache_width(cfg)),
+                                dtype),
             "index": jnp.zeros((), jnp.int32),
         }
     return {
@@ -93,23 +133,25 @@ def _causal_mask(q_pos, k_pos, window: Optional[int]):
 
 
 def _flash_sdpa_xla(q, k, v, q_pos, k_pos, window: Optional[int],
-                    q_chunk: int = 512, k_chunk: int = 1024):
+                    q_chunk: int = 512, k_chunk: int = 1024,
+                    scale: Optional[float] = None):
     """Flash-attention structured as pure XLA: outer scan over query chunks,
     inner scan over key chunks with online softmax. Never materializes more
     than [B, Kv, g, q_chunk, k_chunk] of logits. Used for long sequences
     where the [S, S] score matrix of `_sdpa` would not fit.
 
-    q: [B,S,H,D]; k/v: [B,S,Kv,D]; q_pos/k_pos: [S] absolute positions.
+    q/k: [B,S,H|Kv,D]; v: [B,S,Kv,Dv]; q_pos/k_pos: [S] absolute
+    positions; ``scale`` defaults to 1/sqrt(D).
     """
     B, S, H, D = q.shape
-    Kv = k.shape[2]
+    Kv, Dv = k.shape[2], v.shape[3]
     g = H // Kv
     q_chunk = min(q_chunk, S)
     k_chunk = min(k_chunk, S)
     nq = (S + q_chunk - 1) // q_chunk
     nk = (S + k_chunk - 1) // k_chunk
     Sp_q, Sp_k = nq * q_chunk, nk * k_chunk
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     def pad_seq(x, Sp):
         return jnp.pad(x, ((0, 0), (0, Sp - x.shape[1])) + ((0, 0),) *
@@ -117,7 +159,7 @@ def _flash_sdpa_xla(q, k, v, q_pos, k_pos, window: Optional[int],
 
     qp = pad_seq(q, Sp_q).reshape(B, nq, q_chunk, Kv, g, D)
     kp = pad_seq(k, Sp_k).reshape(B, nk, k_chunk, Kv, D)
-    vp = pad_seq(v, Sp_k).reshape(B, nk, k_chunk, Kv, D)
+    vp = pad_seq(v, Sp_k).reshape(B, nk, k_chunk, Kv, Dv)
     qpos = jnp.pad(q_pos, (0, Sp_q - S), constant_values=-1)
     qpos = qpos.reshape(nq, q_chunk)
     kpos = jnp.pad(k_pos, (0, Sp_k - S), constant_values=2**30)
@@ -146,7 +188,7 @@ def _flash_sdpa_xla(q, k, v, q_pos, k_pos, window: Optional[int],
 
         m0 = jnp.full((B, Kv, g, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Kv, g, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, Kv, g, q_chunk, D), jnp.float32)
+        a0 = jnp.zeros((B, Kv, g, q_chunk, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             inner, (m0, l0, a0),
             (kp.swapaxes(0, 1), vp.swapaxes(0, 1), kpos))
@@ -155,20 +197,21 @@ def _flash_sdpa_xla(q, k, v, q_pos, k_pos, window: Optional[int],
 
     _, outs = jax.lax.scan(outer, None,
                            (qp.swapaxes(0, 1), qpos))      # [nq,B,Kv,g,c,D]
-    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sp_q, H, D)
+    out = outs.transpose(1, 0, 4, 2, 3, 5).reshape(B, Sp_q, H, Dv)
     return out[:, :S]
 
 
-def _sdpa(q, k, v, mask):
-    """q: [B,Sq,H,Dh], k/v: [B,Sk,Kv,Dh] (GQA broadcast), mask [Sq,Sk] or
-    [B,Sq,Sk]."""
+def _sdpa(q, k, v, mask, scale: Optional[float] = None):
+    """q: [B,Sq,H,Dh], k: [B,Sk,Kv,Dh], v: [B,Sk,Kv,Dv] (GQA broadcast),
+    mask [Sq,Sk] or [B,Sq,Sk]; ``scale`` defaults to 1/sqrt(Dh)."""
     B, Sq, H, Dh = q.shape
     Kv = k.shape[2]
     groups = H // Kv
     q = q.reshape(B, Sq, Kv, groups, Dh)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q, k,
                         preferred_element_type=jnp.float32)
-    logits = logits / math.sqrt(Dh)
+    logits = (logits / math.sqrt(Dh) if scale is None
+              else logits * scale)
     if mask.ndim == 2:
         mask = mask[None, None, None]
     else:
@@ -176,27 +219,52 @@ def _sdpa(q, k, v, mask):
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(B, Sq, H, Dh)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 # ---------------------------------------------------------------------------
 # full-sequence (train / prefill) attention
 # ---------------------------------------------------------------------------
 
+def _mla_project(params, cfg: ModelConfig, x, positions):
+    """x: [B,S,M] -> (q_nope [B,S,H,dn], roped q_pe [B,S,H,dr], latent
+    [B,S,W]: RMSNorm(c_kv) ‖ the roped shared key ‖ zeros, a cache row)."""
+    B, S, _ = x.shape
+    r, dn, dr, _ = mla_dims(cfg)
+    q = dense_apply(params["wq"], x).reshape(B, S, cfg.num_heads, dn + dr)
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta,
+                      cfg.rope_scaling)
+    kv_a = dense_apply(params["w_kv_a"], x)                     # [B,S,r+dr]
+    c_kv = rmsnorm_apply(params["kv_norm"], kv_a[..., :r], cfg.norm_eps)
+    k_pe = apply_rope(kv_a[..., None, r:], positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0]
+    pad = jnp.zeros((B, S, mla_cache_width(cfg) - r - dr), c_kv.dtype)
+    return q[..., :dn], q_pe, jnp.concatenate([c_kv, k_pe, pad], -1)
+
+
+def _mla_expand(params, cfg: ModelConfig, q_nope, q_pe, latent):
+    """The prefill's K and V for the prompt's own positions: [k_nope ‖
+    k_pe] [B,S,H,dn+dr] and v [B,S,H,dv] from c_kv·W_kv_b, with the query
+    [q_nope ‖ q_pe]."""
+    B, S, H, _ = q_nope.shape
+    r, dn, dr, dv = mla_dims(cfg)
+    with jax.named_scope("kv_up"):
+        kv = dense_apply(params["w_kv_b"], latent[..., :r])
+        kv = kv.reshape(B, S, H, dn + dv)
+    k_pe = jnp.broadcast_to(latent[:, :, None, r:r + dr], (B, S, H, dr))
+    k = jnp.concatenate([kv[..., :dn], k_pe], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    return q, k, kv[..., dn:]
+
+
 def _project_qkv(params, cfg: ModelConfig, x, positions):
     B, S, _ = x.shape
     hd = cfg.head_dim
-    q = dense_apply(params["wq"], x).reshape(B, S, cfg.num_heads, hd)
     if cfg.mla_kv_lora_rank:
-        ckv = dense_apply(params["w_dkv"], x)                    # [B,S,r]
-        kv = dense_apply(params["w_ukv"], ckv)
-        kv = kv.reshape(B, S, cfg.num_heads, 2 * hd)
-        k_nope, v = jnp.split(kv, 2, axis=-1)
-        kpe = dense_apply(params["w_kpe"], x)[:, :, None, :]     # [B,S,1,hd]
-        kpe = apply_rope(kpe, positions, cfg.rope_theta)
-        k = k_nope + kpe                                         # MHA (Kv == H)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        return q, k, v, {"ckv": ckv, "kpe": kpe[:, :, 0, :]}
+        q_nope, q_pe, latent = _mla_project(params, cfg, x, positions)
+        q, k, v = _mla_expand(params, cfg, q_nope, q_pe, latent)
+        return q, k, v, {"latent": latent[:, None]}
+    q = dense_apply(params["wq"], x).reshape(B, S, cfg.num_heads, hd)
     k = dense_apply(params["wk"], x).reshape(B, S, cfg.num_kv_heads, hd)
     v = dense_apply(params["wv"], x).reshape(B, S, cfg.num_kv_heads, hd)
     q = apply_rope(q, positions, cfg.rope_theta)
@@ -214,16 +282,18 @@ def attention_fullseq(params, cfg: ModelConfig, x, positions,
     B, S, _ = x.shape
     window = cfg.sliding_window if cfg.attention in ("sliding", "local") else None
     q, k, v, to_cache = _project_qkv(params, cfg, x, positions)
+    scale = mla_scale(cfg) if cfg.mla_kv_lora_rank else None
 
     if impl == "flash" and cfg.mla_kv_lora_rank == 0:
         from repro.kernels.flash_attention import ops as flash_ops
         out = flash_ops.flash_attention(q, k, v, causal=True, window=window)
     elif impl == "chunked" or (impl in ("xla", "decode_kernel")
                                and S > 2048):
-        out = _flash_sdpa_xla(q, k, v, positions[0], positions[0], window)
+        out = _flash_sdpa_xla(q, k, v, positions[0], positions[0], window,
+                              scale=scale)
     else:
         mask = _causal_mask(positions[0], positions[0], window)
-        out = _sdpa(q, k, v, mask)
+        out = _sdpa(q, k, v, mask, scale)
 
     out = dense_apply(params["wo"], out.reshape(B, S, -1))
     new_cache = None
@@ -233,7 +303,7 @@ def attention_fullseq(params, cfg: ModelConfig, x, positions,
 
 
 def _cache_len(cfg: ModelConfig, cache) -> int:
-    name = "ckv" if cfg.mla_kv_lora_rank else "k"
+    name = "latent" if cfg.mla_kv_lora_rank else "k"
     return cache[name].shape[SEQ_AXIS[name]]
 
 
@@ -379,19 +449,22 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
         return _attention_decode_paged(params, cfg, x, cache, impl, ctx,
                                        lengths, block_table)
     B = x.shape[0]
-    hd = cfg.head_dim
     index = jnp.asarray(cache["index"])
     positions = (jnp.full((B, 1), index, jnp.int32) if index.ndim == 0
                  else index[:, None].astype(jnp.int32))
-    q, k, v, to_cache = _project_qkv(params, cfg, x, positions)
+    if cfg.mla_kv_lora_rank:
+        q_nope, q_pe, latent = _mla_project(params, cfg, x, positions)
+        to_cache = {"latent": latent[:, None]}
+    else:
+        q, k, v, to_cache = _project_qkv(params, cfg, x, positions)
 
     C = _cache_len(cfg, cache)
     is_ring = cfg.attention in ("sliding", "local")
-    if impl == "decode_kernel" and (cfg.mla_kv_lora_rank or is_ring):
+    if impl == "decode_kernel" and is_ring:
         raise ValueError(
-            "the decode kernel takes full-attention GQA caches "
-            f"(attention={cfg.attention!r}, mla={cfg.mla_kv_lora_rank}); "
-            "pass attn_impl='xla' for this model")
+            "the decode kernel takes full-attention caches "
+            f"(attention={cfg.attention!r}); pass attn_impl='xla' for "
+            "this model")
 
     mesh = getattr(ctx, "mesh", None)
     axis = getattr(ctx, "expert_axis", "model")
@@ -455,7 +528,11 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
             lens = jnp.minimum(index.astype(jnp.int32) + 1, C)
         mask = slots[None, None, :] < lens[:, None, None]      # [B, 1, C]
 
-    if impl == "decode_kernel":
+    if cfg.mla_kv_lora_rank:
+        out = _mla_decode_core(params, cfg, q_nope[:, 0], q_pe[:, 0],
+                               new_cache["latent"].astype(x.dtype), lens,
+                               impl, getattr(ctx, "decode_bc", None))
+    elif impl == "decode_kernel":
         # the serving path: ragged Pallas kernel streams ceil(len/bc)
         # blocks per row instead of the dense [B, C] cache
         from repro.kernels.decode_attention import ops as dec_ops
@@ -466,18 +543,34 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
             new_cache["v"].astype(x.dtype), lens)
         out = out[:, None]
     else:
-        if cfg.mla_kv_lora_rank:
-            ckv_all, kpe_all = new_cache["ckv"], new_cache["kpe"]
-            kv = dense_apply(params["w_ukv"], ckv_all.astype(x.dtype))
-            kv = kv.reshape(B, C, cfg.num_heads, 2 * hd)
-            k_all, v_all = jnp.split(kv, 2, axis=-1)
-            k_all = k_all + kpe_all.astype(x.dtype)[:, :, None, :]
-        else:
-            k_all = new_cache["k"].astype(x.dtype).swapaxes(1, 2)
-            v_all = new_cache["v"].astype(x.dtype).swapaxes(1, 2)
+        k_all = new_cache["k"].astype(x.dtype).swapaxes(1, 2)
+        v_all = new_cache["v"].astype(x.dtype).swapaxes(1, 2)
         out = _sdpa(q, k_all, v_all, mask)
     out = dense_apply(params["wo"], out.reshape(B, 1, -1))
     return out, new_cache
+
+
+def _mla_decode_core(params, cfg: ModelConfig, q_nope, q_pe, latent, lens,
+                     impl: str, bc: Optional[int]):
+    """Absorbed MLA decode: q_nope [B,H,dn], q_pe [B,H,dr], latent cache
+    [B,1,C,W], lens [B] -> [B,H,dv]. The query takes W_uk (per head,
+    dn -> r), attention runs over the latent cache (keys all W columns,
+    the query zero past r + dr; values the first r), and the output takes
+    W_uv (r -> dv): W_kv_b never multiplies the cache."""
+    from repro.kernels.decode_attention import ops as dec_ops
+    r, dn, dr, dv = mla_dims(cfg)
+    w_kv_b = params["w_kv_b"]["kernel"].astype(q_nope.dtype)
+    w_kv_b = w_kv_b.reshape(r, cfg.num_heads, dn + dv)
+    with jax.named_scope("q_absorb"):
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_kv_b[..., :dn])
+    pad = jnp.zeros(q_pe.shape[:-1] + (latent.shape[-1] - r - dr,),
+                    q_pe.dtype)
+    q = jnp.concatenate([q_lat, q_pe, pad], -1)                # [B,H,W]
+    o_lat = dec_ops.decode_attention_latent(
+        q, latent, lens, dv=r, scale=mla_scale(cfg), bc=bc or 512,
+        use_kernel=impl == "decode_kernel")
+    with jax.named_scope("v_up"):
+        return jnp.einsum("bhr,rhd->bhd", o_lat, w_kv_b[..., dn:])
 
 
 def _attention_decode_paged(params, cfg: ModelConfig, x, cache, impl, ctx,

@@ -88,18 +88,53 @@ def layernorm_apply(p, x, eps: float = 1e-5):
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float = 10000.0):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction for a context stretched ``factor``
+    times (DeepSeek-V2's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_ramp(half: int, head_dim: int, theta: float, scaling):
+    """Per-frequency share of the interpolated frequency: 0 below the
+    dimension that turns ``beta_fast`` times in the original context, 1
+    above the one that turns ``beta_slow`` times, linear between."""
+    def dim_at(rotations):
+        return (head_dim * math.log(scaling.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(dim_at(scaling.beta_fast)), 0)
+    high = min(math.ceil(dim_at(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = (jnp.arange(half, dtype=jnp.float32) - low) / (high - low)
+    return jnp.clip(ramp, 0.0, 1.0)
+
+
+def rope_frequencies(head_dim: int, theta: float = 10000.0, scaling=None):
+    """Inverse frequencies [hd/2]; with a ``YarnScaling``, YaRN's blend of
+    the original and the ``factor``-interpolated frequencies."""
     exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponents)                      # [hd/2]
+    freqs = 1.0 / (theta ** exponents)                      # [hd/2]
+    if scaling is None:
+        return freqs
+    ramp = _yarn_ramp(head_dim // 2, head_dim, theta, scaling)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: [..., seq, heads, head_dim]; positions: [..., seq]."""
+def apply_rope(x, positions, theta: float = 10000.0, scaling=None):
+    """x: [..., seq, heads, head_dim]; positions: [..., seq]. Rotate-half
+    pairs; ``scaling`` (``YarnScaling``) takes YaRN's frequencies and
+    scales cos and sin by mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                    # [hd/2]
+    freqs = rope_frequencies(hd, theta, scaling)           # [hd/2]
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [...,S,hd/2]
     angles = angles[..., None, :]                          # [...,S,1,hd/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -51,7 +51,9 @@ def moe_init(key, d_model: int, mcfg: MoEConfig, num_experts_padded: int = 0):
 # ---------------------------------------------------------------------------
 
 class Routing(NamedTuple):
-    weights: jax.Array       # [T, k]  combine weights (post-softmax, renorm)
+    weights: jax.Array       # [T, k]  combine weights (top-k softmax
+    #                                    probs, renormalized unless
+    #                                    norm_topk_prob is False)
     experts: jax.Array       # [T, k]  int32 expert ids
     probs: jax.Array         # [T, E]  full softmax (for aux loss)
 
@@ -67,8 +69,9 @@ def route_topk(router_params, x_flat, mcfg: MoEConfig,
             logits = logits.at[..., mcfg.num_experts:].set(neg)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, mcfg.top_k)
-        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True),
-                                        1e-9)
+        if mcfg.norm_topk_prob:
+            weights = weights / jnp.maximum(weights.sum(-1, keepdims=True),
+                                            1e-9)
     return Routing(weights=weights, experts=experts.astype(jnp.int32),
                    probs=probs)
 

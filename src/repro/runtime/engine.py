@@ -83,19 +83,24 @@ def check_decode_kernel(cfg: ModelConfig, *, mesh=None,
                         dtype=jnp.float32) -> None:
     """Raise ``ValueError`` unless the ragged decode kernel can serve every
     attention layer of ``cfg`` at these cache shapes: full-attention GQA
-    caches (no MLA latents, no ring windows), KV heads that the mesh's
-    expert axis divides (each device runs the kernel on its own heads),
-    and, for the dense layout, a context length the kernel can tile."""
+    caches or MLA latent caches (no ring windows), KV heads that the
+    mesh's expert axis divides (each device runs the kernel on its own
+    heads; a latent cache is one KV head, so MLA takes one device), and,
+    for the dense layout, a context length the kernel can tile."""
     from repro.kernels.decode_attention.kernel import (largest_block_size,
                                                        sublane_tile)
     if not cfg.uses_attention:
         return                  # no layer decodes through a KV cache
-    if cfg.mla_kv_lora_rank or cfg.attention in ("sliding", "local"):
+    if cfg.attention in ("sliding", "local"):
         raise ValueError(
-            "the decode kernel takes full-attention GQA caches; "
-            f"{cfg.name} has attention={cfg.attention!r}, "
-            f"mla={cfg.mla_kv_lora_rank}: pass attn_impl='xla'")
+            "the decode kernel takes full-attention caches; "
+            f"{cfg.name} has attention={cfg.attention!r}: pass "
+            "attn_impl='xla'")
     mo = mesh.shape.get("model", 1) if mesh is not None else 1
+    if cfg.mla_kv_lora_rank and mesh is not None and mesh.size > 1:
+        raise ValueError(
+            f"{cfg.name}: the latent cache is one KV head and the decode "
+            "kernel does not split it over a mesh: pass attn_impl='xla'")
     if cfg.num_kv_heads % mo:
         raise ValueError(
             f"{cfg.num_kv_heads} KV heads do not divide the mesh's "
@@ -398,6 +403,7 @@ class ServingEngine:
         self._h_decode = self._h_prefill = None
         self._c_rows: Dict[Tuple[str, str], Counter] = {}
         self._c_prefill_traces: Optional[Counter] = None
+        self._c_kv_tokens: Optional[Counter] = None
         if self.metrics is not None:
             self._register_metrics(self.metrics)
 
@@ -443,6 +449,13 @@ class ServingEngine:
         self._c_prefill_traces = m.counter(
             "repro_engine_prefill_traces_total",
             "prefill programs traced: distinct token shapes and static keys")
+        self._c_kv_tokens = m.counter(
+            "repro_engine_decode_kv_tokens_total",
+            "context positions attended, summed over the decode steps' "
+            "live rows")
+        m.gauge("repro_engine_kv_cache_bytes",
+                "bytes the engine's KV caches hold on the device (0 until "
+                "they are made)", fn=self._kv_cache_bytes)
         m.register_source("repro_engine", self._engine_snapshot)
         m.register_reset(self.stats.reset)
         if self.plan_cache is not None:
@@ -469,6 +482,11 @@ class ServingEngine:
                 "waiting": float(len(self.waiting)),
                 "live_slots": float(sum(r is not None
                                         for r in self.slots))}
+
+    def _kv_cache_bytes(self) -> float:
+        caches = self.kv.caches
+        return float(sum(a.nbytes for a in jax.tree.leaves(caches))
+                     if caches is not None else 0)
 
     def _drift_snapshot(self) -> Dict[str, float]:
         st = self.drift.stats
@@ -1091,7 +1109,8 @@ class ServingEngine:
             # the ledger's per-slot context lengths drive the attention
             # mask AND the ragged kernel's block skip (dead slots decode
             # as len 0)
-            lengths = jnp.asarray(self.kv.lengths(), jnp.int32)
+            host_lengths = self.kv.lengths()
+            lengths = jnp.asarray(host_lengths, jnp.int32)
             tables = self.kv.table_array() if self._paged else None
         t0 = time.perf_counter()
         with self._phase("decode_step", step=self.stats.steps,
@@ -1113,6 +1132,8 @@ class ServingEngine:
             "decode",
             self.model.expert_rows(self.num_slots, 1, program, cap_scale),
             len(live) * self.model.routed_per_token)
+        if self._c_kv_tokens is not None:
+            self._c_kv_tokens.inc(sum(host_lengths[i] for i in live))
         with self._phase("emit"):
             self._emit(live, nxt, new_caches, mstats, plan_key, plan,
                        measured)

@@ -4,7 +4,7 @@ Rules are name-based over the parameter pytree paths produced by
 ``repro.models``:
 
   dense / moe / vlm / audio (attention stacks):
-    column-parallel (shard output dim over "model"): wq wk wv w_ukv gate up
+    column-parallel (shard output dim over "model"): wq wk wv w_kv_b gate up
         ffn_up w_gates w_if skip lm_head proj
     row-parallel   (shard input dim over "model"):  wo down out ffn_down
     expert-parallel (shard expert dim):             experts.{gate,up,down}
@@ -27,11 +27,11 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
-COL = ("wq", "wk", "wv", "w_ukv", "gate", "up", "ffn_up", "w_gates",
+COL = ("wq", "wk", "wv", "w_kv_b", "gate", "up", "ffn_up", "w_gates",
        "w_if", "skip", "lm_head", "proj", "in_x", "in_gate", "w_a", "w_i")
 ROW = ("wo", "down", "out", "ffn_down")
 # "shared" experts replicate: in DEP they belong to the (data-parallel) AG
-REPL = ("w_dkv", "w_kpe", "router", "r_gates", "conv", "shared")
+REPL = ("w_kv_a", "router", "r_gates", "conv", "shared")
 
 
 def _path_names(path) -> Tuple[str, ...]:
@@ -210,8 +210,6 @@ def cache_pspecs(cache, cfg: ModelConfig, mesh: Mesh, global_batch: int,
                                 # shard_map distributed-flash decode core
                 return P(*lead, b_axes, None, model_axis, None)
             return P(*lead, b_axes, None, None, None)
-        if any(n in ("ckv", "kpe") for n in names) and nd == 3:
-            return P(*lead, b_axes, None, None)
         if "h" in names and nd == 2 and cfg.family == "hybrid":
             return P(*lead, b_axes, model_axis)
         # ssm states / conv states: batch-sharded only

@@ -121,7 +121,6 @@ def test_sampler_greedy_vs_temperature():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("arch,kw", [
-    ("deepseek-v2-lite", {}),                  # MLA latent cache
     ("recurrentgemma-9b", {}),                 # local-attention ring cache
     ("qwen2-1.5b", {"max_context": 1021}),     # prime C > bc: cannot tile
 ])
@@ -136,6 +135,41 @@ def test_decode_kernel_refuses_at_construction(arch, kw):
     # the dense path stays available when asked for by name
     ServingEngine(cfg, num_slots=2, attn_impl="xla", dtype=jnp.float32,
                   **kw).close()
+
+
+def test_decode_kernel_serves_mla():
+    """An MLA model builds with attn_impl="decode_kernel" (the latent
+    cache is one KV head the kernel streams) and serves every request,
+    token for token as the dense path does; the KV counters read the
+    latent cache's bytes and the positions its decode steps attended."""
+    cfg = get_smoke_config("deepseek-v2-lite")
+    outs = []
+    for impl in ("decode_kernel", "xla"):
+        eng = ServingEngine(cfg, num_slots=2, max_context=64,
+                            attn_impl=impl, dtype=jnp.float32, seed=4)
+        reqs = [Request(prompt=[3, 1, 4, 1, 5], max_new_tokens=4),
+                Request(prompt=[2, 7], max_new_tokens=3)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run()
+        assert all(r.state == RequestState.FINISHED for r in reqs)
+        outs.append([r.output for r in reqs])
+        snap = eng.metrics.snapshot()
+        # 2 slots x 64 positions x r + dr float32 values a layer, padded
+        # to whole 128-lane rows, and the per-slot index
+        width = -(-(cfg.mla_kv_lora_rank + cfg.mla_rope_dim) // 128) * 128
+        assert snap["repro_engine_kv_cache_bytes"] == cfg.num_layers * (
+            2 * 64 * width * 4 + 2 * 4)
+        # decode steps attend 5..8 and 2..4 positions (prompt length,
+        # then one more a step)
+        assert snap["repro_engine_decode_kv_tokens_total"] == \
+            sum(range(5, 9)) + sum(range(2, 5))
+        eng.reset_stats()
+        snap = eng.metrics.snapshot()
+        assert snap["repro_engine_decode_kv_tokens_total"] == 0
+        assert snap["repro_engine_kv_cache_bytes"] > 0
+        eng.close()
+    assert outs[0] == outs[1]
 
 
 def test_engine_weights_in_serving_dtype():

@@ -35,6 +35,27 @@ def test_routing_topk_properties():
     assert bool(jnp.all(r.experts[:, 0] != r.experts[:, 1]))
 
 
+def test_routing_without_renormalization():
+    """norm_topk_prob=False (DeepSeek-V2) combines the chosen experts with
+    their softmax probabilities as they are; the default renormalizes the
+    same experts' weights to sum to one."""
+    mcfg, params = mk(E=8, top_k=3)
+    raw = dataclasses.replace(mcfg, norm_topk_prob=False)
+    x = jax.random.normal(KEY, (16, 32), jnp.float32)
+    r = moe_lib.route_topk(params["router"], x, raw)
+    n = moe_lib.route_topk(params["router"], x, mcfg)
+    np.testing.assert_array_equal(np.asarray(r.experts),
+                                  np.asarray(n.experts))
+    np.testing.assert_allclose(
+        np.asarray(r.weights),
+        np.take_along_axis(np.asarray(r.probs), np.asarray(r.experts), -1),
+        rtol=1e-6)
+    assert float(r.weights.sum(-1).max()) < 1.0
+    np.testing.assert_allclose(
+        np.asarray(n.weights),
+        np.asarray(r.weights / r.weights.sum(-1, keepdims=True)), rtol=1e-5)
+
+
 def test_padded_experts_receive_no_tokens():
     mcfg, _ = mk(E=6)
     params = moe_lib.moe_init(KEY, 32, mcfg, num_experts_padded=8)
