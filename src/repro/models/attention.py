@@ -494,16 +494,11 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
                 cache[name], val.astype(cache[name].dtype), slot,
                 axis=SEQ_AXIS[name])
     else:
-        # per-sample indices (continuous batching): scatter one row each
-        batch_ix = jnp.arange(B)
-        for name, val in to_cache.items():
-            dt = cache[name].dtype
-            if SEQ_AXIS[name] == 2:
-                new_cache[name] = cache[name].at[batch_ix, :, slot].set(
-                    val[:, :, 0].astype(dt))
-            else:
-                new_cache[name] = cache[name].at[batch_ix, slot].set(
-                    val[:, 0].astype(dt))
+        # per-sample indices (continuous batching): one row each
+        with jax.named_scope("kv_write"):
+            for name, val in to_cache.items():
+                new_cache[name] = _write_rows(cache[name], val, slot,
+                                              SEQ_AXIS[name])
     new_cache["index"] = index + 1
 
     # per-row attended prefix (non-ring): the ledger's context length when
@@ -548,6 +543,20 @@ def attention_decode(params, cfg: ModelConfig, x, cache, impl: str = "xla",
         out = _sdpa(q, k_all, v_all, mask)
     out = dense_apply(params["wo"], out.reshape(B, 1, -1))
     return out, new_cache
+
+
+def _write_rows(arr, val, pos, axis: int):
+    """Row b of ``val`` (one position along ``axis``) written at position
+    ``pos[b]`` of row b of ``arr``, by one ``dynamic_update_slice`` a row.
+    These keep the cache in its own layout, so a donated cache is updated
+    in place; a scatter over (row, position) pairs runs in a heads-minor
+    layout, and XLA copies the whole cache into it and back out."""
+    val = val.astype(arr.dtype)
+    for b in range(arr.shape[0]):
+        start = [0] * arr.ndim
+        start[0], start[axis] = b, pos[b]
+        arr = jax.lax.dynamic_update_slice(arr, val[b:b + 1], start)
+    return arr
 
 
 def _mla_decode_core(params, cfg: ModelConfig, q_nope, q_pe, latent, lens,

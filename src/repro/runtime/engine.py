@@ -386,11 +386,14 @@ class ServingEngine:
         # only the executor-visible task graph (keyed by r2/order/m_e) is
         # a static argument: plans differing in modeled throughput share
         # one compiled program, so retraces are bounded by distinct
-        # executable schedules
+        # executable schedules. The KV caches are donated: each step
+        # writes its new rows into the cache buffers in place, where an
+        # undonated cache costs a whole-cache copy a layer
         self._decode_jit = jax.jit(
             self._decode_step,
             static_argnames=("plan", "use_topk", "placement",
-                             "cap_scale", "collect_stats"))
+                             "cap_scale", "collect_stats"),
+            donate_argnames=("caches",))
         # the prefill likewise: one program per [rows, bucket] token shape
         # and static key, with the weights a traced argument (never baked
         # into the program as constants)
@@ -1125,6 +1128,9 @@ class ServingEngine:
                         if self._dep_active else None,
                         cap_scale=cap_scale,
                         collect_stats=self._track_load)
+                # the step consumed the donated caches: install its own
+                # at once, so nothing holds the deleted buffers
+                self.kv.caches = new_caches
             with self._phase("decode_wait"):
                 jax.block_until_ready(nxt)
         measured = time.perf_counter() - t0
@@ -1135,16 +1141,15 @@ class ServingEngine:
         if self._c_kv_tokens is not None:
             self._c_kv_tokens.inc(sum(host_lengths[i] for i in live))
         with self._phase("emit"):
-            self._emit(live, nxt, new_caches, mstats, plan_key, plan,
-                       measured)
+            self._emit(live, nxt, mstats, plan_key, plan, measured)
         self.stats.steps += 1
         return True
 
-    def _emit(self, live: List[int], nxt, new_caches, mstats, plan_key,
+    def _emit(self, live: List[int], nxt, mstats, plan_key,
               plan: Optional[Plan], measured: float) -> None:
-        """After a decode step: observe its time, install its caches and
-        tokens, read the tokens back and hand each live request its own,
-        finishing those that are done."""
+        """After a decode step: observe its time, install its tokens, read
+        them back and hand each live request its own, finishing those that
+        are done."""
         # measured decode wall-time vs the plan's modeled makespan: this is
         # the observe edge of the profiling loop — a sustained residual
         # breach re-solves THIS occupancy's plan on the refresh worker, so
@@ -1157,7 +1162,6 @@ class ServingEngine:
             self.load_tracker.observe(np.asarray(mstats.load))
             self.stats.dropped_tokens += int(mstats.dropped)
             self._maybe_rebalance()
-        self.kv.caches = new_caches
         self.last_tokens = nxt
         self.kv.note_decode(live)
         toks = np.asarray(nxt[:, 0])

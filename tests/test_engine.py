@@ -1,4 +1,5 @@
 """Serving engine: continuous batching correctness and lifecycle."""
+import dataclasses
 import os
 
 import jax
@@ -68,6 +69,48 @@ def test_continuous_batching_staggered_arrivals():
         pass
     assert r1.output == ref1
     assert r2.output == ref2
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "deepseek-v2-lite"])
+def test_decode_step_takes_caches_donated(arch):
+    """The decode step takes the KV caches donated: after a step every
+    cache array it was handed is deleted and the engine holds the step's
+    own. Rows written in place, each at its slot's own position (prompts
+    of 7, 3 and 11 tokens), serve the greedy reference's tokens, greedy
+    and under top-k 1 at a high temperature. The reference decodes one
+    row at a time, so the MoE drops no token (capacity factor E/k)."""
+    cfg = get_smoke_config(arch)
+    if cfg.is_moe:
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, capacity_factor=cfg.moe.num_experts / cfg.moe.top_k))
+    eng = ServingEngine(cfg, num_slots=3, max_context=64,
+                        dtype=jnp.float32)
+    model = build_model(cfg, dtype=jnp.float32)
+    rng = np.random.RandomState(5)
+    prompts = [list(rng.randint(0, cfg.vocab_size, size=n))
+               for n in (7, 3, 11)]
+    refs = [_greedy_ref(model, eng.params, p, 6, budget=64)
+            for p in prompts]
+    reqs = [Request(prompt=p, max_new_tokens=6, temperature=t, top_k=k)
+            for p, (t, k) in zip(prompts, [(0.0, 0), (5.0, 1), (0.0, 0)])]
+    handed = []
+    decode = eng._decode_jit
+
+    def spy(params, tokens, caches, *args, **kw):
+        handed.extend(jax.tree.leaves(caches))
+        return decode(params, tokens, caches, *args, **kw)
+
+    eng._decode_jit = spy
+    for r in reqs:
+        eng.submit(r)
+    while not handed:
+        eng.step()
+    assert all(a.is_deleted() for a in handed)
+    assert not any(a.is_deleted() for a in jax.tree.leaves(eng.kv.caches))
+    while eng.step() or eng.waiting:
+        pass
+    assert [r.output for r in reqs] == refs
+    eng.close()
 
 
 def test_more_requests_than_slots():
