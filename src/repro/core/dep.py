@@ -116,14 +116,8 @@ def _walk_chunk_stream(program, handlers) -> None:
     caller, each handler call is wrapped in a task span (``emit=True``).
     Under jit the walk runs at jax trace time, so these spans record
     op-emission order and trace cost once per compiled program — NOT
-    per-step execution time. When the walk executes EAGERLY (shard_map
-    outside jit dispatches each op immediately) and the tracer was built
-    with ``fence=True``, the walker fences each handler's returned value
-    (``maybe_fence``) before closing its span: the spans then bound real
-    on-device work per task — the fenced-emission trace the overlap
-    attributor consumes (``obs.device``). With no active tracer (the
-    default) this is the bare loop and the emitted program is
-    identical."""
+    per-step execution time. With no active tracer (the default) this is
+    the bare loop and the emitted program is identical."""
     if isinstance(program, tg.TaskGraph):
         program = tg.ExecProgram(program)
     tracer = active_tracer()
@@ -134,14 +128,11 @@ def _walk_chunk_stream(program, handlers) -> None:
                 h(task)
         return
     clock = tracer.clock
-    fence = tracer.fence
     for task in program.walk():
         h = handlers.get(task.kind)
         if h is not None:
             t0 = clock()
-            out = h(task)
-            if fence:
-                tracer.maybe_fence(out)
+            h(task)
             tracer.task_span(task, t0, clock(), emit=True)
 
 

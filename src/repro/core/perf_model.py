@@ -7,9 +7,7 @@ All times are SECONDS. Workload units follow the paper:
   * comm      z = bytes on the wire per device
 
 The paper fits these with least squares on microbenchmarks (Fig. 7,
-R^2 > 0.994); ``fit_alpha_beta`` reproduces that procedure and
-``benchmarks/perf_model_fit.py`` validates linearity on this host's
-measured GEMMs.
+R^2 > 0.994); ``fit_alpha_beta`` reproduces that procedure.
 """
 from __future__ import annotations
 

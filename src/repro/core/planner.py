@@ -153,8 +153,8 @@ class FinDEPPlanner:
                       skew=None) -> ScheduleResult:
         """Lower ``plan`` and schedule it under this planner's measured
         stage models for ``seq_len`` — the modeled per-task timeline of
-        one executed step (benchmarks/plan_trace renders this as a
-        Gantt; Table 7 derives exposed-communication time from it).
+        one executed step (``taskgraph.ascii_gantt`` renders it; Table 7
+        derives exposed-communication time from it).
         With ``skew`` the timeline includes the REP lane segment and the
         kappa/(1-rho)-scaled EXP/comm task times."""
         models = self.stage_models(seq_len, decode_context=decode_context,

@@ -522,7 +522,7 @@ class ScheduleResult:
 
     def spans(self) -> Tuple[Tuple[Task, float, float], ...]:
         """(task, start, end) triples in emission order -- the view the
-        Chrome-trace exporter and the replay harness consume."""
+        Chrome-trace exporter and the overlap attributor consume."""
         return tuple(zip(self.graph.tasks, self.starts, self.ends))
 
     def lane_idle(self) -> Dict[str, float]:
@@ -682,9 +682,9 @@ def stream_serial_deps(graph: TaskGraph) -> Dict[int, Tuple[int, ...]]:
     i fully retires (the engine's chunked-prefill loop blocks on each
     chunk's output before issuing the next). Returns extra dep edges
     {first task of stream i: (last task of stream i-1 per lane, ...)}
-    for i ≥ 1 — the edges ``obs.replay`` adds when replaying the
-    sequential realization, and the complement of what the interleaved
-    program removes."""
+    for i ≥ 1 — the edges graphcheck adds to check the sequential
+    realization, and the complement of what the interleaved program
+    removes."""
     extra: Dict[int, Tuple[int, ...]] = {}
     first_of: Dict[int, int] = {}
     last_per_lane: Dict[int, Dict[str, int]] = {}
@@ -797,7 +797,7 @@ def schedule_makespan(graph: TaskGraph, costs: TaskCosts) -> float:
 
 
 # ---------------------------------------------------------------------------
-# ASCII Gantt rendering (benchmarks/plan_trace.py)
+# ASCII Gantt rendering
 # ---------------------------------------------------------------------------
 
 _GANTT_GLYPH = {ATTN: "A", SHARED: "S", GATE: "g", A2E: ">", EXP: "E",

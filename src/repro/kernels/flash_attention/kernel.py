@@ -8,8 +8,8 @@ block-aligned shapes the MXU sees (bq x D) @ (D x bk) and (bq x bk) @
 (bk x D), all dims multiples of 128 for bq = bk = 128, D = 128.
 
 VMEM per step (bq=bk=128, D=128, bf16): q 32KB + k/v 64KB + acc f32 64KB +
-m/l 1KB -> well under budget; larger bq/bk trade VMEM for fewer grid steps
-(swept in benchmarks/kernel_blocks.py).
+m/l 1KB -> well under budget; larger bq/bk trade VMEM for fewer grid
+steps.
 """
 from __future__ import annotations
 

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache for the entry points.
 
-Entry points (``chip_smoke.py``, ``examples/serve_moe.py``,
-``benchmarks/run.py``) call ``enable_compile_cache()`` once, before their
-first compile. Tests never call it.
+Entry points (``chip_smoke.py``, ``examples/serve_moe.py``) call
+``enable_compile_cache()`` once, before their first compile. Tests never
+call it.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Step builders + abstract input specs for every (arch × input shape).
 
-This is the single source used by the dry-run, the examples and the
-benchmarks:
+This is the single source used by the dry-run, the training loop and the
+examples:
 
   * ``build(arch_cfg, shape, mesh)``   -> StepBundle with jit-able step fn,
     abstract inputs (ShapeDtypeStruct, no allocation) and shardings.

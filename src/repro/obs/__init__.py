@@ -18,9 +18,6 @@ Three concerns, one package (DESIGN.md "Observability dataflow"):
                    exposed-comm time and diff them against the lowered
                    graph's schedule/``CostBreakdown`` (the "executed
                    overlap == scheduled overlap within eps" metric);
-    ``replay``   — execute a scheduled graph for real on four host lanes
-                   (worker threads, per-task fencing) so the attribution
-                   has executed spans to chew on even without a TPU mesh.
   * ``metrics``  — ``Counter``/``Gauge``/``Histogram`` (fixed log-spaced
                    buckets, p50/p99) behind one ``MetricsRegistry`` that
                    every existing stat surface registers into: one
@@ -38,8 +35,6 @@ from repro.obs.overlap import (LaneOccupancy, OverlapReport,
                                attribute_overlap, executed_exposed_comm,
                                interval_total, interval_subtract,
                                interval_union, lane_intervals)
-from repro.obs.replay import ReplayResult, replay_schedule
-from repro.obs.device import DeviceTrace, device_mesh, trace_dep_execution
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "log_buckets",
@@ -49,6 +44,4 @@ __all__ = [
     "LaneOccupancy", "OverlapReport", "attribute_overlap",
     "executed_exposed_comm", "interval_total", "interval_subtract",
     "interval_union", "lane_intervals",
-    "ReplayResult", "replay_schedule",
-    "DeviceTrace", "device_mesh", "trace_dep_execution",
 ]

@@ -4,9 +4,8 @@ The solver optimizes a *modeled* makespan; ROADMAP item 3's win claim is
 "executed overlap == scheduled overlap within eps". This module computes
 both sides of that equation from one pair of inputs:
 
-  * executed: ``cat="task"`` spans from a ``TraceRecorder`` (produced by
-    ``obs.replay`` on host lanes, or by any future on-device profiler
-    that tags spans with the IR's kind/lane coordinates);
+  * executed: ``cat="task"`` spans from a ``TraceRecorder`` (any
+    producer that tags spans with the IR's kind/lane coordinates);
   * scheduled: the lowered graph's ``taskgraph.ScheduleResult``.
 
 Reductions (same interval algebra as ``core.simulator``'s Table 7
@@ -19,9 +18,8 @@ metric, reimplemented here over spans):
     executed counterpart of the plan's ``CostBreakdown``.
 
 ``attribute_overlap`` diffs the two sides into an ``OverlapReport``.
-Because a host replay runs time-scaled, the headline gap metric is the
-difference of exposed-comm *fractions of makespan* (scale cancels);
-absolute executed seconds are de-scaled for side-by-side reporting.
+The headline gap metric is the difference of exposed-comm *fractions of
+makespan*, so it compares a schedule with executed spans on any clock.
 """
 from __future__ import annotations
 
@@ -193,9 +191,7 @@ def class_busy(spans: Iterable[Span]) -> Dict[str, float]:
 
 @dataclass
 class OverlapReport:
-    """Executed vs scheduled, side by side. All executed seconds are
-    de-scaled by the replay's ``time_scale`` so they are directly
-    comparable to the modeled values; ``gap`` is the difference of
+    """Executed vs scheduled, side by side; ``gap`` is the difference of
     exposed-comm fractions of makespan (dimensionless, scale-free):
 
         gap = | exposed_exec / makespan_exec
@@ -211,7 +207,6 @@ class OverlapReport:
     idle_executed: Dict[str, float]
     breakdown_modeled: CostBreakdown
     breakdown_executed: Dict[str, float] = field(default_factory=dict)
-    time_scale: float = 1.0
 
     @property
     def exposed_frac_modeled(self) -> float:
@@ -241,7 +236,6 @@ class OverlapReport:
             "exposed_frac_modeled": self.exposed_frac_modeled,
             "exposed_frac_executed": self.exposed_frac_executed,
             "gap": self.gap,
-            "time_scale": self.time_scale,
         }
         for lane in COMM_LANES + ("total",):
             out[f"exposed_modeled_{lane}_s"] = self.exposed_modeled[lane]
@@ -258,12 +252,10 @@ class OverlapReport:
         return out
 
 
-def attribute_overlap(spans: Iterable[Span], result: ScheduleResult,
-                      time_scale: float = 1.0) -> OverlapReport:
+def attribute_overlap(spans: Iterable[Span],
+                      result: ScheduleResult) -> OverlapReport:
     """Reduce executed task ``spans`` and diff against the scheduled
-    ``result``. ``time_scale`` is the replay's duration multiplier
-    (executed seconds are divided by it for reporting; the gap metric is
-    scale-free either way)."""
+    ``result``."""
     spans = list(spans)
     occ = lane_occupancy(spans)
     exec_exposed = executed_exposed_comm(spans)
@@ -272,16 +264,13 @@ def attribute_overlap(spans: Iterable[Span], result: ScheduleResult,
         lo = min(o.first for o in occ.values())
         hi = max(o.last for o in occ.values())
         makespan_exec = hi - lo
-    k = 1.0 / time_scale if time_scale > 0 else 1.0
     return OverlapReport(
         makespan_modeled=result.makespan,
-        makespan_executed=makespan_exec * k,
+        makespan_executed=makespan_exec,
         exposed_modeled=scheduled_exposed_comm(result),
-        exposed_executed={lane: v * k for lane, v in exec_exposed.items()},
+        exposed_executed=exec_exposed,
         busy_modeled=dict(result.busy),
-        busy_executed={lane: o.busy * k for lane, o in occ.items()},
-        idle_executed={lane: o.idle * k for lane, o in occ.items()},
+        busy_executed={lane: o.busy for lane, o in occ.items()},
+        idle_executed={lane: o.idle for lane, o in occ.items()},
         breakdown_modeled=result.breakdown(),
-        breakdown_executed={cls: v * k
-                            for cls, v in class_busy(spans).items()},
-        time_scale=time_scale)
+        breakdown_executed=class_busy(spans))

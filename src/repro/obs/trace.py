@@ -8,15 +8,12 @@ span families (taxonomy table in DESIGN.md):
                       decode_step), opened by the engine's host loop
                       around its already-fenced device calls — real
                       wall-clock intervals;
-  * ``cat="task"``    one span per executed task (ATTN/SHARED/GATE/A2E/
-                      EXP/E2A/REP), tagged kind/layer/mb/chunk/lane.
-                      Two producers: the DEP executor's walk records the
-                      op-*emission* of each task (``args["emit"]=True``
-                      — trace-time, once per compiled program: the
-                      executed program order, not durations), and
-                      ``obs.replay`` records genuinely executed,
-                      per-task-fenced spans the overlap attributor
-                      reduces;
+  * ``cat="task"``    one span per task (ATTN/SHARED/GATE/A2E/EXP/E2A/
+                      REP), tagged kind/layer/mb/chunk/lane. The DEP
+                      executor's walk records the op-*emission* of each
+                      task (``args["emit"]=True`` — trace-time, once per
+                      compiled program: the executed program order, not
+                      durations);
   * ``cat="request"`` request lifecycle segments (queued / prefill /
                       decode) reconstructed from the request's
                       timestamps when it finishes — TTFT/TPOT live here.
@@ -27,11 +24,6 @@ its model calls (``use_tracer``), and ``core.dep``'s walker asks
 tracer set (or a disabled one) every hook is None/no-op and the executor
 emits the exact same ops — tracing off compiles the identical program
 (test-locked).
-
-``fence=True`` opts into extra ``jax.block_until_ready`` fencing at
-chunk boundaries (``maybe_fence``) so phase spans bound device work
-instead of async dispatch; it is off by default because extra syncs cost
-wall time (the compiled program is identical either way).
 
 Every ``span`` also enters ``jax.profiler.TraceAnnotation("<track>.<name>")``
 so, under a running profiler, the same interval sits on the host plane of
@@ -85,10 +77,8 @@ class TraceRecorder:
     to, so multiple recorders/export groups align.
     """
 
-    def __init__(self, enabled: bool = True, fence: bool = False,
-                 clock=time.perf_counter):
+    def __init__(self, enabled: bool = True, clock=time.perf_counter):
         self.enabled = enabled
-        self.fence = fence
         self.clock = clock
         self.origin = clock()
         self.spans: List[Span] = []
@@ -161,15 +151,6 @@ class TraceRecorder:
             self.add_span("decode", track, first, finish, cat="request",
                           request_id=rid, state=state,
                           tokens=len(req.output))
-
-    # -- fencing --------------------------------------------------------
-    def maybe_fence(self, x) -> None:
-        """Opt-in chunk-boundary fence: block on ``x`` so the enclosing
-        phase span bounds device work, not async dispatch. No-op unless
-        this recorder was built with ``fence=True``."""
-        if self.enabled and self.fence and x is not None:
-            import jax
-            jax.block_until_ready(x)
 
     # -- readers --------------------------------------------------------
     def task_spans(self, emitted: Optional[bool] = None) -> List[Span]:

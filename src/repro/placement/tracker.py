@@ -171,8 +171,8 @@ def capacity_scale(skew: Optional[SkewSummary],
 def zipf_loads(num_experts: int, s: float = 1.2,
                permutation: Optional[Sequence[int]] = None) -> np.ndarray:
     """Zipf(s) load histogram over ``num_experts`` (rank r gets
-    1/(r+1)^s, normalized) — the skew regime the benchmark and tests
-    replay. ``permutation`` shuffles which expert id is hot."""
+    1/(r+1)^s, normalized) — the skew regime the tests replay.
+    ``permutation`` shuffles which expert id is hot."""
     ranks = np.arange(1, num_experts + 1, dtype=np.float64)
     frac = ranks ** (-float(s))
     frac /= frac.sum()

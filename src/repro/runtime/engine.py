@@ -57,7 +57,6 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core.perf_model import HardwareProfile, get_profile
-from repro.core.planner import FinDEPPlanner
 from repro.core.solver import Plan
 from repro.models import build_model
 from repro.models.transformer import ExecutionContext, Model
@@ -73,8 +72,7 @@ from repro.runtime.kv import KVCacheManager
 from repro.runtime.paging import PagedKVCacheManager
 from repro.runtime.request import Request, RequestState
 from repro.runtime.sampler import sample
-from repro.sched import (FinDEPPolicy, OccupancySummary, PlanCache,
-                         SchedulePolicy)
+from repro.sched import OccupancySummary, PlanCache, SchedulePolicy
 
 
 def check_decode_kernel(cfg: ModelConfig, *, mesh=None,
@@ -139,10 +137,8 @@ class EngineStats:
 class ServingEngine:
     """``plan_policy`` is any repro.sched.SchedulePolicy; ``scheduler`` a
     configured BatchScheduler (or use the ``admission``/``token_budget``
-    shorthands). The legacy ``policy=``/``planner=`` kwargs still work
-    (with a DeprecationWarning): ``planner=FinDEPPlanner(...)`` wraps
-    itself in a FinDEPPolicy. With no policy at all, the engine runs
-    unscheduled (dense/capacity MoE or non-MoE models)."""
+    shorthands). With no policy at all, the engine runs unscheduled
+    (dense/capacity MoE or non-MoE models)."""
 
     def __init__(self, cfg: ModelConfig, params=None, *, num_slots: int = 4,
                  max_context: int = 4096, mesh=None,
@@ -150,9 +146,6 @@ class ServingEngine:
                  admission: str = "fcfs",
                  token_budget: Optional[int] = None,
                  plan_policy: Optional[SchedulePolicy] = None,
-                 planner: Optional[FinDEPPlanner] = None,
-                 policy: Optional[SchedulePolicy] = None,
-                 plan_cache_capacity: Optional[int] = None,
                  telemetry=None,
                  tracer=None,
                  metrics=None,
@@ -160,40 +153,20 @@ class ServingEngine:
                  profile_store=None,
                  drift_threshold: Optional[float] = None,
                  drift_min_samples: int = 3,
-                 drift_recalibrate: bool = True,
                  recalibrate_max_age_s: Optional[float] = None,
                  attn_impl: str = "decode_kernel",
                  kv_layout: str = "dense",
                  kv_block_size: int = 32,
                  kv_num_blocks: Optional[int] = None,
-                 kv_watermark_high: float = 0.90,
-                 kv_watermark_low: float = 0.75,
                  decode_bc: Optional[int] = None,
                  replicate_hot_k: int = 0,
                  rebalance_threshold: Optional[float] = None,
                  track_expert_load: Optional[bool] = None,
-                 rebalance_min_observations: int = 3,
-                 max_capacity_scale: float = 4.0,
                  interleave: str = "streams",
                  validate: bool = False,
                  dtype=jnp.float32, seed: int = 0):
-        if policy is not None:
-            warnings.warn(
-                "ServingEngine(policy=...) is deprecated; pass "
-                "plan_policy=...", DeprecationWarning, stacklevel=2)
-            if plan_policy is None:
-                plan_policy = policy
-        if planner is not None:
-            warnings.warn(
-                "ServingEngine(planner=...) is deprecated; pass "
-                "plan_policy=FinDEPPolicy(planner)",
-                DeprecationWarning, stacklevel=2)
-            if plan_policy is None:
-                plan_policy = FinDEPPolicy(planner)
-        self.policy = plan_policy          # back-compat alias
         self.plan_policy = plan_policy
-        self.plan_cache = (PlanCache(plan_policy,
-                                     capacity=plan_cache_capacity)
+        self.plan_cache = (PlanCache(plan_policy)
                            if (plan_policy is not None and cfg.is_moe)
                            else None)
         # measured cost models (repro.profiling): an explicit profile= /
@@ -228,7 +201,6 @@ class ServingEngine:
                 else StepTimer(),
                 threshold=drift_threshold,
                 min_samples=drift_min_samples,
-                recalibrate=drift_recalibrate,
                 metrics=self.metrics)
         # cron-style background re-calibration: when the stored profile
         # for this host goes stale, re-run the microbenchmarks off the
@@ -302,8 +274,6 @@ class ServingEngine:
         # is applied between steps by permuting the stacked expert weights
         self.replicate_hot_k = max(int(replicate_hot_k), 0)
         self.rebalance_threshold = rebalance_threshold
-        self.rebalance_min_observations = int(rebalance_min_observations)
-        self._max_capacity_scale = float(max_capacity_scale)
         placement_wanted = (self.replicate_hot_k > 0
                             or rebalance_threshold is not None)
         if track_expert_load is None:
@@ -340,7 +310,6 @@ class ServingEngine:
                 self._owns_placement_refresher = True
         self.num_slots = num_slots
         self.max_context = max_context
-        self.planner = planner
         self.key = jax.random.PRNGKey(seed + 1)
         if kv_layout not in ("dense", "paged"):
             raise ValueError(f"kv_layout={kv_layout!r}; "
@@ -361,9 +330,7 @@ class ServingEngine:
             self.kv: KVCacheManager = PagedKVCacheManager(
                 num_slots, max_context, model=self.model,
                 dtype=self.model.dtype, block_size=kv_block_size,
-                num_blocks=kv_num_blocks,
-                watermark_high=kv_watermark_high,
-                watermark_low=kv_watermark_low)
+                num_blocks=kv_num_blocks)
         else:
             self.kv = KVCacheManager(num_slots, max_context,
                                      model=self.model,
@@ -683,6 +650,10 @@ class ServingEngine:
     # expert placement (observe -> place -> plan)
     # ------------------------------------------------------------------
     _PLACEMENT_KEY = ("__placement__",)
+    # tracked routing steps before the first re-balance may be solved
+    _REBALANCE_MIN_OBSERVATIONS = 3
+    # cap on the skew-driven capacity multiplier (bounds buffer growth)
+    _MAX_CAPACITY_SCALE = 4.0
 
     def _current_skew(self) -> Optional[SkewSummary]:
         """The quantized skew fingerprint plans are resolved under; None
@@ -697,14 +668,14 @@ class ServingEngine:
     def _capacity_scale(self, skew: Optional[SkewSummary]) -> float:
         """Static capacity multiplier for the executed dispatch, rounded
         up to a power of two (bounds trace cardinality) and capped at
-        ``max_capacity_scale`` (bounds buffer growth)."""
+        ``_MAX_CAPACITY_SCALE`` (bounds buffer growth)."""
         if skew is None or not self._dep_active:
             return 1.0
         raw = capacity_scale(skew, self.cfg.moe.capacity_factor)
         if raw <= 1.0:
             return 1.0
         return float(min(2.0 ** math.ceil(math.log2(raw)),
-                         self._max_capacity_scale))
+                         self._MAX_CAPACITY_SCALE))
 
     def rank_imbalance(self) -> float:
         """Worst EP rank's cold (non-replicated) load as a multiple of
@@ -735,7 +706,7 @@ class ServingEngine:
                 or self._pending_placement is not None):
             return False
         if (self.load_tracker.observations
-                < self.rebalance_min_observations):
+                < self._REBALANCE_MIN_OBSERVATIONS):
             return False
         if self.rank_imbalance() <= self.rebalance_threshold:
             return False

@@ -57,7 +57,7 @@ SCRATCH_PAGE = 0
 
 @dataclass
 class PagingStats:
-    """Telemetry counters the engine/benchmarks surface."""
+    """Telemetry counters the engine surfaces."""
 
     prefix_hit_tokens: int = 0      # prefill tokens served from shared pages
     prefix_miss_tokens: int = 0     # prefill tokens that streamed fresh
@@ -293,8 +293,7 @@ class PagedKVCacheManager(KVCacheManager):
     pools ``[num_blocks, Kv, block_size, D]``, and ``merge_prefill``
     scatters prefill rows page-by-page — skipping pages served by the
     prefix cache. ``model=None`` still gives a ledger-only manager
-    (tables/pool/prefix fully functional, no device arrays) for tests
-    and capacity benchmarks.
+    (tables/pool/prefix fully functional, no device arrays) for tests.
     """
 
     def __init__(self, num_slots: int, max_context: int, model=None,

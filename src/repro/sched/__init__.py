@@ -1,8 +1,8 @@
 """First-class scheduling layer: pluggable policies + per-shape plan cache.
 
 ``SchedulePolicy.resolve(phase, seq_bucket, batch_per_device,
-occupancy=...) -> Plan`` is the single interface through which the engine,
-the DEP executor, the benchmarks and the examples obtain schedules;
+occupancy=..., skew=...) -> Plan`` is the single interface through which
+the engine, the DEP executor and the examples obtain schedules;
 ``PlanCache`` memoizes resolved plans per shape (prefill buckets) or per
 ``OccupancySummary`` (decode solved on the real live-slot composition) so
 steady-state decode pays ~zero solver cost.

@@ -10,15 +10,13 @@ pay ~zero scheduling cost while genuine shape changes still re-solve.
 Two key spaces coexist:
 
   * shape keys ``(phase, seq_bucket, batch_per_device)`` — the prefill
-    surface (a padded bucket IS the executed shape) and the legacy decode
-    proxy;
+    surface (a padded bucket IS the executed shape);
   * occupancy keys ``(phase, OccupancySummary)`` — decode plans solved on
     the real live-slot composition from the KV ledger.
 
-Policies that predate the ``occupancy=`` argument are still served: the
-cache detects the old ``resolve(phase, seq_bucket, batch)`` signature and
-falls back to it (with a DeprecationWarning) by projecting the summary
-onto its (seq_bucket, live) shape.
+Every lookup consults the policy as ``resolve(phase, seq_bucket,
+batch_per_device, occupancy=..., skew=...)``, the ``SchedulePolicy``
+protocol's signature.
 
 Beyond memoization the cache is the refresh surface of the profiling
 subsystem (``repro.profiling``):
@@ -33,9 +31,7 @@ subsystem (``repro.profiling``):
 """
 from __future__ import annotations
 
-import inspect
 import time
-import warnings
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple, Union
 
@@ -99,17 +95,6 @@ class EntryMeta:
         return self.hits * self.solve_s
 
 
-def _takes_kwarg(policy, kwarg: str) -> bool:
-    try:
-        return kwarg in inspect.signature(policy.resolve).parameters
-    except (TypeError, ValueError):    # builtins / exotic callables
-        return True
-
-
-def _takes_occupancy(policy) -> bool:
-    return _takes_kwarg(policy, "occupancy")
-
-
 class PlanCache:
     """Memoizes ``policy.resolve`` per execution shape.
 
@@ -133,8 +118,6 @@ class PlanCache:
         self._meta: Dict[PlanKey, EntryMeta] = {}
         self._tick = 0
         self.stats = PlanCacheStats()
-        self._occupancy_aware = _takes_occupancy(policy)
-        self._skew_aware = _takes_kwarg(policy, "skew")
 
     @staticmethod
     def _key(phase: str, seq_bucket, batch_per_device, occupancy,
@@ -237,27 +220,8 @@ class PlanCache:
 
     def _resolve(self, phase, seq_bucket, batch_per_device, occupancy,
                  skew=None):
-        # legacy policies without a skew= keyword solve under the uniform
-        # assumption — the entry still keys on the summary, so a skew
-        # regime shift re-consults the policy rather than serving stale
-        kw = {"skew": skew} if (skew is not None and self._skew_aware) else {}
-        if occupancy is None:
-            return self.policy.resolve(phase, seq_bucket, batch_per_device,
-                                       **kw)
-        if self._occupancy_aware:
-            return self.policy.resolve(phase, seq_bucket, batch_per_device,
-                                       occupancy=occupancy, **kw)
-        warnings.warn(
-            f"policy {getattr(self.policy, 'name', self.policy)!r} has a "
-            "legacy resolve(phase, seq_bucket, batch) signature; occupancy "
-            "summaries are projected onto (seq_bucket, live). Add an "
-            "occupancy= keyword to resolve() to schedule on the real "
-            "composition.", DeprecationWarning, stacklevel=3)
-        return self.policy.resolve(
-            phase, seq_bucket if seq_bucket is not None
-            else occupancy.seq_bucket,
-            batch_per_device if batch_per_device is not None
-            else occupancy.live)
+        return self.policy.resolve(phase, seq_bucket, batch_per_device,
+                                   occupancy=occupancy, skew=skew)
 
     def entries(self) -> Dict[PlanKey, Plan]:
         return dict(self._plans)

@@ -3,8 +3,8 @@
 A ``SchedulePolicy`` maps an observed execution shape — (phase, sequence
 bucket, per-device batch), or for decode an ``OccupancySummary`` of the
 real live-slot composition — to a fully-specified ``Plan`` (m_a, r1, r2,
-order). The serving engine, the DEP executor, the benchmarks and the
-examples all consume schedules through this one surface, so the paper's
+order). The serving engine, the DEP executor and the examples all
+consume schedules through this one surface, so the paper's
 baselines are runnable systems rather than analytic curves:
 
   FinDEPPolicy        Algorithm 1 per shape (the paper's online phase)

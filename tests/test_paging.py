@@ -332,6 +332,41 @@ class TestPagedManager:
         assert kv._nblk[s] == 0                # partial assignment undone
         assert kv.pool.free_count() == 2
 
+    def test_capacity_at_fixed_budget_vs_dense(self):
+        """At an 8192-token KV budget, conversations sharing a 256-token
+        system prompt (32 private prompt tokens, 96 decoded) fit at least
+        twice as many at once as dense rows of ``max_context`` would, and
+        the shared prompt is served from the prefix cache."""
+        max_context, bs, budget = 4096, 32, 8192
+        kv = PagedKVCacheManager(256, max_context, block_size=bs,
+                                 num_blocks=budget // bs + 1)  # +1 scratch
+        shared = list(range(256))
+        resident = 0
+        while True:
+            slot = kv.alloc()
+            if slot is None:
+                break
+            prompt = shared + [10_000 + resident * 131 + i
+                               for i in range(32)]
+            try:
+                kv.assign_blocks(slot, prompt[:-1])
+            except RuntimeError:
+                kv.free(slot)
+                break
+            kv.set_length(slot, len(prompt))
+            decoded = 0
+            # engine order: page the write at length-1, then advance
+            while decoded < 96 and kv.ensure_decode_page(slot):
+                kv.set_length(slot, kv.length(slot) + 1)
+                decoded += 1
+            if decoded < 96:
+                kv.free(slot)
+                break
+            resident += 1
+        dense = budget // max_context
+        assert resident >= 2 * dense, (resident, dense)
+        assert kv.paging_summary()["prefix_hit_rate"] > 0.0
+
 
 # ---------------------------------------------------------------------------
 # engine: end-to-end parity + preemption

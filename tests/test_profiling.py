@@ -284,7 +284,7 @@ class SlowRefreshPolicy:
         self.calls = 0
 
     def resolve(self, phase, seq_bucket=None, batch_per_device=None, *,
-                occupancy=None):
+                occupancy=None, skew=None):
         self.calls += 1
         if self.calls > 1:
             time.sleep(self.delay)
@@ -478,7 +478,7 @@ class TunableLatencyPolicy:
         self.calls = 0
 
     def resolve(self, phase, seq_bucket=None, batch_per_device=None, *,
-                occupancy=None):
+                occupancy=None, skew=None):
         self.calls += 1
         if self.delay:
             time.sleep(self.delay)

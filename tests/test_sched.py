@@ -155,17 +155,6 @@ def test_plan_cache_occupancy_keys():
         cache.get("decode")                           # neither shape nor occ
 
 
-def test_plan_cache_shims_legacy_policy_signature():
-    """A policy without the occupancy kwarg still serves occupancy lookups
-    through the deprecated (phase, seq_bucket, batch) projection."""
-    pol = CountingPolicy()
-    cache = PlanCache(pol)
-    occ = OccupancySummary.from_lengths([100, 100, 100])
-    with pytest.warns(DeprecationWarning, match="legacy resolve"):
-        cache.get("decode", occupancy=occ)
-    assert pol.calls == [("decode", occ.seq_bucket, occ.live)]
-
-
 # ---------------------------------------------------------------------------
 # PlanCache
 # ---------------------------------------------------------------------------
@@ -176,7 +165,8 @@ class CountingPolicy:
     def __init__(self):
         self.calls = []
 
-    def resolve(self, phase, seq_bucket, batch_per_device=None):
+    def resolve(self, phase, seq_bucket, batch_per_device=None, *,
+                occupancy=None, skew=None):
         self.calls.append((phase, seq_bucket, batch_per_device))
         return Plan(m_a=1, r1=1, m_e=1.0, r2=len(self.calls), order="AASS",
                     throughput=1.0, makespan=1.0)
@@ -286,29 +276,6 @@ def test_all_policies_serve_end_to_end(name):
     assert len(finished) == 3
     assert all(r.state == RequestState.FINISHED for r in reqs)
     assert len(eng.plan_cache) >= 1
-
-
-def test_legacy_planner_kwarg_still_works():
-    with pytest.warns(DeprecationWarning, match="planner=.*deprecated"):
-        eng = ServingEngine(CFG, num_slots=1, max_context=64,
-                            planner=mk_planner(), dtype=jnp.float32)
-    assert isinstance(eng.policy, FinDEPPolicy)
-    rng = np.random.RandomState(3)
-    (req,) = _mk_requests(rng, 1, 4, 8, max_new=2)
-    eng.submit(req)
-    assert eng.run() == [req]
-
-
-def test_legacy_policy_kwarg_warns_and_works():
-    pol = FinDEPPolicy(mk_planner())
-    with pytest.warns(DeprecationWarning, match="policy=.*deprecated"):
-        eng = ServingEngine(CFG, num_slots=1, max_context=64,
-                            policy=pol, dtype=jnp.float32)
-    assert eng.plan_policy is pol
-    rng = np.random.RandomState(4)
-    (req,) = _mk_requests(rng, 1, 4, 8, max_new=2)
-    eng.submit(req)
-    assert eng.run() == [req]
 
 
 def test_execution_context_plan_shim_is_gone():
